@@ -11,13 +11,49 @@ code below the DataFrame API), so tests can also use them as a second
 differential oracle against the SQLite one: agreement of three independent
 executors (engine / sqlite / pandas) on 22 queries is strong evidence.
 
-Parameter values match benchmarks/tpch.py QUERIES verbatim.
+Parameter values match benchmarks/tpch.py QUERIES verbatim; q1/q6/q12 take
+theirs as keyword defaults so chip_smoke.py can check a changed literal.
 """
 from __future__ import annotations
 
+import numpy as np
 import pandas as pd
 
 _TS = pd.Timestamp
+
+
+def _normalize(df: pd.DataFrame) -> pd.DataFrame:
+    out = df.copy().reset_index(drop=True)
+    for col in out.columns:
+        s = out[col]
+        if pd.api.types.is_datetime64_any_dtype(s):
+            out[col] = pd.to_datetime(s)
+        elif pd.api.types.is_float_dtype(s):
+            out[col] = s.astype(np.float64).round(6)
+        elif pd.api.types.is_bool_dtype(s):
+            out[col] = s.astype(bool)
+        elif pd.api.types.is_integer_dtype(s):
+            out[col] = s.astype(np.int64)
+        else:
+            out[col] = s.astype(str)
+    return out
+
+
+def assert_frames_match(eng: pd.DataFrame, ref: pd.DataFrame,
+                        label: str = "") -> None:
+    """The one comparison of an engine result with its pandas reference
+    (tests/integration/test_pandas_oracle.py and chip_smoke.py): columns
+    positionally (both follow the SELECT list), rows as sets, floats at
+    rtol 1e-5 / atol 1e-6."""
+    assert len(eng.columns) == len(ref.columns), (
+        f"{label}: column count {list(eng.columns)} vs {list(ref.columns)}")
+    ref = ref.rename(columns=dict(zip(ref.columns, eng.columns)))
+    eng_n, ref_n = _normalize(eng), _normalize(ref)
+    cols = list(eng_n.columns)
+    eng_n = eng_n.sort_values(cols, ignore_index=True)
+    ref_n = ref_n.sort_values(cols, ignore_index=True)
+    pd.testing.assert_frame_equal(eng_n, ref_n, check_dtype=False,
+                                  rtol=1e-5, atol=1e-6, obj=label or None)
 
 
 def _sql_sum(s):
@@ -25,11 +61,11 @@ def _sql_sum(s):
     return s.sum() if len(s) else float("nan")
 
 
-def q1(d):
+def q1(d, shipdate="1998-09-02"):
     li = d["lineitem"]
     # narrow before copying: materializing all 16 columns of the ~98%
     # selectivity filter tripled the runtime at SF 1
-    x = li.loc[li["l_shipdate"] <= _TS("1998-09-02"),
+    x = li.loc[li["l_shipdate"] <= _TS(shipdate),
                ["l_returnflag", "l_linestatus", "l_quantity",
                 "l_extendedprice", "l_discount", "l_tax"]].copy()
     x["disc_price"] = x["l_extendedprice"] * (1 - x["l_discount"])
@@ -108,12 +144,12 @@ def q5(d):
     return out.sort_values("revenue", ascending=False, ignore_index=True)
 
 
-def q6(d):
+def q6(d, quantity=24):
     li = d["lineitem"]
     x = li[(li["l_shipdate"] >= _TS("1994-01-01"))
            & (li["l_shipdate"] < _TS("1995-01-01"))
            & (li["l_discount"] >= 0.05) & (li["l_discount"] <= 0.07)
-           & (li["l_quantity"] < 24)]
+           & (li["l_quantity"] < quantity)]
     return pd.DataFrame(
         {"revenue": [_sql_sum(x["l_extendedprice"] * x["l_discount"])]})
 
@@ -229,12 +265,12 @@ def q11(d):
     return g.sort_values("value", ascending=False, ignore_index=True)
 
 
-def q12(d):
+def q12(d, receipt_from="1994-01-01"):
     od, li = d["orders"], d["lineitem"]
     l = li[li["l_shipmode"].isin(["MAIL", "SHIP"])
            & (li["l_commitdate"] < li["l_receiptdate"])
            & (li["l_shipdate"] < li["l_commitdate"])
-           & (li["l_receiptdate"] >= _TS("1994-01-01"))
+           & (li["l_receiptdate"] >= _TS(receipt_from))
            & (li["l_receiptdate"] < _TS("1995-01-01"))]
     m = l.merge(od, left_on="l_orderkey", right_on="o_orderkey")
     hi = m["o_orderpriority"].isin(["1-URGENT", "2-HIGH"])

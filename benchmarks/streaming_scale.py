@@ -173,34 +173,13 @@ def _frames_equal(a, b) -> bool:
 
 
 def main():
-    os.environ.setdefault("XLA_FLAGS",
-                          "--xla_force_host_platform_device_count=8")
-    # persistent XLA cache: the 8-device GSPMD programs cost minutes each
-    # to compile on this host — a rerun (or a crash-restart) must not
-    # re-pay them.  The dir name carries a CPU-feature fingerprint (same
-    # scheme as tests/conftest.py): XLA:CPU AOT executables are micro-arch
-    # specific, and /tmp can survive into a round that runs on a DIFFERENT
-    # machine — loading a foreign executable warns "could lead to
-    # execution errors such as SIGILL" and sometimes does exactly that.
-    import hashlib as _hashlib
-    try:
-        with open("/proc/cpuinfo") as _f:
-            _flags = "".join(sorted(l for l in _f if l.startswith("flags")))
-        _cpu_fp = _hashlib.blake2b(_flags.encode(),
-                                   digest_size=4).hexdigest()
-    except OSError:
-        _cpu_fp = "nocpuinfo"
-    os.environ.setdefault(
-        "DSQL_XLA_CACHE",
-        os.path.join(tempfile.gettempdir(),
-                     f"dsql_stream_scale_xla_{_cpu_fp}"))
+    # the 8-device GSPMD programs cost minutes each to compile on this
+    # host; the package's persistent XLA cache (JAX_COMPILATION_CACHE_DIR,
+    # else <repo>/.jax_cache) keeps a rerun from re-paying them
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 8)
-    except AttributeError:
-        pass  # jax < 0.5: the XLA_FLAGS fallback above covers it
+    jax.config.update("jax_num_cpu_devices", 8)
 
     import pandas as pd
 

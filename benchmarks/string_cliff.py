@@ -1,6 +1,6 @@
 """Dictionary-cliff benchmark: LIKE over increasing string cardinality.
 
-VERDICT r1 weak-point 4: the dictionary walk is host-bound — fine at TPC-H
+Review r1 weak-point 4: the dictionary walk is host-bound — fine at TPC-H
 cardinalities, a cliff at ~1M distinct values (Q13's comment column).  This
 script measures a Q13-shaped predicate (`o_comment NOT LIKE
 '%special%requests%'`) end-to-end through Context.sql at several distinct

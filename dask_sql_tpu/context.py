@@ -575,8 +575,8 @@ class Context:
             for df_name, df in dataframes.items():
                 self.create_table(df_name, df, gpu=gpu)
 
-        # per-call wall breakdown, overwritten by every sql() call: over a
-        # remote TPU the interesting split is host planning vs the (single)
+        # per-call wall breakdown, overwritten by every sql() call: the
+        # interesting split is host planning vs the (single)
         # device round trip vs host decode — bench.py journals this so a
         # slow query names its own bottleneck
         import time as _time

@@ -4,13 +4,15 @@ The reference's planner is native too (Java/Calcite compiled to DaskSQL.jar
 and loaded in-process, /root/reference/dask_sql/java.py:62-98, setup.py:25-42).
 Here the native piece is a C++ recursive-descent parser built into
 ``libdsqlparser.so`` (sources in ``native/`` at the repo root) and loaded via
-ctypes.  If the prebuilt library is missing we try one lazy ``make``; on any
-failure the pure-Python parser in ``dask_sql_tpu.sql.parser`` serves as the
-fallback, keeping the package importable without a toolchain.
+ctypes.  The library is a build product, not a committed file: the first
+``load()`` in a checkout runs one ``make`` (~17 s with g++); if that fails a
+warning says so and the pure-Python parser in ``dask_sql_tpu.sql.parser``
+serves, keeping the package importable without a toolchain.
 """
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import json
 import logging
 import os
@@ -24,19 +26,31 @@ _lib: Optional[ctypes.CDLL] = None
 _load_attempted = False
 
 
-def _try_build() -> bool:
-    """One best-effort build of the native library (repo checkouts only)."""
+def _try_build(path: str) -> bool:
+    """One build of the native library at ``path`` (repo checkouts only).
+    Processes that start together (test workers, server replicas) take
+    turns on a lock and only the first compiles; the library appears under
+    its name by rename, so nobody loads a half-written file."""
     native_src = os.path.join(os.path.dirname(os.path.dirname(
         os.path.dirname(os.path.abspath(__file__)))), "native")
     if not os.path.isfile(os.path.join(native_src, "Makefile")):
         return False
+    lock = os.open(os.path.dirname(path), os.O_RDONLY)
     try:
-        subprocess.run(["make", "-C", native_src], capture_output=True,
-                       timeout=120, check=True)
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if os.path.isfile(path):
+            return True
+        tmp = f"{path}.{os.getpid()}.partial"
+        subprocess.run(["make", "-C", native_src, f"OUT={tmp}"],
+                       capture_output=True, timeout=120, check=True)
+        os.replace(tmp, path)
         return True
     except Exception as exc:  # toolchain missing, build error, timeout
-        logger.debug("native parser build failed: %s", exc)
+        logger.warning("native parser build failed (%s); the Python "
+                       "parser serves", exc)
         return False
+    finally:
+        os.close(lock)
 
 
 def load() -> Optional[ctypes.CDLL]:
@@ -48,7 +62,7 @@ def load() -> Optional[ctypes.CDLL]:
     if os.environ.get("DSQL_NATIVE", "1") == "0":
         return None
     path = os.path.join(os.path.dirname(os.path.abspath(__file__)), _LIB_NAME)
-    if not os.path.isfile(path) and not _try_build():
+    if not os.path.isfile(path) and not _try_build(path):
         return None
     try:
         lib = ctypes.CDLL(path)
@@ -61,7 +75,8 @@ def load() -> Optional[ctypes.CDLL]:
             lib.dsql_optimize.restype = ctypes.c_void_p
         _lib = lib
     except OSError as exc:
-        logger.debug("native parser load failed: %s", exc)
+        logger.warning("native parser load failed (%s); the Python "
+                       "parser serves", exc)
         _lib = None
     return _lib
 

@@ -51,10 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.5: the experimental spelling
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..plan.nodes import (AggCall, Field, LogicalAggregate, LogicalFilter,
                           LogicalJoin, LogicalProject, LogicalSort,
@@ -898,15 +895,11 @@ def _pstore_load(digest: str, flat, n_outs: int):
     if raw is None:
         return None
     try:
-        import jax.tree_util as _jtu
-        from jax.experimental import serialize_executable as _se
         if (int(raw.get("v", 0)) != 1 or raw.get("kind") != "spmd"
                 or int(raw["n_args"]) != len(flat)
                 or int(raw["n_outs"]) != n_outs):
             raise ValueError("entry layout mismatch")
-        in_tree = _jtu.tree_structure((tuple(range(len(flat))), {}))
-        out_tree = _jtu.tree_structure(tuple(range(n_outs)))
-        fn = _se.deserialize_and_load(raw["payload"], in_tree, out_tree)
+        fn = _pstore.load_program(raw, len(flat), n_outs)
         outs = fn(*flat)
     except (KeyboardInterrupt, SystemExit):
         raise
@@ -925,15 +918,14 @@ def _pstore_save(digest: str, fn, n_args: int, n_outs: int) -> None:
     if not store.enabled():
         return
     try:
-        from jax.experimental import serialize_executable as _se
-        payload, _, _ = _se.serialize(fn)
+        program = _pstore.serialize_program(fn)
     except (KeyboardInterrupt, SystemExit):
         raise
     except Exception as e:
         _tel.inc("program_store_errors")
         logger.debug("spmd program serialize failed (%s); not persisted", e)
         return
-    store.store(digest, {"v": 1, "kind": "spmd", "payload": payload,
+    store.store(digest, {"v": 1, "kind": "spmd", **program,
                          "n_args": int(n_args), "n_outs": int(n_outs)})
 
 

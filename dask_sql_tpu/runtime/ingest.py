@@ -122,11 +122,11 @@ def _encode_table(t) -> dict:
     cols = []
     for name in df.columns:
         s = df[name]
-        if np.issubdtype(s.dtype, np.datetime64):
+        if s.dtype.kind == "M":
             vals = [None if v is None or str(v) == "NaT" else int(v.value)
                     for v in s]
             cols.append({"n": str(name), "d": "datetime64[ns]", "v": vals})
-        elif s.dtype == object or s.dtype.kind in ("U", "S"):
+        elif s.dtype.kind in ("O", "U", "S"):
             vals = [None if v is None or (isinstance(v, float) and v != v)
                     else str(v) for v in s.tolist()]
             cols.append({"n": str(name), "d": "str", "v": vals})

@@ -12,7 +12,7 @@ host-side supervision — this module is that discipline for dask_sql_tpu:
   ``UserError``       the query/input is wrong; retrying cannot help
                       (Presto ``USER_ERROR``);
   ``TransientError``  the attempt failed but a retry or a lower rung can
-                      succeed — compile crashes, device OOM, transfer/tunnel
+                      succeed — compile crashes, device OOM, transfer
                       drops (Presto ``INTERNAL_ERROR``, or
                       ``INSUFFICIENT_RESOURCES`` for ``kind="oom"``);
   ``FatalError``      an engine invariant broke; retrying is pointless and
@@ -92,7 +92,7 @@ class TransientError(ResilienceError):
     """A retry — or a lower degradation rung — can succeed.
 
     ``kind`` labels the failure class: ``"compile"`` (backend compile
-    crash), ``"oom"`` (device memory), ``"io"`` (transfer/tunnel),
+    crash), ``"oom"`` (device memory), ``"io"`` (transfer),
     ``"device"`` (other runtime errors), ``"injected"`` (test faults)."""
 
     error_name = "TRANSIENT_ERROR"
@@ -259,7 +259,7 @@ def classify(exc: BaseException, *, default=FatalError
             return wrap(TransientError, msg, kind="oom")
         if any(m in text for m in _XLA_FATAL_MARKERS):
             return wrap(FatalError, msg)
-        # INTERNAL / UNAVAILABLE / ABORTED / DEADLINE_EXCEEDED / tunnel
+        # INTERNAL / UNAVAILABLE / ABORTED / DEADLINE_EXCEEDED / transfer
         # drops: the attempt failed, the program may be fine
         return wrap(TransientError, msg, kind="compile")
     if isinstance(exc, (ConnectionError, TimeoutError, OSError)):

@@ -3,7 +3,7 @@
 The engine reuses compiled *programs* across queries (physical/compiled.py's
 stage-graph cache) but until now re-executed every query from scratch —
 repeated dashboard-style queries paid full device time every run, the
-dominant steady-state cost over a remote TPU.  Flare (PAPERS.md) shows
+dominant steady-state cost.  Flare (PAPERS.md) shows
 native SQL engines win by reusing compiled/materialized artifacts across
 queries; this module is the data-reuse layer on top of the program-reuse
 layer: it memoizes **query results** and **materialized stage-graph

@@ -1295,20 +1295,16 @@ def _number_value(text: str):
 
 import re as _re
 
-# EXPLAIN ANALYZE / EXPLAIN PROFILE are Python-parser-only extensions for
-# now: the native C++ grammar predates them and would report a parse error
-# at the modifier keyword, so such statements route directly to the Python
-# parser (which stays the lockstep superset) instead of bouncing off a
-# native error.
-_EXPLAIN_ANALYZE_RE = _re.compile(r"^\s*EXPLAIN\s+(ANALYZE|PROFILE)\b",
-                                  _re.IGNORECASE)
-
-# Same story for the materialized-view / append grammar (ISSUE 14): the
-# native C++ grammar predates CREATE/DROP MATERIALIZED VIEW, REFRESH
-# MATERIALIZED VIEW and INSERT INTO, so these statements route directly to
-# the Python parser instead of bouncing off a native parse error.
-_MATVIEW_STMT_RE = _re.compile(
-    r"^\s*(INSERT|REFRESH)\b"
+# Statements the native C++ grammar predates and would answer with a parse
+# ERROR (which native_bridge raises, it does not bounce): EXPLAIN
+# ANALYZE/PROFILE, the materialized-view / append grammar (ISSUE 14) and
+# prepared statements (PREPARE / EXECUTE / DEALLOCATE).  They route
+# directly to the Python parser, which stays the lockstep superset.  So
+# does any text with a parameter marker: the native lexer refuses ``$n``
+# and the native parser numbers every ``?`` as parameter 0.
+_PYTHON_ONLY_STMT_RE = _re.compile(
+    r"^\s*EXPLAIN\s+(ANALYZE|PROFILE)\b"
+    r"|^\s*(INSERT|REFRESH|PREPARE|EXECUTE|DEALLOCATE)\b"
     r"|^\s*(CREATE|DROP)\s+(OR\s+REPLACE\s+)?MATERIALIZED\b",
     _re.IGNORECASE)
 
@@ -1320,12 +1316,13 @@ def parse_sql(sql: str) -> List[Statement]:
     counterpart of the reference's native Java planner front-end,
     RelationalAlgebraGenerator.java:87); the pure-Python parser below is the
     fallback when the library is unavailable (``DSQL_NATIVE=0`` disables the
-    native path explicitly) and the only parser for ``EXPLAIN ANALYZE``.
+    native path explicitly) and the only parser for the statements of
+    ``_PYTHON_ONLY_STMT_RE``.
     """
     from .. import native as _native
     from . import native_bridge
 
-    if _EXPLAIN_ANALYZE_RE.match(sql) or _MATVIEW_STMT_RE.match(sql):
+    if _PYTHON_ONLY_STMT_RE.match(sql) or "?" in sql or "$" in sql:
         return Parser(sql).parse_statements()
     envelope = _native.parse_to_json(sql)
     if envelope is not None:

@@ -1,14 +1,8 @@
 """Packaging for dask_sql_tpu (reference: /root/reference/setup.py console
-scripts at :106-111; no jar build step — the planner is native Python/C++)."""
+scripts at :106-111; no jar build step).  The native C++ parser is built
+from ``native/`` on first import of a checkout (dask_sql_tpu/native); an
+installed package without it is served by the Python parser."""
 from setuptools import find_packages, setup
-from setuptools.dist import Distribution
-
-
-class _BinaryDistribution(Distribution):
-    """The prebuilt native parser makes this a platform wheel."""
-
-    def has_ext_modules(self):
-        return True
 
 
 setup(
@@ -16,7 +10,6 @@ setup(
     version="0.1.0",
     description="TPU-native distributed SQL query engine (dask-sql capability parity)",
     packages=find_packages(include=["dask_sql_tpu", "dask_sql_tpu.*"]),
-    package_data={"dask_sql_tpu.native": ["*.so"]},
     python_requires=">=3.10",
     install_requires=[
         "jax",
@@ -34,5 +27,4 @@ setup(
             "dask-sql-tpu-server = dask_sql_tpu.server.app:main",
         ]
     },
-    distclass=_BinaryDistribution,
 )

@@ -1,4 +1,4 @@
-"""Exact decimal aggregation (VERDICT r1 item 6).
+"""Exact decimal aggregation (review r1 item 6).
 
 DECIMAL(p<=18, s<=9) SUM/AVG accumulate in scaled int64 — order-independent
 (bit-stable across runs and row orders) and exactly equal to true decimal

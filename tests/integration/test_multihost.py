@@ -10,7 +10,7 @@ TPU pods).  This test launches TWO real processes on localhost, each with 4
 virtual CPU devices, builds the 8-device global mesh in each, runs a
 compiled aggregate+join query through ``Context(mesh=...)`` on BOTH, and
 checks the answer equals the single-host result — exercising the
-init_multihost path that had never executed before round 4 (VERDICT r3
+init_multihost path that had never executed before round 4 (review r3
 item 6).
 """
 import json

@@ -13,7 +13,7 @@ import numpy as np
 import pandas as pd
 import pytest
 
-from benchmarks.pandas_tpch import PANDAS_QUERIES
+from benchmarks.pandas_tpch import PANDAS_QUERIES, assert_frames_match
 from benchmarks.tpch import QUERIES, generate_tpch
 from dask_sql_tpu import Context
 
@@ -27,35 +27,9 @@ def tpch():
     return c, data
 
 
-def _normalize(df: pd.DataFrame) -> pd.DataFrame:
-    out = df.copy().reset_index(drop=True)
-    for col in out.columns:
-        s = out[col]
-        if pd.api.types.is_datetime64_any_dtype(s):
-            out[col] = pd.to_datetime(s)
-        elif pd.api.types.is_float_dtype(s):
-            out[col] = s.astype(np.float64).round(6)
-        elif pd.api.types.is_bool_dtype(s):
-            out[col] = s.astype(bool)
-        elif pd.api.types.is_integer_dtype(s):
-            out[col] = s.astype(np.int64)
-        else:
-            out[col] = s.astype(str)
-    return out
-
-
 @pytest.mark.parametrize("qid", sorted(QUERIES))
 def test_engine_matches_pandas(tpch, qid):
     c, data = tpch
     eng = c.sql(QUERIES[qid], return_futures=False)
     ref = PANDAS_QUERIES[qid](data)
-    assert len(eng.columns) == len(ref.columns), (
-        f"Q{qid}: column count {list(eng.columns)} vs {list(ref.columns)}")
-    # compare positionally: both follow the SELECT list order
-    ref = ref.rename(columns=dict(zip(ref.columns, eng.columns)))
-    eng_n, ref_n = _normalize(eng), _normalize(ref)
-    cols = list(eng_n.columns)
-    eng_n = eng_n.sort_values(cols, ignore_index=True)
-    ref_n = ref_n.sort_values(cols, ignore_index=True)
-    pd.testing.assert_frame_equal(eng_n, ref_n, check_dtype=False,
-                                  rtol=1e-5, atol=1e-6)
+    assert_frames_match(eng, ref, f"Q{qid}")

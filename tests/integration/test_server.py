@@ -86,7 +86,7 @@ def test_aggregate_via_server(server):
 
 def test_stats_filled(server):
     """The reference returns hardcoded zero stats (responses.py:11-49);
-    ours must carry real execution telemetry (VERDICT r1 item 7)."""
+    ours must carry real execution telemetry (review r1 item 7)."""
     payload = _run_to_completion(server, "SELECT a, COUNT(*) AS n FROM df "
                                          "GROUP BY a")
     stats = payload["stats"]

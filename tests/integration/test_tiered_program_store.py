@@ -91,6 +91,22 @@ def test_fresh_load_executes_with_zero_compiles(c, pstore):
                                   check_dtype=False)
 
 
+def test_one_device_program_reloads_in_an_8_device_process(c, pstore):
+    """The load must name the devices the program was compiled for: left
+    to jax 0.9's default (every local device) it died here with "Expected
+    args ... to have 8 shards", was counted as a store ERROR and silently
+    recompiled — the store never hit on any multi-device host."""
+    assert len(jax.devices()) == 8
+    c.sql(QUERY, return_futures=False)
+    _forget_programs()
+    c1 = tel.REGISTRY.counters()
+    c.sql(QUERY, return_futures=False)
+    d = _deltas(c1)
+    assert d.get("program_store_hits", 0) >= 1, d
+    assert d.get("program_store_errors", 0) == 0, d
+    assert d.get("compiles", 0) == 0, d
+
+
 def test_store_caps_survive_fresh_process(c, pstore):
     # long_table overflows the default group cap? No — 3 groups.  Force an
     # escalation instead via a tiny learned cap, then prove the RE-stored

@@ -195,3 +195,21 @@ def test_native_errors_match_python_positions(sql):
     with pytest.raises(ParsingException):
         Parser(sql).parse_statements()
     assert "^" in str(native_exc.value) or "Unterminated" in str(native_exc.value)
+
+
+@pytest.mark.parametrize("sql", [
+    "PREPARE above AS SELECT a, b FROM t WHERE a > ?",
+    "EXECUTE above (5, 'x')",
+    "DEALLOCATE above",
+    "SELECT a FROM t WHERE a > $1 AND b < $2",
+    "SELECT a FROM t WHERE a > ? AND b < ?",
+])
+def test_statements_native_grammar_lacks_still_parse(sql):
+    """With the native library LOADED, ``parse_sql`` must serve prepared
+    statements and parameter markers from the Python parser: the C++
+    grammar has no PREPARE/EXECUTE/DEALLOCATE, refuses ``$n`` and numbers
+    every ``?`` as parameter 0, and its parse errors are raised, not
+    bounced."""
+    from dask_sql_tpu.sql.parser import parse_sql
+    assert native.load() is not None
+    assert parse_sql(sql) == Parser(sql).parse_statements()

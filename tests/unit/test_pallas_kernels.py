@@ -90,10 +90,7 @@ def test_xla_blocked_nonfinite_safe_wrapper():
     assert np.isnan(got[0, 0]) and got[0, 1] == 3.0 and got[0, 2] == np.inf
 
 
-@pytest.mark.parametrize("n,g,a", [(100, 3, 1), (5000, 25, 3), (9000, 8, 2)])
-def test_segmented_sums_exact_matches_oracle_bitwise(n, g, a):
-    """The limb kernel's claim is EXACTNESS on integer-grid values (scaled
-    decimals / counts), including negatives and magnitudes near 2**52."""
+def _assert_exact_matches_oracle_bitwise(n, g, a):
     rng = np.random.RandomState(11)
     # integer grid up to ~1e9 per value plus a few +-2**50 outliers: total
     # magnitude stays inside the kernel's sum(|v|) < 2**53 contract (the
@@ -115,6 +112,22 @@ def test_segmented_sums_exact_matches_oracle_bitwise(n, g, a):
         want[:, gg] = vn[:, mn & (cn == gg)].sum(axis=1)
     assert np.array_equal(got, want.astype(np.float64)), (
         np.abs(got - want).max())
+
+
+@pytest.mark.parametrize("n,g,a", [(100, 3, 1), (5000, 25, 3), (9000, 8, 2)])
+def test_segmented_sums_exact_matches_oracle_bitwise(n, g, a):
+    """The limb kernel's claim is EXACTNESS on integer-grid values (scaled
+    decimals / counts), including negatives and magnitudes near 2**52."""
+    _assert_exact_matches_oracle_bitwise(n, g, a)
+
+
+@pytest.mark.parametrize("n", [2 * 8192 + 1, 2 * 8192 + 1234, 3 * 8192])
+def test_segmented_sums_exact_looped_slabs_bitwise(monkeypatch, n):
+    """More rows than one slab: the slab body is traced once and looped,
+    and the last slab overlaps the one before it (those rows masked out) —
+    no row may be counted twice or dropped."""
+    monkeypatch.setattr(pk, "SLAB_EXACT", 2 * pk.BLOCK_EXACT)
+    _assert_exact_matches_oracle_bitwise(n, 7, 2)
 
 
 def test_segmented_sums_exact_nonfinite_masked_rows_ignored():

@@ -102,6 +102,27 @@ def test_store_round_trip(store):
     assert got["fingerprint"] == ps.runtime_fingerprint()
 
 
+def test_program_reloads_on_the_devices_it_was_compiled_for():
+    """jax 0.9's ``deserialize_and_load`` loads onto EVERY local device by
+    default; a one-device program then refuses its one-shard arguments in
+    this 8-device process.  The pair in program_store carries the device
+    ids across."""
+    import jax
+    import jax.numpy as jnp
+    import pickle
+
+    dev = jax.devices()[3]
+    x = jax.device_put(jnp.arange(8.0), dev)
+    compiled = jax.jit(lambda a: (a * 2, a.sum())).lower(x).compile()
+    rec = pickle.loads(pickle.dumps(ps.serialize_program(compiled)))
+    assert rec["devices"] == [dev.id]
+    doubled, total = ps.load_program(rec, n_args=1, n_outs=2)(x)
+    assert doubled.devices() == {dev} and float(total) == 28.0
+    rec["devices"] = [10 ** 6]  # a device this process does not have
+    with pytest.raises(KeyError):
+        ps.load_program(rec, n_args=1, n_outs=2)
+
+
 def test_store_miss_counts(store):
     before = tel.REGISTRY.get("program_store_misses")
     assert store.load(store.digest("never-stored")) is None

@@ -163,7 +163,7 @@ def test_retry_transient_exhausts_typed(monkeypatch):
     monkeypatch.setenv("DSQL_RETRY_MAX", "1")
 
     def always():
-        raise OSError("tunnel down")   # classifies transient
+        raise OSError("link down")   # classifies transient
 
     with pytest.raises(R.TransientError):
         R.retry_transient(always, site="t")
