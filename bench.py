@@ -1013,20 +1013,6 @@ def _stage_main():
                         bd = {k: round(v, 1) for k, v in t.items()}
                     if left() < 20:
                         break
-                # one extra DSQL_TIME_DEVICE rep: splits the exec wall
-                # into device dispatch+compute vs host materialize (it
-                # costs an extra device sync, so it never contaminates
-                # the recorded best — its split just joins the breakdown)
-                if left() > 30 and "DSQL_TIME_DEVICE" not in os.environ:
-                    os.environ["DSQL_TIME_DEVICE"] = "1"
-                    try:
-                        c.sql(QUERIES[qid], return_futures=False)
-                        t = getattr(c, "last_timings", None) or {}
-                        for k in ("device_ms", "materialize_ms"):
-                            if k in t and bd is not None:
-                                bd[k] = round(t[k], 1)
-                    finally:
-                        del os.environ["DSQL_TIME_DEVICE"]
             except Exception as e:
                 # a transient failure here must not cost the stage_done record
                 # — every number is already journaled

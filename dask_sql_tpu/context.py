@@ -618,9 +618,9 @@ class Context:
                 self.last_report = trace.report
                 timings = getattr(self, "last_timings", None)
                 if timings is not None:
-                    # compile/device/materialize phase split joins the
+                    # compile/materialize phase split joins the
                     # bench-journaled breakdown (attributable BENCH_r*.json)
-                    for k in ("compile", "device", "materialize"):
+                    for k in ("compile", "materialize"):
                         v = trace.report.phases.get(k)
                         if v is not None:
                             timings[f"{k}_ms"] = v
@@ -718,34 +718,43 @@ class Context:
         # catalog epochs + table uids) replays its materialized result and
         # skips device execution entirely; volatile plans key to None
         cache = _rc.get_cache()
-        ckey = _rc.plan_key(plan, self) if cache.enabled() else None
-        if ckey is not None:
-            # EXPLAIN PROFILE measures a real execution: the lookup is
-            # skipped (the store below still refreshes the entry)
-            if getattr(self, "_rc_bypass", False):
-                _tel.annotate(result_cache="bypass")
-            else:
-                hit = cache.get(ckey)
-                if hit is not None:
-                    table, tier = hit
-                    _tel.inc("result_cache_hits")
-                    _tel.annotate(result_cache="hit",
-                                  result_cache_tier=tier)
-                    # the hit bypasses execution, so stamp the plan
-                    # fingerprint HERE: the cache-hit envelope keeps the
-                    # hot query's rank in system.view_candidates accruing
-                    # (the candidate-starvation fix)
-                    if os.environ.get("DSQL_HISTORY_FILE"):
-                        try:
-                            from .runtime import flight_recorder as _fr
-                            fp = _fr.plan_fingerprint(plan, self)
-                            if fp is not None:
-                                _tel.annotate(plan_fp=fp)
-                        except Exception:
-                            logger.debug("plan fingerprint failed",
-                                         exc_info=True)
-                    return table
-                _tel.inc("result_cache_misses")
+        ckey = None
+        if cache.enabled():
+            # the probe: key build and lookup (the store below is the same
+            # phase; phases sum by span name)
+            with _tel.span("result_cache"):
+                ckey = _rc.plan_key(plan, self)
+                hit = None
+                if ckey is None:
+                    pass  # volatile plan: nothing to probe or store
+                elif getattr(self, "_rc_bypass", False):
+                    # EXPLAIN PROFILE measures a real execution: the lookup
+                    # is skipped (the store below still refreshes the entry)
+                    _tel.annotate(result_cache="bypass")
+                else:
+                    hit = cache.get(ckey)
+                    if hit is None:
+                        _tel.inc("result_cache_misses")
+                        _tel.annotate(result_cache="miss")
+                    else:
+                        _tel.inc("result_cache_hits")
+                        _tel.annotate(result_cache="hit",
+                                      result_cache_tier=hit[1])
+            if hit is not None:
+                # the hit bypasses execution, so stamp the plan
+                # fingerprint HERE: the cache-hit envelope keeps the
+                # hot query's rank in system.view_candidates accruing
+                # (the candidate-starvation fix)
+                if os.environ.get("DSQL_HISTORY_FILE"):
+                    try:
+                        from .runtime import flight_recorder as _fr
+                        fp = _fr.plan_fingerprint(plan, self)
+                        if fp is not None:
+                            _tel.annotate(plan_fp=fp)
+                    except Exception:
+                        logger.debug("plan fingerprint failed",
+                                     exc_info=True)
+                return hit[0]
         autopilot_on = (os.environ.get("DSQL_AUTOPILOT", "0").strip()
                         not in ("", "0"))
         # flight recorder (runtime/flight_recorder.py): stamp the canonical
@@ -803,9 +812,10 @@ class Context:
             # populate only on the success path: a crashed /
             # deadline-exceeded execution raised before this line and
             # never reaches the cache
-            if ckey is not None and result is not None \
-                    and cache.put(ckey, result):
-                _tel.annotate(result_cache="store")
+            if ckey is not None and result is not None:
+                with _tel.span("result_cache"):
+                    if cache.put(ckey, result):
+                        _tel.annotate(result_cache="store")
             return result
         finally:
             if autopilot_on:

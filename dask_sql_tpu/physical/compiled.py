@@ -102,56 +102,6 @@ _DENY_OPS = {"RAND", "RAND_INTEGER"}
 stats = _tel.CounterAlias()
 
 
-class _ExecProfileAlias:
-    """DEPRECATED thread-local view of the DSQL_TIME_DEVICE exec split.
-
-    The old process-global dict raced: concurrent server queries clobbered
-    each other's device/materialize timings.  Each query thread now owns
-    its profile (telemetry.exec_profile()) and the authoritative numbers
-    land on the query's span / QueryReport; this alias keeps the
-    ``compiled.last_exec_profile`` surface readable per thread."""
-
-    def get(self, key, default=None):
-        return _tel.exec_profile().get(key, default)
-
-    def pop(self, key, default=None):
-        return _tel.exec_profile().pop(key, default)
-
-    def __getitem__(self, key):
-        return _tel.exec_profile()[key]
-
-    def __setitem__(self, key, value):
-        _tel.exec_profile()[key] = value
-
-    def __contains__(self, key):
-        return key in _tel.exec_profile()
-
-    def __iter__(self):
-        return iter(_tel.exec_profile())
-
-    def __len__(self):
-        return len(_tel.exec_profile())
-
-    def keys(self):
-        # dict(alias) goes through keys(); without it dict() would try to
-        # consume the iterator as key-value PAIRS
-        return _tel.exec_profile().keys()
-
-    def items(self):
-        return _tel.exec_profile().items()
-
-    def clear(self):
-        _tel.exec_profile().clear()
-
-    def __repr__(self):  # pragma: no cover - debugging nicety
-        return repr(_tel.exec_profile())
-
-
-# DSQL_TIME_DEVICE=1 diagnostic: per-call split of the execute wall into
-# dispatch+device-compute vs host materialize (see try_execute_compiled)
-last_exec_profile = _ExecProfileAlias()
-
-
 class Unsupported(Exception):
     """Plan (or expression) outside the compilable subset."""
 
@@ -969,7 +919,10 @@ class _Tracer:
         m = getattr(self, "_" + type(rel).__name__, None)
         if m is None:
             raise Unsupported(type(rel).__name__)
-        return m(rel)
+        # trace time only: every op this node lowers to carries the node's
+        # type in its op_name, which is how a device trace names it
+        with jax.named_scope("dsql." + type(rel).__name__):
+            return m(rel)
 
     # -- nodes -------------------------------------------------------------
     def _LogicalTableScan(self, rel: LogicalTableScan) -> _VT:
@@ -1021,11 +974,12 @@ class _Tracer:
         cap = min(self.caps.get(tag, default_cap), n)
         if cap * 2 >= n:
             return vt  # learned: not selective enough to pay the gathers
-        mask = vt.vmask()
-        count = mask.sum()
-        idx = jnp.nonzero(mask, size=cap, fill_value=0)[0]
-        row_valid = jnp.arange(cap) < count
-        cols = [c.take(idx) for c in vt.table.columns]
+        with jax.named_scope("dsql.compact"):
+            mask = vt.vmask()
+            count = mask.sum()
+            idx = jnp.nonzero(mask, size=cap, fill_value=0)[0]
+            row_valid = jnp.arange(cap) < count
+            cols = [c.take(idx) for c in vt.table.columns]
         # count > cap rows were silently dropped: the flags check raises
         # _NeedsRecompile before any result materializes
         self.ngroups.append(count)
@@ -1271,9 +1225,10 @@ class _Tracer:
             mxu_rows.append(crow)
             row_classes.append("unit")
 
-        stack = jnp.stack(mxu_rows)
-        red = pk.segmented_sums_dispatch(stack, codes, kmask, domain,
-                                         row_classes=row_classes)
+        with jax.named_scope("dsql.groupby_limbs"):
+            stack = jnp.stack(mxu_rows)
+            red = pk.segmented_sums_dispatch(stack, codes, kmask, domain,
+                                             row_classes=row_classes)
         occupancy = red[0] > 0
 
         from ..types import physical_dtype
@@ -1635,25 +1590,29 @@ class _Tracer:
                        None if c0.mask is None else jnp.zeros(npr, bool),
                        c0.dictionary)
                 for c0 in build.table.columns]
-        order = jnp.argsort(bh)
-        bh_sorted = bh[order]
-        # duplicate build keys / hash collisions appear as adjacent equal
-        # hashes in sorted order (same flag policy as every strategy)
-        adj = (bh_sorted[1:] == bh_sorted[:-1]) & (bh_sorted[1:] != _U64_MAX)
-        raws_sorted = [braw[order] for _, braw in bparts]
-        self._append_join_flags(
-            jt, adj, [rs[1:] != rs[:-1] for rs in raws_sorted])
+        with jax.named_scope("dsql.join_build"):
+            order = jnp.argsort(bh)
+            bh_sorted = bh[order]
+            # duplicate build keys / hash collisions appear as adjacent
+            # equal hashes in sorted order (same flag policy as every
+            # strategy)
+            adj = ((bh_sorted[1:] == bh_sorted[:-1])
+                   & (bh_sorted[1:] != _U64_MAX))
+            raws_sorted = [braw[order] for _, braw in bparts]
+            self._append_join_flags(
+                jt, adj, [rs[1:] != rs[:-1] for rs in raws_sorted])
 
-        pos = jnp.searchsorted(bh_sorted, ph, side="left", method="sort")
-        in_range = pos < nb
-        pos_c = jnp.minimum(pos, nb - 1)
-        cand = order[pos_c]
-        match = in_range & pvalid & (bh_sorted[pos_c] == ph)
-        for (_, praw), (_, braw) in zip(pparts, bparts):
-            match = match & (praw == braw[cand])
-        if jt in ("SEMI", "ANTI"):
-            return match, None
-        return match, [c0.take(cand) for c0 in build.table.columns]
+        with jax.named_scope("dsql.join_probe"):
+            pos = jnp.searchsorted(bh_sorted, ph, side="left", method="sort")
+            in_range = pos < nb
+            pos_c = jnp.minimum(pos, nb - 1)
+            cand = order[pos_c]
+            match = in_range & pvalid & (bh_sorted[pos_c] == ph)
+            for (_, praw), (_, braw) in zip(pparts, bparts):
+                match = match & (praw == braw[cand])
+            if jt in ("SEMI", "ANTI"):
+                return match, None
+            return match, [c0.take(cand) for c0 in build.table.columns]
 
     def _join_merge_payload(self, jt, probe: _VT, build: _VT, pparts,
                             bparts, pvalid: jax.Array, ph: jax.Array,
@@ -1822,8 +1781,9 @@ class _Tracer:
                 fits = combo_ok & (span_prod <= jnp.float64(size))
                 direct_b = (bkey, jnp.int64(0), fits)
                 direct_p = (pkey, jnp.int64(0), fits)
-        slot, resident, resolved, table, rounds = _hash_table_insert(
-            bh, bvalid, size, direct_b)
+        with jax.named_scope("dsql.join_build"):
+            slot, resident, resolved, table, rounds = _hash_table_insert(
+                bh, bvalid, size, direct_b)
 
         raw_mismatch = jnp.zeros((), bool)
         if not bij:
@@ -1864,8 +1824,9 @@ class _Tracer:
             k, _ = st
             return k < rounds
 
-        _, cand = jax.lax.while_loop(
-            probe_cond, probe_body, (jnp.int32(0), jnp.full(npr, nb32)))
+        with jax.named_scope("dsql.join_probe"):
+            _, cand = jax.lax.while_loop(
+                probe_cond, probe_body, (jnp.int32(0), jnp.full(npr, nb32)))
         found = cand < nb32
         cc = jnp.clip(cand, 0, nb - 1)
         match = found & pvalid
@@ -1926,10 +1887,12 @@ class _Tracer:
 # ---------------------------------------------------------------------------
 
 class _Compiled:
-    __slots__ = ("fn", "spec", "meta", "caps", "key", "origin", "aot")
+    __slots__ = ("fn", "name", "spec", "meta", "caps", "key", "origin", "aot")
 
-    def __init__(self, fn, spec, meta, caps, key, origin=None, aot=False):
+    def __init__(self, fn, name, spec, meta, caps, key, origin=None,
+                 aot=False):
         self.fn = fn
+        self.name = name        # the XLA module's name (_program_name)
         self.spec = spec
         self.meta = meta        # filled during first trace
         self.caps = caps
@@ -2051,6 +2014,15 @@ def _pstore_digest(base_key) -> str:
     return _pstore.get_store().digest(_canonical_program_key(base_key))
 
 
+def _program_name(plan: RelNode, base_key) -> str:
+    """The name a program's XLA module carries (``jit_<name>`` on a
+    trace's ``XLA Modules`` line).  XLA's persistent-cache key includes it,
+    so it has to come out the same in every process for the same program:
+    the root node's type and the canonical digest, never a table uid or an
+    ``id()``."""
+    return f"dsql_{type(plan).__name__}_{_pstore_digest(base_key)[:8]}"
+
+
 def _profile_on() -> bool:
     """Device profiler armed?  Checked BEFORE importing runtime.profiler
     so a disabled profiler costs one env read and zero imports."""
@@ -2101,7 +2073,7 @@ def _pstore_put(entry: _Compiled, base_key, n_args: int, n_outs: int
     store.store(_pstore_digest(base_key), rec)
 
 
-def _pstore_attempt(base_key, flat, query_fp: str = ""):
+def _pstore_attempt(plan: RelNode, base_key, flat, query_fp: str = ""):
     """Load + execute this program from the persistent store.
 
     Returns (entry, outs, caps) on a hit — the executable deserialized
@@ -2122,7 +2094,8 @@ def _pstore_attempt(base_key, flat, query_fp: str = ""):
             raise ValueError("entry layout mismatch")
         fn = _pstore.load_program(raw, len(flat), int(raw["n_outs"]))
         caps = {str(k): int(v) for k, v in (raw.get("caps") or {}).items()}
-        entry = _Compiled(fn, raw["spec"], raw["meta"], caps,
+        entry = _Compiled(fn, _program_name(plan, base_key), raw["spec"],
+                          raw["meta"], caps,
                           (base_key, tuple(sorted(caps.items()))), aot=True)
         outs = entry.fn(*flat)
     except (KeyboardInterrupt, SystemExit):
@@ -2239,8 +2212,13 @@ def _build(plan: RelNode, context, scans, caps: Dict[str, int], key,
                             for c in tbl.columns], tbl.names,
                      row_valid is not None))
     meta: dict = {}
+    name = _program_name(plan, key[0])
 
-    def fn(*flat):
+    def fn(*dsql_input):
+        # the parameters' name reaches the device trace too: the ops XLA
+        # hangs on a program's parameters (on TPU, the f64 split of every
+        # scanned column) read ``dsql_input[i]`` there, outside any scope
+        flat = dsql_input
         i = 0
         tables: Dict[tuple, Tuple[Table, Optional[jax.Array]]] = {}
         for skey, colspec, names, has_valid in spec:
@@ -2294,7 +2272,8 @@ def _build(plan: RelNode, context, scans, caps: Dict[str, int], key,
             outs.append(out.valid)
         return tuple(outs)
 
-    return _Compiled(jax.jit(fn), spec, meta, dict(caps), key, origin)
+    fn.__name__ = fn.__qualname__ = name
+    return _Compiled(jax.jit(fn), name, spec, meta, dict(caps), key, origin)
 
 
 class _NeedsRecompile(Exception):
@@ -2425,6 +2404,8 @@ def _materialize(entry: _Compiled, outs) -> Table:
     _faults.maybe_fail("materialize")
     meta = entry.meta
     total_bytes = sum(int(getattr(o, "nbytes", 0)) for o in outs)
+    _tel.annotate(bytes=total_bytes,
+                  small_fetch=total_bytes <= SMALL_FETCH_BYTES)
     if total_bytes <= SMALL_FETCH_BYTES:
         # small result: ONE blocking transfer for flags + all outputs, then
         # compact on host — each extra sync is a full device round
@@ -2682,12 +2663,6 @@ def _record_stage_stats(st, idx: int, out: Table, query_fp: str,
         digest = (st.scan.table_name if st.scan is not None
                   else f"root:{query_fp}")
         capacity = 1 << max((max(rows_out, 1) - 1).bit_length(), 6)
-        # device time, when DSQL_TIME_DEVICE split it out onto child spans
-        device_ms = 0.0
-        sp = _tel.current_span()
-        if sp is not None:
-            for s in sp.walk():
-                device_ms += float(s.attrs.get("device_ms", 0.0) or 0.0)
         # the span carries the measurements too: record_query sums
         # stage_bytes into the query's measured working set at close
         _tel.annotate(stage_digest=digest, stage_rows_in=rows_in,
@@ -2697,13 +2672,11 @@ def _record_stage_stats(st, idx: int, out: Table, query_fp: str,
             # measured side of the model-vs-measured ledger: what the
             # stage actually touched, against the compile-time prediction
             from ..runtime import profiler as _prof
-            _prof.record_measured(digest, nbytes=nbytes, wall_ms=wall_ms,
-                                  device_ms=device_ms or None)
+            _prof.record_measured(digest, nbytes=nbytes, wall_ms=wall_ms)
         if os.environ.get("DSQL_HISTORY_FILE"):
             _fr.record_stage(digest, rows_in=rows_in, rows_out=rows_out,
                              capacity=capacity, nbytes=nbytes,
-                             wall_ms=wall_ms, device_ms=device_ms or None,
-                             query_fp=query_fp)
+                             wall_ms=wall_ms, query_fp=query_fp)
         if _events_on():
             from ..runtime import events as _ev
             _ev.publish("stage.done", digest=digest, index=idx,
@@ -3191,33 +3164,36 @@ def try_execute_compiled(plan: RelNode, context,
     # digests, EWMA keys) sees the SHAPE while the values ride as trailing
     # jit args.  The eager/SPMD/result-cache paths never see this plan —
     # they key on values, which stays correct.
-    plan = _maybe_parameterize(plan)
-    scans: list = []
-    try:
-        plan_fp = _fp_plan(plan, context, scans)
-    except Unsupported as e:
-        logger.debug("not compilable: %s", e)
-        _tel.inc("unsupported")
-        return None
-    base_key = (plan_fp, _fp_inputs(scans), bool(_on_tpu()),
+    with _tel.span("lookup"):
+        plan = _maybe_parameterize(plan)
+        scans: list = []
+        try:
+            plan_fp = _fp_plan(plan, context, scans)
+        except Unsupported as e:
+            logger.debug("not compilable: %s", e)
+            _tel.inc("unsupported")
+            return None
+        base_key = (plan_fp, _fp_inputs(scans), bool(_on_tpu()),
                     _mesh_signature(context))
 
-    budget_override = _split_limit
-    heavy = _heavy_count(plan)
-    if budget_override is None and heavy > 1:
-        # learned budget hint: a plan whose whole program crashed the
-        # TPU compiler (observed in BENCH_r05: helper SIGSEGV / silent loss on
-        # TPC-H Q3's fused sort-pipeline) carries "__split__" in its
-        # learned-caps entry, so every later process stages it immediately
-        # instead of re-crashing the compiler
-        hint = _learned_caps_get(base_key).get("__split__")
-        if hint is not None:
-            budget_override = int(hint)
-    budget = stage_budget(budget_override)
-    # tiered execution: a cold plan answers on the eager tier NOW while
-    # its stage programs compile in the background; warm (or decided)
-    # plans fall through to the normal compiled path
-    if _tier_serve_eager(plan, context, base_key, budget, _split_limit):
+        budget_override = _split_limit
+        heavy = _heavy_count(plan)
+        if budget_override is None and heavy > 1:
+            # learned budget hint: a plan whose whole program crashed the
+            # TPU compiler (observed in BENCH_r05: helper SIGSEGV / silent
+            # loss on TPC-H Q3's fused sort-pipeline) carries "__split__" in
+            # its learned-caps entry, so every later process stages it
+            # immediately instead of re-crashing the compiler
+            hint = _learned_caps_get(base_key).get("__split__")
+            if hint is not None:
+                budget_override = int(hint)
+        budget = stage_budget(budget_override)
+        # tiered execution: a cold plan answers on the eager tier NOW while
+        # its stage programs compile in the background; warm (or decided)
+        # plans fall through to the normal compiled path
+        serve_eager = _tier_serve_eager(plan, context, base_key, budget,
+                                        _split_limit)
+    if serve_eager:
         _tel.inc("served_eager_while_compiling")
         _tel.annotate(tier="eager-compiling")
         return None
@@ -3239,94 +3215,102 @@ def _execute_single(plan: RelNode, context, query_fp: str,
     different root is a cross-query stage reuse and is counted as such."""
     from ..ops.pallas_kernels import _strategy_on_tpu as _on_tpu
 
-    scans: list = []
-    params: list = []
-    try:
-        plan_fp = _fp_plan(plan, context, scans, params)
-    except Unsupported as e:
-        logger.debug("not compilable: %s", e)
-        _tel.inc("unsupported")
-        return None
-    base_key = (plan_fp, _fp_inputs(scans), bool(_on_tpu()),
-                    _mesh_signature(context))
-
-    host_sort = None
-    if not _on_tpu() and isinstance(plan, LogicalSort):
-        # Terminal ORDER BY/LIMIT runs on the HOST off-TPU: the result is
-        # fetched and compacted to its true row count by _materialize
-        # anyway, and sorting those rows costs microseconds, while the
-        # in-program device lexsort pays O(padded n) per collation key
-        # (~8 ms per key per 100k padded rows on XLA:CPU — it dominated
-        # Q2's profile).  On TPU the in-program sort stays: sorts are fast
-        # there and everything before the single fetch should fuse.
-        host_sort = plan
-        plan = plan.input
-        scans = []
-        params = []
+    # lookup: from the plan to the program's key; the cache probe in the
+    # loop below is the same phase (phases sum by span name)
+    with _tel.span("lookup"):
+        scans: list = []
+        params: list = []
         try:
             plan_fp = _fp_plan(plan, context, scans, params)
         except Unsupported as e:
             logger.debug("not compilable: %s", e)
             _tel.inc("unsupported")
             return None
-        # the backend joins the key: tracing picks backend-specific
-        # strategies (merge vs gather join), and with content-based input
-        # fingerprints a program — or an _UNSUPPORTED verdict — traced for
-        # one backend could otherwise replay on another
         base_key = (plan_fp, _fp_inputs(scans), bool(_on_tpu()),
                     _mesh_signature(context))
-    # runtime verdicts (non-unique build keys, hash collisions) depend on
-    # NUMERIC data the layout fingerprint cannot see, so they are pinned to
-    # the exact Tables via uid — a reload with corrected data must get a
-    # fresh chance at the compiled path, not inherit the old dataset's exile
-    runtime_key = (base_key, tuple(t.uid for _, t, _ in scans))
-    with _state_lock:
-        exiled_runtime = runtime_key in _runtime_eager
-    if exiled_runtime:
-        _tel.inc("fallbacks")
-        return None
-    caps: Dict[str, int] = _learned_caps_get(base_key)
-    # "__split__" is the learned budget hint, not an aggregate-site cap: it
-    # must not leak into the program cache key or _build's cap lookups
-    caps.pop("__split__", None)
-    # stats-derived starting caps for sites the engine has not yet LEARNED
-    # (runtime/statistics.py): setdefault keeps learned/escalated caps
-    # authoritative, and a too-small hint just trips the normal overflow
-    # escalation below — never a wrong result
-    from ..runtime import statistics as _stats
-    hints = _stats.compiled_cap_hints(plan, context)
-    for tag, cap in hints.items():
-        if tag not in caps:
-            caps[tag] = cap
-            _tel.inc("stats_cap_hints")
-            _tel.annotate(cap_hint=f"{tag}={cap}")
+
+        host_sort = None
+        if not _on_tpu() and isinstance(plan, LogicalSort):
+            # Terminal ORDER BY/LIMIT runs on the HOST off-TPU: the result
+            # is fetched and compacted to its true row count by _materialize
+            # anyway, and sorting those rows costs microseconds, while the
+            # in-program device lexsort pays O(padded n) per collation key
+            # (~8 ms per key per 100k padded rows on XLA:CPU — it dominated
+            # Q2's profile).  On TPU the in-program sort stays: sorts are
+            # fast there and everything before the single fetch should fuse.
+            host_sort = plan
+            plan = plan.input
+            scans = []
+            params = []
+            try:
+                plan_fp = _fp_plan(plan, context, scans, params)
+            except Unsupported as e:
+                logger.debug("not compilable: %s", e)
+                _tel.inc("unsupported")
+                return None
+            # the backend joins the key: tracing picks backend-specific
+            # strategies (merge vs gather join), and with content-based
+            # input fingerprints a program — or an _UNSUPPORTED verdict —
+            # traced for one backend could otherwise replay on another
+            base_key = (plan_fp, _fp_inputs(scans), bool(_on_tpu()),
+                        _mesh_signature(context))
+        # runtime verdicts (non-unique build keys, hash collisions) depend
+        # on NUMERIC data the layout fingerprint cannot see, so they are
+        # pinned to the exact Tables via uid — a reload with corrected data
+        # must get a fresh chance at the compiled path, not inherit the old
+        # dataset's exile
+        runtime_key = (base_key, tuple(t.uid for _, t, _ in scans))
+        with _state_lock:
+            exiled_runtime = runtime_key in _runtime_eager
+        if exiled_runtime:
+            _tel.inc("fallbacks")
+            return None
+        caps: Dict[str, int] = _learned_caps_get(base_key)
+        # "__split__" is the learned budget hint, not an aggregate-site cap:
+        # it must not leak into the program cache key or _build's cap lookups
+        caps.pop("__split__", None)
+        # stats-derived starting caps for sites the engine has not yet
+        # LEARNED (runtime/statistics.py): setdefault keeps learned caps
+        # authoritative, and a too-small hint just trips the normal overflow
+        # escalation below — never a wrong result
+        from ..runtime import statistics as _stats
+        hints = _stats.compiled_cap_hints(plan, context)
+        for tag, cap in hints.items():
+            if tag not in caps:
+                caps[tag] = cap
+                _tel.inc("stats_cap_hints")
+                _tel.annotate(cap_hint=f"{tag}={cap}")
     store_tried = False  # one persistent-store attempt per call, tops
     for _ in range(8):  # capacity-escalation bound
         _res.check("execute")
         key = (base_key, tuple(sorted(caps.items())))
         my_event = None
-        with _state_lock:
-            entry = _cache.get(key)
-            if entry is None:
-                other = _inflight.get(key)
-                if other is None:
-                    my_event = _threading.Event()
-                    _inflight[key] = my_event
-        if entry is None and my_event is None:
-            # another thread is compiling this exact program (concurrent
-            # warmup of queries sharing a stage): wait for its verdict
-            # instead of compiling a duplicate — but never past this
-            # query's own deadline
-            rem = None if _res.current() is None \
-                else _res.current().remaining()
-            other.wait(1800 if rem is None else max(min(rem, 1800), 1e-3))
-            _res.check("compile_wait")
+        with _tel.span("lookup", params=len(params)):
             with _state_lock:
                 entry = _cache.get(key)
                 if entry is None:
-                    # builder failed transiently — take over the build
-                    my_event = _threading.Event()
-                    _inflight[key] = my_event
+                    other = _inflight.get(key)
+                    if other is None:
+                        my_event = _threading.Event()
+                        _inflight[key] = my_event
+            if entry is None and my_event is None:
+                # another thread is compiling this exact program (concurrent
+                # warmup of queries sharing a stage): wait for its verdict
+                # instead of compiling a duplicate — but never past this
+                # query's own deadline
+                rem = None if _res.current() is None \
+                    else _res.current().remaining()
+                other.wait(1800 if rem is None
+                           else max(min(rem, 1800), 1e-3))
+                _res.check("compile_wait")
+                with _state_lock:
+                    entry = _cache.get(key)
+                    if entry is None:
+                        # builder failed transiently — take over the build
+                        my_event = _threading.Event()
+                        _inflight[key] = my_event
+            if entry is not None and entry is not _UNSUPPORTED:
+                _tel.annotate(cache_hit=True)
         if entry is _UNSUPPORTED:
             if my_event is not None:
                 with _state_lock:
@@ -3334,12 +3318,18 @@ def _execute_single(plan: RelNode, context, query_fp: str,
                 my_event.set()
             _tel.inc("unsupported")
             return None
-        flat = _flatten_tables(scans)
-        if params:
-            # bound-argument vector: the hoisted literals, after the table
-            # arrays — arity and treedef stay consistent everywhere flat
-            # flows (jit call, AOT lower, store n_args, store replay)
-            flat = flat + _param_args(params)
+        with _tel.span("bind", params=len(params)):
+            flat = _flatten_tables(scans)
+            h2d = 0
+            if params:
+                # bound-argument vector: the hoisted literals, after the
+                # table arrays — arity and treedef stay consistent
+                # everywhere flat flows (jit call, AOT lower, store n_args,
+                # store replay)
+                bound = _param_args(params)
+                h2d = sum(int(a.nbytes) for a in bound)
+                flat = flat + bound
+            _tel.annotate(args=len(flat), h2d_bytes=h2d)
         outs = None
         if entry is None and not store_tried and _pstore.get_store().enabled():
             # persistent program store: a prior process compiled this exact
@@ -3349,7 +3339,7 @@ def _execute_single(plan: RelNode, context, query_fp: str,
             # (they were learned by actually running this program).
             store_tried = True
             with _tel.span("program_store_load"):
-                got = _pstore_attempt(base_key, flat, query_fp)
+                got = _pstore_attempt(plan, base_key, flat, query_fp)
             if got is not None:
                 loaded, outs, caps = got
                 if params:
@@ -3517,7 +3507,6 @@ def _execute_single(plan: RelNode, context, query_fp: str,
                                         degrade[0], degrade[1], split_limit)
         elif outs is None:  # in-memory hit (a store load already ran once)
             _tel.inc("hits")
-            _tel.annotate(cache_hit=True)
             if params:
                 _tel.inc("param_plan_hits")
             if in_stage:
@@ -3540,42 +3529,16 @@ def _execute_single(plan: RelNode, context, query_fp: str,
                     logger.debug("cost replay failed", exc_info=True)
             with _state_lock:
                 _cache.move_to_end(key)
-            if os.environ.get("DSQL_TIME_DEVICE"):
-                # diagnostic split of exec wall: dispatch+device compute
-                # (block_until_ready) vs host materialize/decode.  Costs
-                # one extra device sync per call, so opt-in only.  The
-                # scratchpad is THREAD-LOCAL (telemetry.exec_profile) and
-                # the result lands on the query's own span — concurrent
-                # server queries no longer clobber each other's split.
-                t0 = time.perf_counter()
-                outs = entry.fn(*flat)
-                jax.block_until_ready(outs)
-                t1 = time.perf_counter()
-                prof = _tel.exec_profile()
-                prof["device_ms"] = (t1 - t0) * 1e3
-                prof["materialize_t0"] = t1
-                _tel.annotate(device_ms=prof["device_ms"])
-            else:
+            # asynchronous: the span is the host's cost of launching the
+            # program; the wait for the device is inside materialize
+            with _tel.span("dispatch", program=entry.name):
                 outs = entry.fn(*flat)
         try:
-            try:
-                with _tel.span("materialize"):
-                    result = _res.retry_transient(
-                        lambda: _materialize(entry, outs),
-                        site="materialize",
-                        passthrough=(_NeedsRecompile,))
-            finally:
-                # pop the DSQL_TIME_DEVICE timestamp on EVERY path: a
-                # _NeedsRecompile (or transfer failure) leaking it would
-                # stamp a bogus materialize_ms onto a later untimed call
-                prof = _tel.exec_profile()
-                _mt0 = prof.pop("materialize_t0", None)
-                if _mt0 is not None:
-                    # the "materialize" span above already carries this
-                    # wall; the scratchpad copy only serves the deprecated
-                    # last_exec_profile read surface
-                    prof["materialize_ms"] = \
-                        (time.perf_counter() - _mt0) * 1e3
+            with _tel.span("materialize"):
+                result = _res.retry_transient(
+                    lambda: _materialize(entry, outs),
+                    site="materialize",
+                    passthrough=(_NeedsRecompile,))
         except _NeedsRecompile as r:
             _tel.inc("recompiles")
             caps = r.caps

@@ -5,10 +5,8 @@ goes — parse vs plan vs (the dominant: seconds to minutes per program)
 compile vs device execute vs host materialize.  Flare (PAPERS.md) makes the
 same argument for Spark native compilation.  Before this module that
 visibility was scattered and partly broken: a module-global ``stats`` dict
-in physical/compiled.py with unlocked ``+= 1`` read-modify-writes, a
-process-global ``last_exec_profile`` that concurrent server queries
-clobbered, and ad-hoc counters in server/app.py.  Everything now funnels
-through here:
+in physical/compiled.py with unlocked ``+= 1`` read-modify-writes and
+ad-hoc counters in server/app.py.  Everything now funnels through here:
 
 **Span tracer.**  ``trace_scope(sql)`` opens a per-query trace (the same
 thread-local propagation pattern as ``resilience.QueryRuntime``; worker
@@ -17,7 +15,14 @@ threads re-enter via ``scoped``, exactly like ``resilience.scoped``).
 attaches attributes (row/byte counts, cache hit/miss, degradation rung,
 retry counts) to the innermost open span.  Spans record wall time, the
 owning thread, and exceptions; child append is lock-protected because
-stage-graph workers attach concurrently.
+stage-graph workers attach concurrently.  Span times are
+``time.monotonic_ns()`` readings, and every span is also a
+``jax.profiler.TraceAnnotation`` named ``dsql:<span name>`` for its
+lifetime (a TraceMe: one atomic load while no profiler session is on), so
+any ``jax.profiler`` trace shows the engine's host phases on the device
+trace's own clock.  The root is ``dsql:query`` and carries the query's
+process-wide sequence number (``seq``), which ties a request's spans, its
+report and its events in the trace together.
 
 **Metrics registry.**  ``REGISTRY`` holds process-global thread-safe
 counters and bounded histograms.  It absorbs and deprecates the old
@@ -44,6 +49,7 @@ close.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import logging
 import os
@@ -51,6 +57,8 @@ import threading
 import time
 from contextlib import contextmanager
 from typing import Any, Callable, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 logger = logging.getLogger(__name__)
 
@@ -437,22 +445,25 @@ def inc(name: str, n: int = 1) -> None:
 # ---------------------------------------------------------------------------
 
 class Span:
-    """One timed node of a query's span tree."""
+    """One timed node of a query's span tree.  ``t0``/``t1`` are
+    ``time.monotonic_ns()`` readings (CLOCK_MONOTONIC: the clock a client
+    on the same machine reads), so a span lies on a profiler trace, or
+    beside a client's record, without an anchor."""
 
     __slots__ = ("name", "t0", "t1", "attrs", "children", "tid")
 
     def __init__(self, name: str, attrs: Optional[dict] = None):
         self.name = name
-        self.t0 = time.perf_counter()
-        self.t1: Optional[float] = None
+        self.t0 = time.monotonic_ns()
+        self.t1: Optional[int] = None
         self.attrs: Dict[str, Any] = dict(attrs) if attrs else {}
         self.children: List["Span"] = []
         self.tid = threading.get_ident()
 
     @property
     def wall_ms(self) -> float:
-        end = self.t1 if self.t1 is not None else time.perf_counter()
-        return (end - self.t0) * 1e3
+        end = self.t1 if self.t1 is not None else time.monotonic_ns()
+        return (end - self.t0) / 1e6
 
     def walk(self):
         yield self
@@ -460,9 +471,15 @@ class Span:
             yield from c.walk()
 
     def to_dict(self) -> dict:
-        return {"name": self.name, "wall_ms": round(self.wall_ms, 3),
+        return {"name": self.name, "t0_ns": self.t0,
+                "wall_ms": round(self.wall_ms, 3),
                 "attrs": dict(self.attrs),
                 "children": [c.to_dict() for c in self.children]}
+
+
+# process-wide query sequence: the root span's ``seq`` (next() on a count
+# is one bytecode under the interpreter lock)
+_query_seq = itertools.count(1)
 
 
 class QueryTrace:
@@ -476,7 +493,7 @@ class QueryTrace:
 
     def __init__(self, query: str = ""):
         self.query = query
-        self.root = Span("query")
+        self.root = Span("query", {"seq": next(_query_seq)})
         self.lock = threading.Lock()
         self.counters0 = REGISTRY.counters()
         self.report: Optional["QueryReport"] = None
@@ -487,7 +504,6 @@ class _Tls(threading.local):
     trace: Optional[QueryTrace] = None
     span: Optional[Span] = None
     node_recorder = None
-    exec_profile: Optional[Dict[str, float]] = None
     last_report: Optional["QueryReport"] = None
 
 
@@ -516,7 +532,54 @@ def scoped(trace: Optional[QueryTrace], parent: Optional[Span] = None):
         _tls.trace, _tls.span = prev_t, prev_s
 
 
-@contextmanager
+class _NoSpan:
+    """What ``span()`` hands out outside a trace: enters to None."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return None
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NO_SPAN = _NoSpan()
+
+
+class _OpenSpan:
+    """The context manager of one span (a class, not a generator: a warm
+    query opens a dozen of these, and each is a few microseconds)."""
+
+    __slots__ = ("_trace", "_parent", "_name", "_attrs", "_span",
+                 "_annotation")
+
+    def __init__(self, trace: QueryTrace, parent: Span, name: str,
+                 attrs: dict):
+        self._trace, self._parent = trace, parent
+        self._name, self._attrs = name, attrs
+
+    def __enter__(self) -> Span:
+        s = self._span = Span(self._name, self._attrs)
+        with self._trace.lock:
+            self._parent.children.append(s)
+        _tls.span = s
+        # opened and closed on the thread that runs the span: a stage on a
+        # ``scoped()`` worker lands on the worker's own line of the trace
+        self._annotation = _TraceAnnotation("dsql:" + self._name)
+        self._annotation.__enter__()
+        return s
+
+    def __exit__(self, exc_type, exc, tb):
+        self._annotation.__exit__(exc_type, exc, tb)
+        s = self._span
+        if exc_type is not None:
+            s.attrs["error"] = exc_type.__name__
+        s.t1 = time.monotonic_ns()
+        _tls.span = self._parent
+        return False
+
+
 def span(name: str, **attrs):
     """Open a child span under the current one; no-op outside a trace.
 
@@ -525,20 +588,8 @@ def span(name: str, **attrs):
     trace = _tls.trace
     parent = _tls.span
     if trace is None or parent is None:
-        yield None
-        return
-    s = Span(name, attrs)
-    with trace.lock:
-        parent.children.append(s)
-    _tls.span = s
-    try:
-        yield s
-    except BaseException as e:
-        s.attrs["error"] = type(e).__name__
-        raise
-    finally:
-        s.t1 = time.perf_counter()
-        _tls.span = parent
+        return _NO_SPAN
+    return _OpenSpan(trace, parent, name, attrs)
 
 
 def annotate(**attrs) -> None:
@@ -548,20 +599,12 @@ def annotate(**attrs) -> None:
         s.attrs.update(attrs)
 
 
-# ---------------------------------------------------------------------------
-# per-thread exec profile (the last_exec_profile race fix)
-# ---------------------------------------------------------------------------
-
-def exec_profile() -> Dict[str, float]:
-    """THIS thread's device/materialize timing scratchpad.
-
-    Replaces the old process-global ``compiled.last_exec_profile`` dict,
-    which concurrent server queries clobbered; each query thread now owns
-    its own, and the authoritative copy lands on the query's span."""
-    p = _tls.exec_profile
-    if p is None:
-        p = _tls.exec_profile = {}
-    return p
+def annotation(name: str, **args):
+    """A bare ``dsql:<name>`` event on the profiler's trace, for work done
+    outside any open query trace (the server encodes a page after the
+    query's trace has closed); ``args`` (the query's ``seq``, counts) ride
+    as the event's arguments."""
+    return _TraceAnnotation("dsql:" + name, **args)
 
 
 # ---------------------------------------------------------------------------
@@ -624,25 +667,30 @@ def _fleet_replica() -> Optional[str]:
         return None
 
 
-# span names that aggregate into the phase breakdown; "device"/"materialize"
-# values may also arrive as span ATTRS (device_ms) when DSQL_TIME_DEVICE
-# splits the execute wall
-_PHASE_SPANS = ("parse", "plan", "execute", "fetch", "compile",
-                "materialize", "stage", "stage_graph", "stream_batch",
-                "queued", "retry_backoff", "drain")
+# span names that aggregate into the phase breakdown
+_PHASE_SPANS = frozenset((
+    "parse", "plan", "execute", "fetch", "compile", "materialize", "stage",
+    "stage_graph", "stream_batch", "queued", "retry_backoff", "drain",
+    # the executor's host side, under execute (or a stage)
+    "result_cache", "lookup", "bind", "dispatch"))
+# span attribute -> kind of collective, for QueryReport.collective_bytes
+_COLLECTIVE_ATTRS = (("spmd_exchange_bytes", "all_to_all"),
+                     ("spmd_all_gather_bytes", "all_gather"),
+                     ("spmd_psum_bytes", "psum"))
 
 
 class QueryReport:
     """Everything one ``Context.sql`` call did, in one object.
 
     ``phases``: wall-ms sums per span name (parse/plan/execute/fetch at
-    the top level; compile/materialize/stage nested under execute — so
-    only parse+plan+execute+fetch partition the wall).  ``counters``:
+    the top level; result_cache/lookup/bind/dispatch/materialize/compile/
+    stage nested under execute — so only parse+plan+execute+fetch
+    partition the wall).  ``counters``:
     process-global registry deltas between trace open and close (exact
     per-query attribution when queries do not overlap; an upper bound
     under concurrency).  ``root``: the span tree."""
 
-    __slots__ = ("query", "wall_ms", "phases", "counters", "root",
+    __slots__ = ("query", "seq", "wall_ms", "phases", "counters", "root",
                  "rows_out", "bytes_out", "started_unix", "cache", "tier",
                  "priority", "operators", "spilled", "skew_ratio",
                  "collective_bytes", "cost_err", "trace_id", "tenant",
@@ -651,6 +699,7 @@ class QueryReport:
     def __init__(self, trace: QueryTrace):
         root = trace.root
         self.query = trace.query
+        self.seq = root.attrs.get("seq")
         self.started_unix = trace.started_unix
         self.wall_ms = root.wall_ms
         self.root = root
@@ -670,25 +719,12 @@ class QueryReport:
         self.replica = _fleet_replica()
         self.rows_out = int(root.attrs.get("rows_out", 0))
         self.bytes_out = int(root.attrs.get("bytes_out", 0))
+        # one pass over the tree collects everything below (a warm compiled
+        # query has a dozen spans, and this runs inside the caller's wait)
         phases: Dict[str, float] = {}
-        for s in root.walk():
-            if s is root:
-                continue
-            if s.name in _PHASE_SPANS:
-                phases[s.name] = phases.get(s.name, 0.0) + s.wall_ms
-            for k in ("device_ms", "materialize_ms"):
-                v = s.attrs.get(k)
-                if v is not None:
-                    key = k[:-3]
-                    phases[key] = phases.get(key, 0.0) + float(v)
-        self.phases = phases
-        now = REGISTRY.counters()
-        self.counters = {k: now[k] - trace.counters0.get(k, 0)
-                         for k in now
-                         if now[k] != trace.counters0.get(k, 0)}
         # result-cache section: exact per-query attribution from span attrs
-        # (runtime/result_cache.py annotates the execute/stage spans), plus
-        # the current tier sizes from the gauges
+        # (the ``result_cache`` spans carry the verdict), plus the current
+        # tier sizes from the gauges
         hit = False
         tier: Optional[str] = None
         stored = False
@@ -700,37 +736,12 @@ class QueryReport:
         # workload-manager class: the admission path stamps it on the
         # queued span; None when the scheduler is disabled
         priority: Optional[str] = None
-        for s in root.walk():
-            rc = s.attrs.get("result_cache")
-            if rc == "hit":
-                hit = True
-                tier = s.attrs.get("result_cache_tier", tier)
-            elif rc == "store":
-                stored = True
-            if s.attrs.get("subplan_cache") == "hit":
-                subplan_hits += 1
-            t = s.attrs.get("tier")
-            if t is not None and exec_tier is None:
-                exec_tier = str(t)
-            if s.name == "queued" and priority is None:
-                p = s.attrs.get("priority")
-                priority = str(p) if p is not None else None
-        self.tier = exec_tier
-        self.priority = priority
         # adaptive operator choices (runtime/statistics.py record_choice
         # appends "groupby=dense ..." lines to span attrs) in span order
         operators: List[str] = []
-        for s in root.walk():
-            ops = s.attrs.get("operators")
-            if ops:
-                operators.extend(str(o) for o in ops)
-        self.operators = operators
         # out-of-core marker: the grace-hash driver annotates its morsel
-        # spans with spilled=True; the counter delta catches spills from
-        # nested plans that never opened a span under this trace
-        self.spilled = (self.counters.get("spill_partitions", 0) > 0
-                        or any(s.attrs.get("spilled")
-                               for s in root.walk()))
+        # spans with spilled=True
+        spilled = False
         # device-level profile surface (ISSUE 13): worst shard/partition
         # skew (max/mean row ratio — SPMD stages and grace-hash morsel
         # joins both annotate ``skew_ratio``), collective bytes by kind,
@@ -741,21 +752,55 @@ class QueryReport:
         cost_bytes = 0.0
         measured = 0
         for s in root.walk():
-            r = s.attrs.get("skew_ratio")
+            if s is not root and s.name in _PHASE_SPANS:
+                phases[s.name] = phases.get(s.name, 0.0) + s.wall_ms
+            attrs = s.attrs
+            if not attrs:
+                continue
+            rc = attrs.get("result_cache")
+            if rc == "hit":
+                hit = True
+                tier = attrs.get("result_cache_tier", tier)
+            elif rc == "store":
+                stored = True
+            if attrs.get("subplan_cache") == "hit":
+                subplan_hits += 1
+            t = attrs.get("tier")
+            if t is not None and exec_tier is None:
+                exec_tier = str(t)
+            if s.name == "queued" and priority is None:
+                p = attrs.get("priority")
+                priority = str(p) if p is not None else None
+            ops = attrs.get("operators")
+            if ops:
+                operators.extend(str(o) for o in ops)
+            if attrs.get("spilled"):
+                spilled = True
+            r = attrs.get("skew_ratio")
             if r is not None:
                 skew = max(float(r), skew) if skew is not None else float(r)
-            for attr, kind in (("spmd_exchange_bytes", "all_to_all"),
-                               ("spmd_all_gather_bytes", "all_gather"),
-                               ("spmd_psum_bytes", "psum")):
-                v = s.attrs.get(attr)
+            for attr, kind in _COLLECTIVE_ATTRS:
+                v = attrs.get(attr)
                 if v:
                     coll[kind] = coll.get(kind, 0) + int(v)
-            cb = s.attrs.get("cost_bytes")
+            cb = attrs.get("cost_bytes")
             if cb:
                 cost_bytes += float(cb)
-            sb = s.attrs.get("stage_bytes")
+            sb = attrs.get("stage_bytes")
             if sb:
                 measured += int(sb)
+        self.phases = phases
+        now = REGISTRY.counters()
+        self.counters = {k: now[k] - trace.counters0.get(k, 0)
+                         for k in now
+                         if now[k] != trace.counters0.get(k, 0)}
+        self.tier = exec_tier
+        self.priority = priority
+        self.operators = operators
+        # the counter delta catches spills from nested plans that never
+        # opened a span under this trace
+        self.spilled = (spilled
+                        or self.counters.get("spill_partitions", 0) > 0)
         self.skew_ratio = round(skew, 3) if skew is not None else None
         self.collective_bytes = coll or None
         # measured working set mirrors the flight recorder's definition:
@@ -828,16 +873,17 @@ class QueryReport:
 
     def to_chrome_trace(self) -> dict:
         """chrome://tracing ("Trace Event Format") JSON of the span tree:
-        complete ("X") events in microseconds relative to the root."""
-        t0 = self.root.t0
+        complete ("X") events in microseconds on CLOCK_MONOTONIC, each
+        with its absolute ``t0_ns``."""
         events = []
         for s in self.root.walk():
-            end = s.t1 if s.t1 is not None else time.perf_counter()
+            end = s.t1 if s.t1 is not None else time.monotonic_ns()
             events.append({
                 "name": s.name, "ph": "X", "pid": os.getpid(),
                 "tid": s.tid,
-                "ts": round((s.t0 - t0) * 1e6, 1),
-                "dur": round((end - s.t0) * 1e6, 1),
+                "ts": s.t0 / 1e3,
+                "dur": (end - s.t0) / 1e3,
+                "t0_ns": s.t0,
                 "args": {k: (v if isinstance(v, (int, float, str, bool))
                              else repr(v))
                          for k, v in s.attrs.items()},
@@ -895,7 +941,7 @@ def close_background_trace(trace: QueryTrace) -> QueryReport:
     their own — physical/compiled._background_compile): builds the report
     and exports the chrome trace WITHOUT counting a query, arming the
     slow-query log, or recording a history envelope."""
-    trace.root.t1 = time.perf_counter()
+    trace.root.t1 = time.monotonic_ns()
     report = QueryReport(trace)
     trace.report = report
     _export_chrome_trace(report)
@@ -903,7 +949,7 @@ def close_background_trace(trace: QueryTrace) -> QueryReport:
 
 
 def _close_trace(trace: QueryTrace, error: Optional[BaseException]) -> None:
-    trace.root.t1 = time.perf_counter()
+    trace.root.t1 = time.monotonic_ns()
     if error is not None:
         trace.root.attrs["error"] = type(error).__name__
         REGISTRY.inc("query_errors")
@@ -991,7 +1037,6 @@ def trace_scope(query: str = ""):
     trace = QueryTrace(query)
     _tls.trace = trace
     _tls.span = trace.root
-    _tls.exec_profile = {}
     # live-query registry for system.active / GET /v1/engine — gated on the
     # recorder's env knob so the disabled path allocates nothing
     registered = False
@@ -1012,7 +1057,8 @@ def trace_scope(query: str = ""):
             logger.debug("event trace-open hook failed", exc_info=True)
     err: Optional[BaseException] = None
     try:
-        yield trace
+        with _TraceAnnotation("dsql:query", seq=trace.root.attrs["seq"]):
+            yield trace
     except BaseException as e:
         err = e
         raise

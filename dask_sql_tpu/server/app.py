@@ -240,7 +240,7 @@ class _QueryInfo:
                  "bytes", "peak_memory", "compiles", "cache_hits", "phases",
                  "cache_hit", "cache_tier", "subplan_cache_hits",
                  "queued_ms", "tier", "program_store_hits", "operators",
-                 "trace_id")
+                 "trace_id", "seq")
 
     def __init__(self):
         self.submitted = time.monotonic()
@@ -261,6 +261,7 @@ class _QueryInfo:
         self.program_store_hits = 0
         self.operators = []
         self.trace_id = None
+        self.seq = None     # the query's telemetry sequence number
 
 
 def _run_tracked(context, sql: str, info: _QueryInfo,
@@ -331,6 +332,7 @@ def _run_tracked(context, sql: str, info: _QueryInfo,
         report = _tel.last_report()
         if report is not None:
             info.phases = dict(report.phases)
+            info.seq = report.seq
             cache = getattr(report, "cache", None) or {}
             info.cache_hit = bool(cache.get("hit"))
             info.cache_tier = cache.get("tier")
@@ -1056,7 +1058,25 @@ def _make_handler(state: _AppState, base_url: str):
                     self._send(200, _error_payload(str(e), uid, exc=e),
                                headers=self._trace_headers(info))
                     return
-                spooled = _spool_result(state, uid, table, info)
+                # encode: the result table into the wire's rows.  The
+                # query's trace closed on the worker's thread, so this is
+                # a phase of its own and a ``dsql:encode`` event carrying
+                # the query's seq, timed before _stats builds phaseMillis
+                columns = data = None
+                t0 = time.monotonic_ns()
+                with _tel.annotation(
+                        "encode", seq=getattr(info, "seq", None) or 0,
+                        rows=getattr(info, "rows", 0),
+                        bytes=getattr(info, "bytes", 0)):
+                    spooled = _spool_result(state, uid, table, info)
+                    if spooled is None and table is not None \
+                            and table.num_columns:
+                        columns = _columns_payload(table)
+                        data = _data_payload(table)
+                if info is not None:
+                    info.phases["encode"] = (info.phases.get("encode", 0.0)
+                                             + (time.monotonic_ns() - t0)
+                                             / 1e6)
                 state.forget(uid)
                 if spooled is not None:
                     # page 0 inline + a REAL nextUri: the rest of the
@@ -1074,9 +1094,9 @@ def _make_handler(state: _AppState, base_url: str):
                     "id": uid, "infoUri": base_url,
                     "stats": _stats("FINISHED", info),
                 }
-                if table is not None and table.num_columns:
-                    payload["columns"] = _columns_payload(table)
-                    payload["data"] = _data_payload(table)
+                if columns is not None:
+                    payload["columns"] = columns
+                    payload["data"] = data
                 self._send(200, payload,
                            headers=self._trace_headers(info))
                 return

@@ -185,6 +185,18 @@ def test_stats_phase_breakdown(server):
     assert all(v >= 0 for v in phases.values())
 
 
+def test_stats_phase_breakdown_carries_encode(server):
+    """The rows' way onto the wire is a phase of the query that produced
+    them: the GET that serves the page times ``encode`` and folds it into
+    ``phaseMillis`` (the query's own trace closed on the worker thread)."""
+    payload = _run_to_completion(server, "SELECT a, b FROM df")
+    assert payload["data"], "the page has to carry rows"
+    phases = payload["stats"]["phaseMillis"]
+    assert phases["encode"] > 0
+    # encode lies outside execute, after it
+    assert set(phases) >= {"parse", "plan", "execute", "encode"}
+
+
 def test_error_location_matches_reference(server):
     """The reference asserts the exact parse position in errorLocation
     (test_server.py:60-74: 'SELECT 1 + ' -> line 1, column 10+); ours

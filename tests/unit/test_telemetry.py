@@ -1,7 +1,9 @@
 """Unit tests for runtime/telemetry.py: span nesting + exception paths,
 registry atomicity/snapshot/reset, prometheus rendering, chrome-trace
-export, the deprecated dict aliases, and worker-thread re-entry."""
+export, the deprecated dict aliases, worker-thread re-entry, the spans on
+the profiler's trace, and the executor's spans and names on TPC-H shapes."""
 import json
+import os
 import threading
 
 import pytest
@@ -194,21 +196,116 @@ def test_compiled_stats_alias_reads_registry():
         tel.REGISTRY.set("compiles", before)
 
 
-def test_exec_profile_is_thread_local():
-    tel.exec_profile().clear()
-    tel.exec_profile()["device_ms"] = 1.5
+def test_root_carries_a_process_wide_sequence_number():
+    """The root span's ``seq`` identifies a request inside the process: it
+    rises with every trace opened, on any thread, and the report and the
+    chrome trace carry it."""
     seen = {}
 
     def other():
-        seen["empty"] = dict(tel.exec_profile())
-        tel.exec_profile()["device_ms"] = 99.0
+        with tel.trace_scope("theirs") as t:
+            pass
+        seen["seq"] = t.root.attrs["seq"]
 
+    with tel.trace_scope("mine") as first:
+        pass
     t = threading.Thread(target=other)
     t.start()
     t.join()
-    assert seen["empty"] == {}          # the other thread saw ITS OWN dict
-    assert tel.exec_profile()["device_ms"] == 1.5
-    tel.exec_profile().clear()
+    with tel.trace_scope("mine again") as last:
+        pass
+    assert first.root.attrs["seq"] < seen["seq"] < last.root.attrs["seq"]
+    assert last.report.seq == last.root.attrs["seq"]
+    assert last.report.to_dict()["spans"]["attrs"]["seq"] == last.report.seq
+
+
+def test_span_times_are_on_the_monotonic_clock():
+    """``t0``/``t1`` are ``time.monotonic_ns()`` readings, so a span tree
+    lies beside a client's record or a trace without an anchor."""
+    import time
+    before = time.monotonic_ns()
+    with tel.trace_scope("q") as trace:
+        with tel.span("execute"):
+            with tel.span("lookup"):
+                pass
+            with tel.span("bind"):
+                pass
+    after = time.monotonic_ns()
+    spans = list(trace.root.walk())
+    assert all(isinstance(s.t0, int) and isinstance(s.t1, int)
+               for s in spans)
+    assert all(before <= s.t0 <= s.t1 <= after for s in spans)
+    # depth first, in the order things happened: starts never go back
+    starts = [s.t0 for s in spans]
+    assert starts == sorted(starts)
+    d = trace.report.to_dict()["spans"]
+    assert d["t0_ns"] == trace.root.t0
+    assert d["children"][0]["children"][1]["t0_ns"] == spans[3].t0
+    events = trace.report.to_chrome_trace()["traceEvents"]
+    assert [e["t0_ns"] for e in events] == starts
+    assert events[0]["ts"] == trace.root.t0 / 1e3
+
+
+def _dsql_events(trace_dir):
+    """{line index: [(name, {stat: value})]} of the ``dsql:`` events on
+    the host's lines of the one trace under ``trace_dir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+    path, = glob.glob(str(trace_dir / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            found = [(e.name, dict(e.stats)) for e in line.events
+                     if e.name.startswith("dsql:")]
+            if found:
+                out[i] = found
+    return out
+
+
+def test_spans_are_trace_annotations_on_the_thread_that_ran_them(tmp_path):
+    """With a profiler session on, every span is a ``dsql:<name>`` event;
+    the root carries ``seq``; a span opened on a ``scoped()`` worker opens
+    and closes its annotation on the worker's own thread line."""
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with tel.trace_scope("q") as trace:
+            with tel.span("execute") as execute:
+                with tel.span("lookup"):
+                    pass
+
+                def worker():
+                    with tel.scoped(trace, execute), \
+                            tel.span("stage", index=0):
+                        with tel.span("dispatch"):
+                            pass
+
+                t = threading.Thread(target=worker)
+                t.start()
+                t.join()
+        with tel.annotation("encode", seq=trace.report.seq, rows=3):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    by_line = _dsql_events(tmp_path)
+    assert len(by_line) == 2, by_line
+    main = next(v for v in by_line.values() if v[0][0] == "dsql:query")
+    other = next(v for v in by_line.values() if v is not main)
+    assert [n for n, _ in main] == ["dsql:query", "dsql:execute",
+                                    "dsql:lookup", "dsql:encode"]
+    assert main[0][1] == {"seq": trace.report.seq}
+    assert main[3][1] == {"seq": trace.report.seq, "rows": 3}
+    assert [n for n, _ in other] == ["dsql:stage", "dsql:dispatch"]
+    # and the worker's spans still hang under the query's tree
+    assert [s.name for s in trace.root.walk()] == [
+        "query", "execute", "lookup", "stage", "dispatch"]
 
 
 # ---------------------------------------------------------------------------
@@ -345,3 +442,172 @@ def test_node_recorder_accumulates_per_node():
     assert rec.get(a) == [3.0, 20, 2]
     assert rec.get(b) == [5.0, 3, 1]
     assert rec.get(N()) is None
+
+
+# ---------------------------------------------------------------------------
+# the executor's spans, and the names the device sees (TPC-H at SF0.005)
+# ---------------------------------------------------------------------------
+
+_EXECUTOR_PHASES = ("lookup", "bind", "dispatch", "materialize")
+
+
+@pytest.fixture(scope="module")
+def tpch():
+    from benchmarks.tpch import QUERIES, generate_tpch
+    from dask_sql_tpu import Context
+
+    context = Context()
+    for name, frame in generate_tpch(0.005).items():
+        context.create_table(name, frame)
+    return context, QUERIES
+
+
+def _children(span, name):
+    return [c for c in span.children if c.name == name]
+
+
+def test_compiled_report_opens_execute_into_its_phases(tpch):
+    """A query served by a cached program: ``lookup``, ``bind``,
+    ``dispatch`` and ``materialize`` are children of ``execute``, carry
+    their counts, and together lie within it."""
+    context, queries = tpch
+    context.sql(queries[6], return_futures=False)      # compiles
+    context.sql(queries[6], return_futures=False)      # served from cache
+    rep = context.last_report
+    assert rep.tier == "compiled" and rep.counters.get("hits") == 1
+    execute, = _children(rep.root, "execute")
+    for name in _EXECUTOR_PHASES:
+        assert _children(execute, name), f"no {name} under execute"
+    inside = sum(rep.phases[name] for name in _EXECUTOR_PHASES)
+    assert 0 < inside <= rep.phases["execute"]
+    assert any(s.attrs.get("cache_hit") for s in _children(execute, "lookup"))
+    bind, = _children(execute, "bind")
+    assert bind.attrs["params"] == rep.counters["param_literals_hoisted"] > 0
+    assert bind.attrs["args"] > bind.attrs["params"]
+    assert bind.attrs["h2d_bytes"] > 0
+    dispatch, = _children(execute, "dispatch")
+    assert dispatch.attrs["program"].startswith("dsql_Logical")
+    materialize, = _children(execute, "materialize")
+    assert materialize.attrs["bytes"] > 0
+    assert materialize.attrs["small_fetch"] is True
+
+
+def test_staged_report_sums_the_phases_over_its_stages(tpch, monkeypatch):
+    """A plan run as a stage graph: each stage has its own lookup, bind,
+    dispatch and materialize, and the report's phases are their sums."""
+    context, queries = tpch
+    monkeypatch.setenv("DSQL_STAGE_HEAVY", "1")
+    context.sql(queries[12], return_futures=False)
+    context.sql(queries[12], return_futures=False)
+    rep = context.last_report
+    stages = [s for s in rep.root.walk() if s.name == "stage"]
+    assert len(stages) >= 2
+    for name in _EXECUTOR_PHASES:
+        in_stages = [c for s in stages for c in _children(s, name)]
+        assert len(in_stages) >= len(stages), name
+        everywhere = [s for s in rep.root.walk() if s.name == name]
+        assert rep.phases[name] == pytest.approx(
+            sum(s.wall_ms for s in everywhere))
+    programs = {c.attrs["program"] for s in stages
+                for c in _children(s, "dispatch")}
+    assert len(programs) == len(stages)
+    assert sum(rep.phases[n] for n in _EXECUTOR_PHASES) \
+        <= rep.phases["execute"]
+
+
+def _program(context, sql):
+    """(entry, lowered text with locations) of the program that serves
+    ``sql`` whole."""
+    from dask_sql_tpu.physical import compiled as cm
+
+    context.sql(sql, return_futures=False)      # compiles, or is served
+    context.sql(sql, return_futures=False)      # served from the cache
+    dispatch, = [s for s in context.last_report.root.walk()
+                 if s.name == "dispatch"]
+    entry, = [e for e in cm._cache.values()
+              if e is not cm._UNSUPPORTED
+              and e.name == dispatch.attrs["program"]]
+    return entry, entry.fn.lower(*_flat_shapes(context, sql, cm)).as_text(
+        debug_info=True)
+
+
+def _flat_shapes(context, sql, cm):
+    """The argument list ``_execute_single`` would bind, as shapes."""
+    import jax
+
+    from dask_sql_tpu.sql.parser import parse_sql
+    plan = cm._maybe_parameterize(
+        context._get_plan(parse_sql(sql)[0].query), count=False)
+    if type(plan).__name__ == "LogicalSort":
+        plan = plan.input       # off the TPU a terminal sort runs on the host
+    scans, params = [], []
+    cm._fp_plan(plan, context, scans, params)
+    flat = cm._flatten_tables(scans) + cm._param_args(params)
+    return [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in flat]
+
+
+@pytest.mark.parametrize("query, scopes", [
+    (6, ("dsql.LogicalAggregate", "dsql.LogicalFilter")),
+    (12, ("dsql.LogicalAggregate", "dsql.LogicalJoin", "dsql.LogicalFilter",
+          "dsql.join_build", "dsql.join_probe")),
+])
+def test_lowered_programs_carry_the_engine_names(tpch, query, scopes):
+    """What the chip's trace will read: the module is named after the
+    program, and every op's location nests the plan nodes it came from."""
+    context, queries = tpch
+    entry, text = _program(context, queries[query])
+    assert entry.name.startswith("dsql_Logical")
+    assert f"@jit_{entry.name}" in text
+    assert "jit_fn" not in text
+    for scope in scopes:
+        assert scope in text, scope
+    if query == 12:
+        assert "dsql.LogicalJoin/dsql.join_build" in text.replace(
+            '"', "")
+
+
+_NAME_SCRIPT = """
+import sys
+import numpy as np, pandas as pd
+from dask_sql_tpu import Context
+from dask_sql_tpu.physical import compiled as cm
+c = Context()
+n = int(sys.argv[1])
+c.create_table('t', pd.DataFrame({'a': np.arange(n), 'b': np.arange(n) % 7,
+                                  'x': np.arange(n) * 0.5}))
+for sql in sys.argv[2:]:
+    c.sql(sql, return_futures=False)
+    r = c.last_report
+    print(next((s.attrs['program'] for s in r.root.walk()
+                if s.name == 'dispatch'), '-'))
+"""
+
+
+def test_module_names_repeat_across_processes_and_differ_by_plan():
+    """The name joins XLA's persistent-cache key: two processes given the
+    same plan and layout must produce the same name (whatever the literal,
+    the table uid or the data), and another plan or layout another."""
+    import subprocess
+    import sys
+
+    a = "SELECT SUM(x) FROM t WHERE a > 5"
+    b = "SELECT b, SUM(x) FROM t WHERE a > 5 GROUP BY b"
+
+    def names(rows, *sqls):
+        out = subprocess.run(
+            [sys.executable, "-c", _NAME_SCRIPT, str(rows), *sqls],
+            capture_output=True, text=True, timeout=300,
+            env={**os.environ, "DSQL_TIERED": "0", "DSQL_RESULT_CACHE_MB": "0",
+                 "JAX_PLATFORMS": "cpu"})
+        assert out.returncode == 0, out.stderr[-2000:]
+        return out.stdout.split()
+
+    # each query runs twice in a process: the first compiles, the second
+    # is dispatched from the cache and names its program
+    first = names(1000, a, a, b, b)
+    second = names(1000, a.replace("5", "77"), a.replace("5", "78"), b, b)
+    assert first[1] == second[1] and first[3] == second[3]
+    assert first[1] != first[3]
+    assert first[0] == "-" and first[1].startswith("dsql_Logical")
+    other_layout = names(2000, a, a)
+    assert other_layout[1] != first[1]
