@@ -215,6 +215,28 @@ def mask_to_indices(mask: jax.Array) -> jax.Array:
     return jnp.nonzero(mask, size=count)[0]
 
 
+def compact_indices(mask: jax.Array, cap: int) -> Tuple[jax.Array, jax.Array]:
+    """(positions of the first ``cap`` set rows of ``mask``, number of set
+    rows): element for element ``jnp.nonzero(mask, size=cap,
+    fill_value=0)[0]`` (ascending, slots past the count 0; with more than
+    ``cap`` rows set, the first ``cap``) and ``mask.sum()``, traceable.
+
+    Not ``jnp.nonzero(size=)`` itself: JAX builds that as a ``bincount``
+    of the mask's running sum, a scatter-add of all n rows into ``cap``
+    bins whatever the selectivity, and a TPU serializes a scatter's
+    updates: 495 ms at n = 6.0 M on a v5e, at every cap.  Here the set
+    rows' positions are sorted to the front by one single-operand sort
+    (the idiom of ``sorted_agg.segment_bounds``): 11.8 ms on the same
+    chip, the same at every cap.  Positions are int32 while n allows.
+    """
+    n = mask.shape[0]
+    if cap > n:
+        raise ValueError(f"compact_indices: cap {cap} over {n} rows")
+    itype = jnp.int32 if n < 2 ** 31 else jnp.int64
+    pos = jnp.sort(jnp.where(mask, jnp.arange(n, dtype=itype), n))[:cap]
+    return jnp.where(pos < n, pos, 0), mask.sum()
+
+
 # ---------------------------------------------------------------------------
 # civil-date arithmetic (Howard Hinnant's algorithms, pure integer ops)
 # ---------------------------------------------------------------------------

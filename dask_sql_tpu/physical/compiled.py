@@ -58,9 +58,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops import groupby as G
-from ..ops.kernels import (canon_f64, comparable_data, float_class,
-                           key_parts as _key_parts, orderable_int64,
-                           unify_string_codes)
+from ..ops.kernels import (canon_f64, compact_indices, comparable_data,
+                           float_class, key_parts as _key_parts,
+                           orderable_int64, unify_string_codes)
 from ..plan.nodes import (
     LogicalAggregate, LogicalFilter, LogicalJoin, LogicalProject, LogicalSort,
     LogicalTableScan, LogicalUnion, LogicalValues, LogicalWindow, RelNode,
@@ -962,9 +962,14 @@ class _Tracer:
         masked rows into every join/sort above it — the single biggest
         steady-state tax vs the reference's dynamic partitions.  Compact to
         a power-of-2 capacity learned through the same flags/recompile
-        machinery as group caps: cumsum + small gathers (~tens of ms)
-        where every downstream sort then costs cap instead of n.  A
-        learned cap >= n/2 disables the site (unselective filter)."""
+        machinery as group caps: one sort of the set rows' positions
+        (``compact_indices``) and a gather per column, where every
+        downstream sort then costs cap instead of n.  On a v5e at
+        n = 6.0 M (TPC-H Q12 / Q14, cap 65 536 / 262 144): 13.1 / 23.9 ms a
+        request under ``dsql.compact``; 495 / 506 ms while the positions
+        came from ``jnp.nonzero(size=cap)``, a scatter-add of all n rows
+        (PERF.md, PR 26).  A learned cap >= n/2 disables the site
+        (unselective filter)."""
         n = vt.n
         if n < (1 << 16):
             return vt  # small inputs: gathers save nothing
@@ -976,8 +981,7 @@ class _Tracer:
             return vt  # learned: not selective enough to pay the gathers
         with jax.named_scope("dsql.compact"):
             mask = vt.vmask()
-            count = mask.sum()
-            idx = jnp.nonzero(mask, size=cap, fill_value=0)[0]
+            idx, count = compact_indices(mask, cap)
             row_valid = jnp.arange(cap) < count
             cols = [c.take(idx) for c in vt.table.columns]
         # count > cap rows were silently dropped: the flags check raises
@@ -2365,6 +2369,16 @@ def _compact_eligible(plan: RelNode) -> set:
     return out
 
 
+def _compact_attrs(meta: dict) -> dict:
+    """Whether a program compacts, and at what capacity: the ``cmp*`` sites
+    live in it (a site whose learned cap says the filter is unselective
+    leaves none) and the largest of their caps."""
+    caps = [cap for (_, _, tag), cap in zip(meta["agg_sites"],
+                                            meta["ngroup_caps"])
+            if tag.startswith("cmp")]
+    return {"compact_sites": len(caps), "compact_cap": max(caps, default=0)}
+
+
 def _check_flags(entry: _Compiled, flags) -> None:
     """Raise _NeedsRecompile on group-cap overflow; flags[0] => eager.
     Compaction sites (tag cmp*) additionally SHRINK: a cap far above the
@@ -3531,7 +3545,8 @@ def _execute_single(plan: RelNode, context, query_fp: str,
                 _cache.move_to_end(key)
             # asynchronous: the span is the host's cost of launching the
             # program; the wait for the device is inside materialize
-            with _tel.span("dispatch", program=entry.name):
+            with _tel.span("dispatch", program=entry.name,
+                           **_compact_attrs(entry.meta)):
                 outs = entry.fn(*flat)
         try:
             with _tel.span("materialize"):

@@ -635,3 +635,21 @@ def test_filter_compaction_learned_caps(monkeypatch):
         check_dtype=False, rtol=1e-6, atol=1e-9)
     assert cm.stats["fallbacks"] == fb, "compaction must not cause fallback"
     assert cm.stats["recompiles"] > rec, "shrink recompile expected"
+
+    def dispatch_of(sql):
+        """The ``dispatch`` span of ``sql``, served by a program that is
+        there already (a first arrival runs its program inside ``compile``)."""
+        ctx.sql(sql, return_futures=False)
+        span, = [s for s in ctx.last_report.root.walk()
+                 if s.name == "dispatch"]
+        return span
+
+    # the report says whether a query compacted and at what capacity: here
+    # the tight cap the shrink learned, far under the default n/4
+    attrs = dispatch_of(q.replace("sel < 3", "sel < 2")).attrs
+    assert attrs["compact_sites"] >= 1
+    assert 1024 <= attrs["compact_cap"] <= n // 8
+    # a filter under a global aggregate never compacts
+    ctx.sql("SELECT SUM(v) AS s FROM fact WHERE sel < 3", return_futures=False)
+    attrs = dispatch_of("SELECT SUM(v) AS s FROM fact WHERE sel < 2").attrs
+    assert attrs["compact_sites"] == 0 and attrs["compact_cap"] == 0
