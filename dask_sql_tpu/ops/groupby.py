@@ -348,104 +348,6 @@ def dedup_for_distinct_agg(group_codes_arr: jax.Array, value_col: Column,
     return rows
 
 
-# ---------------------------------------------------------------------------
-# scatter-free aggregation over group-sorted rows (TPU hot path, used by the
-# compiled executor — physical/compiled.py). See ops/sorted_agg.py for the
-# primitive layer and the rationale (TPU scatter is serialized).
-# ---------------------------------------------------------------------------
-
-def sorted_segment_aggregate(op: str, col_sorted: Optional[Column],
-                             valid_sorted: Optional[jax.Array],
-                             codes_sorted: jax.Array, starts: jax.Array,
-                             ends: jax.Array, out_type: SqlType) -> Column:
-    """One aggregate over a group-sorted stream, gathers/scans only.
-
-    ``col_sorted`` is the argument column already permuted into group order
-    (None for COUNT(*)); ``valid_sorted`` is the combined row-validity +
-    FILTER-clause + value-nullability mask in the same order.
-    """
-    from . import sorted_agg as sa
-
-    n = codes_sorted.shape[0]
-    if valid_sorted is None:
-        valid_sorted = jnp.ones(n, dtype=bool)
-
-    if op in ("COUNT", "REGR_COUNT"):
-        return Column(sa.seg_count(valid_sorted, starts, ends), out_type, None)
-
-    assert col_sorted is not None, f"{op} requires an argument"
-    data = col_sorted.data
-    count = sa.seg_count(valid_sorted, starts, ends)
-    has_any = count > 0
-
-    if op in ("SUM", "$SUM0", "AVG", "STDDEV", "STDDEV_POP", "STDDEV_SAMP",
-              "VAR_POP", "VAR_SAMP", "VARIANCE"):
-        dscale = exact_decimal_scale(col_sorted.stype) if op in (
-            "SUM", "$SUM0", "AVG") else None
-        if dscale is not None:
-            idata = _decimal_scaled_ints(data, dscale)
-            s_int = sa.seg_sum(idata, valid_sorted, codes_sorted, starts,
-                               ends).astype(jnp.int64)
-            return _decimal_exact_result(op, s_int, count, dscale, out_type)
-        s = sa.seg_sum(data, valid_sorted, codes_sorted, starts, ends)
-        if op == "SUM":
-            return Column(s.astype(physical_dtype(out_type)), out_type, has_any)
-        if op == "$SUM0":
-            return Column(s.astype(physical_dtype(out_type)), out_type, None)
-        mean = s.astype(jnp.float64) / jnp.maximum(count, 1)
-        if op == "AVG":
-            return Column(mean, out_type, has_any)
-        sq = data.astype(jnp.float64) ** 2
-        s2 = sa.seg_sum(sq, valid_sorted, codes_sorted, starts, ends)
-        var_pop = jnp.maximum(s2 / jnp.maximum(count, 1) - mean**2, 0.0)
-        if op == "VAR_POP":
-            return Column(var_pop, out_type, has_any)
-        denom = jnp.maximum(count - 1, 1)
-        var_samp = jnp.maximum((s2 - count * mean**2) / denom, 0.0)
-        ok = count > 1
-        if op in ("VAR_SAMP", "VARIANCE"):
-            return Column(var_samp, out_type, ok)
-        if op == "STDDEV_POP":
-            return Column(jnp.sqrt(var_pop), out_type, has_any)
-        return Column(jnp.sqrt(var_samp), out_type, ok)
-
-    if op in ("MIN", "MAX"):
-        if col_sorted.stype.is_string:
-            ranked = col_sorted.dict_ranks().data.astype(jnp.int64)
-            f = sa.seg_min if op == "MIN" else sa.seg_max
-            out_ranks = f(ranked, valid_sorted, codes_sorted, ends)
-            order = dict_sort_order(col_sorted.dictionary)
-            inv = jnp.asarray(order.astype(np.int64))
-            safe = jnp.clip(out_ranks, 0, len(order) - 1)
-            return Column(jnp.take(inv, safe).astype(jnp.int32), out_type,
-                          has_any, col_sorted.dictionary)
-        f = sa.seg_min if op == "MIN" else sa.seg_max
-        out = f(data, valid_sorted, codes_sorted, ends)
-        return Column(out.astype(physical_dtype(out_type)), out_type, has_any)
-
-    if op in ("EVERY", "BOOL_AND"):
-        out = sa.seg_min(jnp.where(valid_sorted, data.astype(bool), True)
-                         .astype(jnp.int32),
-                         jnp.ones(n, bool), codes_sorted, ends) > 0
-        return Column(out, out_type, has_any)
-    if op in ("BOOL_OR", "ANY"):
-        out = sa.seg_max(jnp.where(valid_sorted, data.astype(bool), False)
-                         .astype(jnp.int32),
-                         jnp.ones(n, bool), codes_sorted, ends) > 0
-        return Column(out, out_type, has_any)
-
-    if op in ("ANY_VALUE", "SINGLE_VALUE", "FIRST_VALUE", "LAST_VALUE"):
-        if op == "LAST_VALUE":
-            pos = sa.seg_last_valid_pos(valid_sorted, codes_sorted, ends)
-        else:
-            pos = sa.seg_first_valid_pos(valid_sorted, codes_sorted, ends)
-        safe = jnp.clip(pos, 0, max(n - 1, 0))
-        out = col_sorted.take(safe)
-        return out.with_mask(out.valid_mask() & has_any)
-
-    raise NotImplementedError(f"Sorted aggregate {op}")
-
-
 def whole_table_aggregate(op: str, col: Optional[Column],
                           fmask: Optional[jax.Array], out_type: SqlType,
                           n_rows: int) -> Column:

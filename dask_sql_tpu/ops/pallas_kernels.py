@@ -55,9 +55,16 @@ def _strategy_on_tpu() -> bool:
     scatter groupby (host-shaped: scatters are ~1 ms where sorts are
     hundreds).  Distinct from ``_on_tpu`` (the hardware truth, which gates
     pallas ``interpret=``): ``DSQL_STRATEGY=tpu|host`` forces a strategy on
-    either backend.  (BENCH_r04/r05 ran ``host`` on their TPU because the
-    merge join's variadic sorts compiled ~8x slower there; not measured
-    on the attached chip — ROADMAP S2.)"""
+    either backend.  BENCH_r04/r05 ran ``host`` on their TPU because the
+    merge join's variadic sorts compiled ~8x slower there.  Measured for a
+    v5e since (PR 27): a sort's compile time is in its key channels and
+    rows, minutes apiece at millions of rows, so under the TPU strategy
+    a join still takes the scatter formulation where its sorts would see
+    more than ``SORT_ROWS_MAX`` rows
+    (``physical/compiled.py::_sort_formulation``, decided per operator from
+    the static shapes of the plan), a grouped aggregate takes it at every
+    size, and an ORDER BY above ``LEXSORT_ROWS_MAX`` rows sorts a key
+    channel a pass."""
     s = os.environ.get("DSQL_STRATEGY", "auto").lower()
     if s == "tpu":
         return True
@@ -496,7 +503,7 @@ def _nonfinite_safe(backend):
     def wrapped(vals, codes, mask, num_groups):
         if not jnp.issubdtype(vals.dtype, jnp.floating):
             return backend(vals, codes, mask, num_groups)
-        from .sorted_agg import ieee_reassemble
+        from .kernels import ieee_reassemble
         a = vals.shape[0]
         isnan = jnp.isnan(vals)
         ispos = jnp.isposinf(vals)

@@ -15,7 +15,9 @@ fresh data with the same layout never recompiles.
 **Stage graphs bound program size.** XLA:TPU compile time grows
 superlinearly with the number of fused heavy (join/aggregate/window)
 pipelines in one program (~50 s at 2, never-finishes at 8-9 in
-BENCH_r04/r05), so plans above a heavy-node budget are partitioned
+BENCH_r04/r05; for a v5e the minutes are the nodes' sorts, which big
+operators no longer hold: ``SORT_ROWS_MAX``), so plans above a heavy-node
+budget are partitioned
 (physical/stages.py) into a DAG of stages of at most ``DSQL_STAGE_HEAVY``
 heavy nodes (default 6; legacy ``DSQL_SPLIT_HEAVY`` honored).  Stage
 outputs materialize into padded power-of-2 capacity-class temp tables
@@ -60,7 +62,9 @@ import numpy as np
 from ..ops import groupby as G
 from ..ops.kernels import (canon_f64, compact_indices, comparable_data,
                            float_class, key_parts as _key_parts,
-                           orderable_int64, unify_string_codes)
+                           lexsort_by_passes, orderable_int64,
+                           unify_string_codes)
+from ..ops.pallas_kernels import _strategy_on_tpu
 from ..plan.nodes import (
     LogicalAggregate, LogicalFilter, LogicalJoin, LogicalProject, LogicalSort,
     LogicalTableScan, LogicalUnion, LogicalValues, LogicalWindow, RelNode,
@@ -351,148 +355,59 @@ def _hash_group_parts(parts) -> jax.Array:
     return h
 
 
-class _GroupSorted:
-    """Group-sorted stream: the one factorize result both the aggregate and
-    UNION DISTINCT paths consume (scatter-free; see ops/sorted_agg.py).
+#: The most rows at which a join traced for a TPU keeps its SORT
+#: formulation (the merge join: sorts of one u64 key, the hash).  XLA:TPU's
+#: compile time for one ``sort`` grows with its KEY channels after the x64
+#: split and with its rows, not with its payload (AOT for a described v5e,
+#: PR 27, seconds at 65 536 / 1.5 M / 6 M rows): one 32-bit key 15 / - /
+#: 31-34; one u64 key 37 / 69 / 174; ``searchsorted(method="sort")``, two
+#: such sorts, 328 at 6 M probes; the two keys (invalid, u64 hash) of the
+#: group sort this strategy had until PR 27, 134 / - / 737.  A join sorts
+#: three times and TPC-H Q3 / Q5 / Q10 join
+#: two to five times at 1.5-6 M rows: 1127 s for Q3's program (PR 23), past
+#: any set-up.  The scatter formulations (``_join_hash_table``,
+#: ``_hashed_aggregate``) hold no sort and compile in seconds at any size;
+#: on the chip they pay a serialized scatter per build row and a gather
+#: per probe row instead: 186 ns a build row (Q3's trace on a v5e, PR 27:
+#: 306.6 ms under ``dsql.join_build`` for 1.65 M rows) where the merge
+#: join's build sort takes 20 (Q12: 30.2 ms for the same 1.5 M orders), so
+#: Q12 under the hash table would cost some 320 ms for its 68 and the
+#: merge join stays wherever its sorts compile.  The choice is made per
+#: operator from the rows its sorts would see, and the limit is a budget
+#: of compile time: at 262 144 rows a u64-key sort compiles in about 50 s
+#: (between the 37 and the 69 above), a join's three in two and a half
+#: minutes, one join's fair share of a 900 s set-up.  TPC-H Q12's and Q14's
+#: probes (compacted to 65 536 / 262 144 rows) lie inside it; a capacity
+#: class higher the join takes the hash table and pays on the chip, not
+#: in the set-up.
+SORT_ROWS_MAX = 1 << 18
 
-    ``collision`` is a traced scalar bool: True when a 64-bit key-hash
-    collision may have interleaved two distinct groups (hash-combined sort
-    path only); callers must append it to the tracer's fallback flags."""
+#: The most rows an ORDER BY is traced at as ONE multi-key sort
+#: (``jnp.lexsort``) for a TPU.  Up to here it compiles in under a second
+#: whatever its keys (TPC-H Q1's and Q12's ORDER BY over four to six rows);
+#: above it a key channel costs minutes (169 s for Q3's ORDER BY at 16 384
+#: rows), and the keys go through one single-key sort, a channel a pass
+#: (``lexsort_by_passes``).
+LEXSORT_ROWS_MAX = 1 << 10
 
-    __slots__ = ("perm", "valid_sorted", "codes_sorted", "num_groups",
-                 "starts", "ends", "first_rows", "n", "cap", "collision",
-                 "payload_sorted")
 
-
-def _group_sorted_codes(key_cols: List[Column],
-                        row_valid: Optional[jax.Array],
-                        cap: int,
-                        payload: Tuple[jax.Array, ...] = ()) -> _GroupSorted:
-    """Sort rows into group order and derive dense codes in sorted space.
-
-    Invalid rows and groups beyond ``cap`` land in the trash slot ``cap``.
-    Stable sort makes ``first_rows[g]`` the group's first original row.
-
-    ``payload`` arrays ride the sort as extra variadic-sort operands and come
-    back group-ordered in ``gs.payload_sorted``. On TPU a random n-element
-    gather costs ~2x a whole extra sort operand (profiled on the bench
-    workload: 32ms gather vs full 7ms u64 argsort at 1.8M rows), so callers
-    should ship every column they need in sorted space through here rather
-    than ``take(gs.perm)`` afterwards. Key parts also ride as payload, which
-    makes boundary detection gather-free.
-
-    With >2 key sort operands, all parts collapse into ONE u64 hash key
-    (sort cost scales with key-operand count); group order is then hash
-    order — unordered, as SQL allows; an explicit ORDER BY sorts above the
-    aggregate anyway. Distinct keys sharing a hash would interleave; that is
-    detected (adjacent equal-hash rows across a raw-key boundary) and
-    reported via ``collision`` for the runtime fallback flag.
-    """
-    from ..ops import sorted_agg as sa
-
-    from ..ops.pallas_kernels import _strategy_on_tpu as _on_tpu
-
-    n = len(key_cols[0])
-    parts = _key_parts(key_cols)
-    invalid = jnp.zeros(n, dtype=bool) if row_valid is None else ~row_valid
-    on_tpu = _on_tpu()
-    n_operands = sum(2 if flag is not None else 1 for _, flag in parts)
-    hashed = on_tpu and n_operands > 2
-
-    # key operands, most significant first (invalid rows last; within a
-    # part the class flag outranks the data: NULL first, NaN last)
-    key_ops: List[jax.Array] = [invalid]
-    if hashed:
-        key_ops.append(_hash_group_parts(parts))
-    else:
-        for d, flag in parts:
-            if flag is not None:
-                key_ops.append(flag)
-            key_ops.append(d)
-
-    if not on_tpu:
-        # CPU/GPU: XLA's variadic comparator sort is slow there and random
-        # gathers are cheap — sort keys only, gather everything after
-        perm = jnp.lexsort(tuple(reversed(key_ops)))
-        valid_sorted = ~invalid[perm]
-        payload_sorted = tuple(p[perm] for p in payload)
-        parts_sorted = [(d[perm], None if flag is None else flag[perm])
-                        for d, flag in parts]
-    else:
-        part_pay: List[jax.Array] = []
-        if hashed:
-            for d, flag in parts:
-                part_pay.append(d)
-                if flag is not None:
-                    part_pay.append(flag)
-
-        nk = len(key_ops)
-        iota = jnp.arange(n, dtype=jnp.int64)
-        outs = jax.lax.sort(tuple(key_ops) + (iota,) + tuple(part_pay)
-                            + tuple(payload), num_keys=nk, is_stable=True)
-        perm = outs[nk]
-        valid_sorted = ~outs[0]
-        payload_sorted = outs[nk + 1 + len(part_pay):]
-
-        if hashed:
-            it = iter(outs[nk + 1: nk + 1 + len(part_pay)])
-            parts_sorted = [(next(it),
-                             next(it) if flag is not None else None)
-                            for _, flag in parts]
-        else:
-            it = iter(outs[1:nk])
-            parts_sorted = [((next(it) if flag is not None else None),
-                             next(it)) for _, flag in parts]
-            parts_sorted = [(d, f) for f, d in parts_sorted]
-    diff = jnp.zeros(n - 1, dtype=bool) if n > 1 else jnp.zeros(0, dtype=bool)
-    for d, flag in parts_sorted:
-        diff = diff | (d[1:] != d[:-1])
-        if flag is not None:
-            diff = diff | (flag[1:] != flag[:-1])
-    boundary = jnp.concatenate([jnp.ones(min(n, 1), dtype=bool), diff])
-    boundary = boundary & valid_sorted
-
-    collision = jnp.zeros((), dtype=bool)
-    if hashed:
-        hs = outs[1]
-        adj_pair = valid_sorted[1:] & valid_sorted[:-1]
-        collision = (adj_pair & (hs[1:] == hs[:-1]) & boundary[1:]).any()
-
-    codes_sorted = jnp.cumsum(boundary.astype(jnp.int64)) - 1
-    # last valid row's code + 1; if no valid rows, 0
-    num_groups = jnp.where(
-        valid_sorted.any(),
-        jnp.max(jnp.where(valid_sorted, codes_sorted, -1)) + 1, 0)
-    codes_sorted = jnp.where(valid_sorted, jnp.minimum(codes_sorted, cap), cap)
-
-    gs = _GroupSorted()
-    gs.perm, gs.valid_sorted, gs.codes_sorted = perm, valid_sorted, codes_sorted
-    gs.num_groups, gs.n, gs.cap = num_groups, n, cap
-    gs.collision = collision
-    gs.payload_sorted = payload_sorted
-    gs.starts, gs.ends = sa.segment_bounds(codes_sorted, cap)
-    gs.first_rows = perm[jnp.clip(gs.starts, 0, max(n - 1, 0))]
-    return gs
+def _sort_formulation(rows: int) -> bool:
+    """True when a join whose sorts would see ``rows`` rows is traced in
+    its sort formulation: the strategy is the TPU's and the sorts are small
+    enough to compile inside a set-up (``SORT_ROWS_MAX``).  Off the TPU
+    strategy nothing sorts."""
+    return _strategy_on_tpu() and rows <= SORT_ROWS_MAX
 
 
 def _traced_factorize(key_cols: List[Column], row_valid: Optional[jax.Array],
                       cap: int):
-    """Original-row-order codes view of _group_sorted_codes (UNION DISTINCT
-    needs codes per input row). The un-sort is a payload sort keyed on the
-    permutation — half the cost of the argsort + random gather it replaces.
-
-    Off-TPU the hash table produces row-order codes directly, with zero
-    sorts; there is no ngroups escalation on this path (callers pass
-    cap >= the worst case), so an unresolved table folds into the
-    collision flag and reruns eager."""
-    from ..ops.pallas_kernels import _strategy_on_tpu as _on_tpu
-    if not _on_tpu():
-        codes, first, ng, coll = _group_hashed_codes(key_cols, row_valid,
-                                                     cap)
-        return codes, first, ng, coll | (ng > cap)
-    gs = _group_sorted_codes(key_cols, row_valid, cap)
-    _, codes = jax.lax.sort((gs.perm, gs.codes_sorted), num_keys=1)
-    return codes, gs.first_rows, gs.num_groups, gs.collision
+    """Group codes in original row order (UNION DISTINCT and DISTINCT
+    aggregates need codes per input row): the hash table produces them
+    directly, with no sort.  There is no ngroups escalation on this path
+    (callers pass cap >= the worst case), so an unresolved table folds into
+    the collision flag and reruns eager."""
+    codes, first, ng, coll = _group_hashed_codes(key_cols, row_valid, cap)
+    return codes, first, ng, coll | (ng > cap)
 
 
 STATIC_DOMAIN_CAP = 4096
@@ -1019,90 +934,30 @@ class _Tracer:
         if static is not None:
             return static
 
-        # general path: group-sort once, then every aggregate is a prefix-sum
-        # difference or segmented scan over the sorted stream — no scatter
-        # (TPU scatter is serialized; see ops/sorted_agg.py)
+        if id(rel) in self.compact_ok:
+            # the joins below left most rows unset (TPC-H Q3: 30 000 of
+            # six million), and the group-by's kernels see every row, set
+            # or not: 5.3 s of a 6.2 s Q3 on a v5e (PR 27).  Compact first,
+            # to a learned capacity, as below a join
+            src = self._maybe_compact(src)
+            n = src.n
+            key_cols = [src.table.columns[i] for i in rel.group_keys]
+
         tag = f"agg{self._agg_counter}"
         self._agg_counter += 1
         cap = min(self.caps.get(tag, DEFAULT_GROUP_CAP), n)
 
-        from ..ops.pallas_kernels import _strategy_on_tpu as _on_tpu
-        if not _on_tpu():
-            # CPU/GPU: hash-table codes + scatter segment aggregates — the
-            # group sort this path replaces costs ~350 ms at 600k rows on
-            # XLA:CPU while segment_sum costs ~2 ms
+        # the dynamic-domain group-by: one scope on the device trace (beside
+        # the static domain's dsql.groupby_limbs)
+        with jax.named_scope("dsql.groupby_sorted"):
             return self._hashed_aggregate(rel, src, key_cols, cap, tag)
-
-        # every column an aggregate reads rides the group sort as payload —
-        # cheaper than a post-sort take(perm) random gather per column
-        need: List[int] = []
-        for agg in rel.aggs:
-            for idx in (list(agg.args[:1])
-                        + ([agg.filter_arg] if agg.filter_arg is not None
-                           else [])):
-                if idx not in need:
-                    need.append(idx)
-        payload: List[jax.Array] = []
-        pay_slots: Dict[int, Tuple[int, Optional[int]]] = {}
-        for idx in need:
-            col = src.table.columns[idx]
-            di = len(payload)
-            payload.append(col.data)
-            mi = None
-            if col.mask is not None:
-                mi = len(payload)
-                payload.append(col.mask)
-            pay_slots[idx] = (di, mi)
-
-        # DISTINCT dedup masks: computed once per argument column and shipped
-        # through the group sort as payload (not gathered by perm afterwards)
-        keep_slots: Dict[int, int] = {}
-        for agg in rel.aggs:
-            if agg.distinct and agg.op not in ("MIN", "MAX"):
-                ai = agg.args[0]
-                if ai not in keep_slots:
-                    keep_slots[ai] = len(payload)
-                    payload.append(self._distinct_keep(key_cols, agg, src))
-
-        gs = _group_sorted_codes(key_cols, src.valid, cap, tuple(payload))
-        self.fallback.append(gs.collision)
-        self.ngroups.append(gs.num_groups)
-        self.ngroup_caps.append(cap)
-        self.agg_sites.append((n, False, tag))
-
-        for ki in rel.group_keys:
-            out_cols.append(src.table.columns[ki].take(gs.first_rows))
-
-        def _sorted_col(idx: int) -> Column:
-            di, mi = pay_slots[idx]
-            col = src.table.columns[idx]
-            mask = gs.payload_sorted[mi] if mi is not None else None
-            return Column(gs.payload_sorted[di], col.stype, mask,
-                          col.dictionary)
-
-        for j, agg in enumerate(rel.aggs):
-            f = rel.schema[len(rel.group_keys) + j]
-            col_s = _sorted_col(agg.args[0]) if agg.args else None
-            vmask = gs.valid_sorted
-            if col_s is not None and col_s.mask is not None:
-                vmask = vmask & col_s.mask
-            if agg.filter_arg is not None:
-                fc = _sorted_col(agg.filter_arg)
-                vmask = vmask & fc.data.astype(bool) & fc.valid_mask()
-            if agg.distinct and agg.op not in ("MIN", "MAX"):
-                # DISTINCT: only each (group keys, value) pair's first
-                # occurrence contributes (MIN/MAX are dedup-invariant)
-                vmask = vmask & gs.payload_sorted[keep_slots[agg.args[0]]]
-            out_cols.append(G.sorted_segment_aggregate(
-                agg.op, col_s, vmask, gs.codes_sorted, gs.starts, gs.ends,
-                f.stype))
-        row_valid = jnp.arange(cap) < gs.num_groups
-        return _VT(Table(out_names, out_cols), row_valid)
 
     def _hashed_aggregate(self, rel, src: _VT, key_cols: List[Column],
                           cap: int, tag: str) -> _VT:
-        """General GROUP BY off-TPU: hash-table group codes in original row
-        order (no sort), then each aggregate is a segment_* scatter keyed on
+        """General GROUP BY, on every backend (the group sort the TPU strategy
+        had compiled for minutes above some tens of thousands of rows,
+        ``SORT_ROWS_MAX``, and went in PR 27): hash-table group codes in
+        original row order (no sort), then each aggregate is a segment_* scatter keyed on
         the dense codes — the same kernels the eager path uses
         (ops/groupby.py segment_aggregate), so semantics (exact decimals,
         NULL rules, string MIN/MAX ranks) are shared by construction.
@@ -1320,7 +1175,13 @@ class _Tracer:
                     arrays.append(nullkey)
             if valid is not None:
                 arrays.append((~valid).astype(jnp.int8))  # valid rows first
-            perm = jnp.lexsort(arrays)
+            # one multi-key sort while it is small; above that its key
+            # channels are what XLA:TPU does not compile in a set-up, and
+            # the keys go through one single-key sort, a channel a pass
+            if _strategy_on_tpu() and n > LEXSORT_ROWS_MAX:
+                perm = lexsort_by_passes(arrays)
+            else:
+                perm = jnp.lexsort(arrays)
             table = table.take(perm)
             if valid is not None:
                 count = jnp.sum(valid.astype(jnp.int64))
@@ -1430,18 +1291,20 @@ class _Tracer:
         ph = _hash_parts(pparts, pvalid)
         bh = _hash_parts(bparts, bvalid)
 
-        from ..ops.pallas_kernels import _strategy_on_tpu as _on_tpu
-        if _on_tpu():
+        if _sort_formulation(probe.n):
             # sorted-probe join: one 2-channel build-side argsort + binary
             # search + row-id gathers, regardless of build width — so the
             # r1/r2 wide-build strategy switch is gone (no per-column sort
-            # cost left for it to avoid)
+            # cost left for it to avoid).  Two of its three sorts see every
+            # probe row, so the probe's rows decide (SORT_ROWS_MAX)
             match, gathered = self._join_merge(jt, probe, build, pparts,
                                                bparts, pvalid, ph, bh,
                                                exist_test)
         else:
             # CPU/GPU: scatters and gathers cost ~1 ms where any 600k-row
-            # sort costs 350-750 ms — hash-table join, no sort of either side
+            # sort costs 350-750 ms — hash-table join, no sort of either
+            # side.  On a TPU above SORT_ROWS_MAX probe rows too: there the
+            # sorts are what does not compile
             match, gathered = self._join_hash_table(jt, probe, build,
                                                     pparts, bparts,
                                                     pvalid, ph, bh,
@@ -2342,20 +2205,29 @@ SMALL_FETCH_BYTES = 8 << 20
 
 
 def _compact_eligible(plan: RelNode) -> set:
-    """ids of LogicalFilter nodes worth compacting: the TOPMOST filter of
-    each filter chain with a SORT-SHAPED ancestor above — a join, window,
-    or grouped aggregate, whose in-program sorts shrink with the row
+    """ids of the nodes worth compacting at.  LogicalFilter: the TOPMOST
+    filter of each filter chain with a SORT-SHAPED ancestor above — a join,
+    window, or grouped aggregate, whose in-program sorts shrink with the row
     count.  A global aggregate is masked reductions only: compacting under
-    it is pure gather overhead (TPC-H Q6 measured 0.15 s -> 0.61 s)."""
+    it is pure gather overhead (TPC-H Q6 measured 0.15 s -> 0.61 s).
+    LogicalAggregate: a grouped aggregate straight over a join (projects
+    between them aside), whose INPUT is compacted where the aggregate has
+    no static domain: a join hands on every probe row, matched or not."""
     out: set = set()
 
     def walk(rel: RelNode, sorty_above: bool, parent_is_filter: bool):
         is_filter = isinstance(rel, LogicalFilter)
         if is_filter and sorty_above and not parent_is_filter:
             out.add(id(rel))
+        if isinstance(rel, LogicalAggregate) and rel.group_keys:
+            below = rel.input
+            while isinstance(below, LogicalProject):
+                below = below.input
+            if isinstance(below, LogicalJoin):
+                out.add(id(rel))
         # global DISTINCT aggregates (except MIN/MAX, which are
-        # dedup-invariant and skip _distinct_keep) still sort in-program
-        # on TPU (_traced_factorize -> _group_sorted_codes), so they count
+        # dedup-invariant and skip _distinct_keep) still factorize every
+        # row in-program (_traced_factorize), so they count
         sorty = sorty_above \
             or isinstance(rel, (LogicalJoin, LogicalWindow, LogicalSort)) \
             or (isinstance(rel, LogicalAggregate)
@@ -2481,7 +2353,8 @@ def _materialize(entry: _Compiled, outs) -> Table:
 # number of fused join/aggregate pipelines in one program — TPC-H Q2 (9
 # heavy nodes after decorrelation) never finished compiling in
 # BENCH_r04 (>27 min observed), while 2-join programs compiled in tens of
-# seconds (neither measured on the attached chip).  Plans above the heavy-node budget (physical/stages.py,
+# seconds (what was measured for a v5e since: physical/stages.py).  Plans
+# above the heavy-node budget (physical/stages.py,
 # DSQL_STAGE_HEAVY / legacy DSQL_SPLIT_HEAVY) are partitioned into a DAG of
 # bounded stages; every stage is traced and jitted as its own program with
 # the stage output materialized into a padded power-of-2 capacity-class
@@ -2583,6 +2456,12 @@ def _partition_plan(plan: RelNode, budget: int, context) -> StageGraph:
     return graph
 
 
+def _capacity_class(rows: int) -> int:
+    """The power-of-2 capacity (64 at least) a stage output of ``rows``
+    rows is padded to."""
+    return 1 << max((max(rows, 1) - 1).bit_length(), 6)
+
+
 def _pad_capacity(table: Table):
     """(padded table, row_valid): pad to a power-of-2 capacity with row
     validity.  Consumer programs are keyed on input SHAPES and a stage's
@@ -2590,7 +2469,7 @@ def _pad_capacity(table: Table):
     across runs, so reloading fresh data through the same stage never
     recompiles the consumer."""
     n = table.num_rows
-    cap = 1 << max((max(n, 1) - 1).bit_length(), 6)
+    cap = _capacity_class(n)
     table = table.with_names([f"c{i}" for i in range(table.num_columns)])
     if cap != n:
         pad = cap - n
@@ -2676,7 +2555,7 @@ def _record_stage_stats(st, idx: int, out: Table, query_fp: str,
                 nbytes += int(getattr(c.mask, "nbytes", 0))
         digest = (st.scan.table_name if st.scan is not None
                   else f"root:{query_fp}")
-        capacity = 1 << max((max(rows_out, 1) - 1).bit_length(), 6)
+        capacity = _capacity_class(rows_out)
         # the span carries the measurements too: record_query sums
         # stage_bytes into the query's measured working set at close
         _tel.annotate(stage_digest=digest, stage_rows_in=rows_in,
@@ -2772,7 +2651,7 @@ def _execute_stage_graph_inner(graph: StageGraph, context, query_fp: str,
         # of re-running the stages that produced them.  The failure
         # domain is one stage, not the graph (let alone the query).
         with _res.scoped(rt), _tel.scoped(tel_trace, tel_parent), \
-                _tel.span("stage", index=idx):
+                _tel.span("stage", index=idx, heavy=stages[idx].heavy):
             if stages[idx].est_rows is not None:
                 _tel.annotate(stage_est_rows=stages[idx].est_rows)
             attempt = 0
@@ -2781,6 +2660,12 @@ def _execute_stage_graph_inner(graph: StageGraph, context, query_fp: str,
                 try:
                     t0s = time.perf_counter()
                     out = run_stage_once(idx, attempt)
+                    if out is not None:
+                        # what tells one stage of a trace from another:
+                        # its place, its weight, and what it handed on
+                        rows = int(out.num_rows)
+                        _tel.annotate(rows_out=rows,
+                                      capacity=_capacity_class(rows))
                     if out is not None and (
                             os.environ.get("DSQL_HISTORY_FILE")
                             or _profile_on()):
@@ -2932,7 +2817,11 @@ def _execute_stage_graph_inner(graph: StageGraph, context, query_fp: str,
 #     verdict is "decided" — it runs the normal path (which serves eager
 #     with the proper counters) and never spawns background work;
 #   - the workload manager: background compiles bypass admission entirely,
-#     so they hold no scheduler slot and no memory-broker reservation.
+#     so they hold no scheduler slot and no memory-broker reservation;
+#   - what the eager tier costs where it would have to sort: under the TPU
+#     strategy a plan that joins two big inputs pays its compile on the
+#     first arrival (_eager_bridge_sorts), minutes sooner than the eager
+#     tier's own programs would have compiled.
 # Disable with DSQL_TIERED=0 (tests pin this off; production default on).
 # ---------------------------------------------------------------------------
 
@@ -3079,12 +2968,46 @@ def _background_compile(plan: RelNode, context, base_key,
             _bounded_put(_tier_done, base_key, True)
 
 
+def _eager_bridge_sorts(plan: RelNode, context, on_tpu: bool) -> bool:
+    """True where the eager tier is the slower way over a compile: under
+    the TPU strategy, a plan with a join both of whose sides scan more than
+    ``SORT_ROWS_MAX`` rows.  The eager join (``ops/join.py``) has the sort
+    formulation only, an ``argsort`` of the build side and a
+    ``searchsorted`` per probe row, each a program of its own that XLA:TPU
+    compiles for minutes at these sizes (``SORT_ROWS_MAX`` has the table),
+    where the plan's own program takes the hash-table join and compiles in
+    one or two.  On a v5e from an empty XLA cache (PR 27): TPC-H Q3 / Q5 /
+    Q10 answered by the eager tier after 424 / 483 / 708 s (158-279 small
+    programs a shape), their whole-plan programs ready after 105 / 56 /
+    106 s.  Filters are not counted: a scan's rows are what the plan
+    shows before it runs."""
+    if not on_tpu:
+        return False
+
+    def rows(rel: RelNode) -> int:
+        if isinstance(rel, LogicalTableScan):
+            entry = context.catalog_entry(rel.schema_name, rel.table_name)
+            return 0 if entry.table is None else entry.table.num_rows
+        return max((rows(i) for i in rel.inputs), default=0)
+
+    def walk(rel: RelNode) -> bool:
+        if isinstance(rel, LogicalJoin) \
+                and min(rows(rel.left), rows(rel.right)) > SORT_ROWS_MAX:
+            return True
+        return any(walk(i) for i in rel.inputs)
+
+    return walk(plan)
+
+
 def _tier_serve_eager(plan: RelNode, context, base_key, budget: int,
                       split_limit: Optional[int]) -> bool:
     """The tier decision: True => answer THIS arrival on the eager tier
-    (the caller returns None) while the programs build in the background."""
+    (the caller returns None) while the programs build in the background.
+    False for a plan the eager tier would answer later than its own compile
+    (``_eager_bridge_sorts``): that arrival pays the compile."""
     if split_limit is not None or not _tiering_enabled() \
-            or getattr(_tier_local, "bg", False):
+            or getattr(_tier_local, "bg", False) \
+            or _eager_bridge_sorts(plan, context, base_key[2]):
         return False
     global _bg_sem
     with _tier_lock:
@@ -3151,7 +3074,8 @@ def tier_probe(plan: RelNode, context) -> str:
         return "eager"
     with _tier_lock:
         inflight = base_key in _tier_inflight
-    if inflight or _tiering_enabled():
+    if inflight or (_tiering_enabled() and not _eager_bridge_sorts(
+            plan, context, base_key[2])):
         return "eager-compiling"
     return "compiled-cold"
 

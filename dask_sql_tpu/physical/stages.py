@@ -3,10 +3,21 @@
 XLA:TPU compile time grows superlinearly with the number of fused
 join/aggregate pipelines in one program (physical/compiled.py module
 docstring: ~50 s at 2 heavy nodes, ~400 s at 6, never-finishes at 8-9 in
-BENCH_r04/r05; not measured on the attached chip).  This module partitions a logical plan into a DAG of
+BENCH_r04/r05).  This module partitions a logical plan into a DAG of
 **stages**, each holding at most ``budget`` heavy nodes; the compiled
 executor traces and jits every stage as its own program, materializing
 stage outputs into padded capacity-class temp tables between them.
+
+What was measured for a v5e since (PR 27; AOT for a described chip, the
+chip's host compiles 2-3x slower): those minutes were the heavy nodes'
+SORTS, whose compile time is in their key channels and rows
+(``compiled.SORT_ROWS_MAX`` has the table: 174 s for one u64-keyed sort at
+six million rows, 737 s with a second key), not the number of nodes.  With
+the sorts of big operators gone (``compiled._sort_formulation``) TPC-H Q3
+(3 heavy nodes), Q10 (4) and Q5 (6, this module's whole budget) compile as
+ONE program each in 75 / 88 / 94 s at SF1 shapes, against 1127 s for Q3
+before (PR 23): the budget of 6 stands, and no TPC-H shape of the chip
+benchmark is cut into stages.
 
 The partitioner is a pure bottom-up greedy walk and therefore
 **deterministic** and **ancestor-independent**: the cuts made inside a
@@ -31,9 +42,10 @@ from ..plan.nodes import (LogicalAggregate, LogicalJoin, LogicalTableScan,
 
 #: Heavy-node budget per compiled program.  The default sits at the
 #: compile-time knee BENCH_r04/r05 measured (tens of seconds per program,
-#: never minutes); not measured on the attached chip (ROADMAP S2); override with ``DSQL_STAGE_HEAVY`` (or the legacy
-#: ``DSQL_SPLIT_HEAVY``, kept for compatibility with existing bench configs
-#: and learned "__split__" hints).
+#: never minutes), and holds for a v5e: TPC-H Q5, six heavy nodes at SF1
+#: shapes, is one program of 94 s (module docstring).  Override with
+#: ``DSQL_STAGE_HEAVY`` (or the legacy ``DSQL_SPLIT_HEAVY``, kept for
+#: compatibility with existing bench configs and learned "__split__" hints).
 DEFAULT_STAGE_HEAVY = 6
 
 
