@@ -317,15 +317,25 @@ class _VT:
     row count): heuristics that pick sides by size — the INNER-join
     probe/build choice — must see the logical stream size, or a compacted
     fact side masquerades as small, becomes the build, and its duplicate
-    keys trip the unique-build fallback."""
+    keys trip the unique-build fallback.
 
-    __slots__ = ("table", "valid", "weight")
+    ``hash_joins`` is set on a stream compacted at a join's output: the
+    joins above it keep the hash table though their probe side is small
+    now (``_LogicalJoin`` has the reason)."""
+
+    __slots__ = ("table", "valid", "weight", "hash_joins")
 
     def __init__(self, table: Table, valid: Optional[jax.Array],
-                 weight: Optional[int] = None):
+                 weight: Optional[int] = None, hash_joins: bool = False):
         self.table = table
         self.valid = valid
         self.weight = weight if weight is not None else table.num_rows
+        self.hash_joins = hash_joins
+
+    def carry(self, table: Table, valid: Optional[jax.Array]) -> "_VT":
+        """This stream after an operator that hands its rows on: what the
+        joins above decide by rides along."""
+        return _VT(table, valid, self.weight, self.hash_joins)
 
     @property
     def n(self) -> int:
@@ -796,6 +806,9 @@ class _Tracer:
         self.agg_sites: List[Tuple[int, bool, str]] = []  # (rows, hashed, tag)
         self._agg_counter = 0
         self._cmp_counter = 0
+        self._join_site_counter = 0
+        # rows the program's joins take in, probe + build of each: static
+        self.join_rows = 0
         # filter nodes (by id) eligible for learned-capacity compaction —
         # computed by _compact_eligible over the whole plan before tracing
         self.compact_ok: set = set()
@@ -855,8 +868,7 @@ class _Tracer:
             if isinstance(v, Scalar):
                 v = Column.from_scalar(v, src.n)
             cols.append(v)
-        return _VT(Table([f.name for f in rel.schema], cols), src.valid,
-                   weight=src.weight)
+        return src.carry(Table([f.name for f in rel.schema], cols), src.valid)
 
     def _LogicalFilter(self, rel: LogicalFilter) -> _VT:
         src = self.run(rel.input)
@@ -866,12 +878,12 @@ class _Tracer:
                 return src
             return _VT(src.table, jnp.zeros(src.n, dtype=bool))
         valid = mask if src.valid is None else (mask & src.valid)
-        out = _VT(src.table, valid, weight=src.weight)
+        out = src.carry(src.table, valid)
         if id(rel) in self.compact_ok:
             out = self._maybe_compact(out)
         return out
 
-    def _maybe_compact(self, vt: _VT) -> _VT:
+    def _maybe_compact(self, vt: _VT, after_join: bool = False) -> _VT:
         """Learned-capacity COMPACTION after a selective filter: static
         shapes mean a filter that drops 98% of lineitem still feeds all n
         masked rows into every join/sort above it — the single biggest
@@ -884,14 +896,36 @@ class _Tracer:
         request under ``dsql.compact``; 495 / 506 ms while the positions
         came from ``jnp.nonzero(size=cap)``, a scatter-add of all n rows
         (PERF.md, PR 26).  A learned cap >= n/2 disables the site
-        (unselective filter)."""
+        (unselective filter).
+
+        ``after_join``: the site is the output of a join that another join
+        takes in (TPC-H Q5's joins three to five probed all six million
+        lineitem rows with 15 %, then 0.6 % of them set).  Such sites stand
+        in chains, and a site that overflows drops rows, so every count
+        above it is too low for that round: default caps would cost a
+        chain a recompile a site.  An unlearned one therefore only COUNTS
+        (its cap is n), and ``_check_flags`` sizes every site of the chain
+        from true counts in one round."""
         n = vt.n
+        if after_join:
+            # numbered apart and before the size is looked at: the sites
+            # below decide this one's rows, and a site that came and went
+            # with their caps would renumber the others between two rounds
+            tag = f"cmpj{self._join_site_counter}"
+            self._join_site_counter += 1
         if n < (1 << 16):
             return vt  # small inputs: gathers save nothing
-        tag = f"cmp{self._cmp_counter}"
-        self._cmp_counter += 1
-        default_cap = 1 << max(int((max(n // 4, 1) - 1)).bit_length(), 10)
-        cap = min(self.caps.get(tag, default_cap), n)
+        if not after_join:
+            tag = f"cmp{self._cmp_counter}"
+            self._cmp_counter += 1
+        cap = self.caps.get(tag)
+        if cap is None and after_join:
+            self._compact_site(jnp.sum(vt.vmask(), dtype=jnp.int64), n, n,
+                               tag)
+            return vt
+        if cap is None:
+            cap = 1 << max(int((max(n // 4, 1) - 1)).bit_length(), 10)
+        cap = min(cap, n)
         if cap * 2 >= n:
             return vt  # learned: not selective enough to pay the gathers
         with jax.named_scope("dsql.compact"):
@@ -901,11 +935,17 @@ class _Tracer:
             cols = [c.take(idx) for c in vt.table.columns]
         # count > cap rows were silently dropped: the flags check raises
         # _NeedsRecompile before any result materializes
+        self._compact_site(count, cap, n, tag)
+        return _VT(Table(list(vt.table.names), cols), row_valid,
+                   weight=vt.weight, hash_joins=vt.hash_joins or after_join)
+
+    def _compact_site(self, count: jax.Array, cap: int, n: int,
+                      tag: str) -> None:
+        """The flags' entry of a compaction site: what it counted, what it
+        holds, the rows it took in (``_check_flags`` reads all three)."""
         self.ngroups.append(count)
         self.ngroup_caps.append(cap)
         self.agg_sites.append((n, False, tag))
-        return _VT(Table(list(vt.table.names), cols), row_valid,
-                   weight=vt.weight)
 
     def _LogicalValues(self, rel: LogicalValues) -> _VT:
         from .rel.executor import _values
@@ -1290,8 +1330,18 @@ class _Tracer:
         bvalid = _keys_valid(bk_cols, build.valid)
         ph = _hash_parts(pparts, pvalid)
         bh = _hash_parts(bparts, bvalid)
+        self.join_rows += probe.n + build.n
 
-        if _sort_formulation(probe.n):
+        # a side compacted at a join's output is small because the plan
+        # chains joins under a hash-table join: were each join above to
+        # take the sort formulation its rows now allow, the plan would pay
+        # three more u64-key sorts a join at set-up (TPC-H Q5's and Q10's
+        # last programs compile for a described v5e in 27 / 26 s with the
+        # hash table above the sites and 72 / 73 s with the merge join,
+        # PERF.md, PR 28), and on the device either is nearly free at
+        # these sizes (build sides of 5 and 25 rows)
+        hash_joins = probe.hash_joins or build.hash_joins
+        if _sort_formulation(probe.n) and not hash_joins:
             # sorted-probe join: one 2-channel build-side argsort + binary
             # search + row-id gathers, regardless of build width — so the
             # r1/r2 wide-build strategy switch is gone (no per-column sort
@@ -1310,9 +1360,20 @@ class _Tracer:
                                                     pvalid, ph, bh,
                                                     exist_test)
 
+        def _out(table: Table, valid) -> _VT:
+            return _VT(table, valid, weight=probe.weight,
+                       hash_joins=hash_joins)
+
+        def _handed_on(table: Table, valid) -> _VT:
+            if id(rel) in self.compact_ok:
+                # another join takes this in, matched rows or not
+                return self._maybe_compact(_out(table, valid),
+                                           after_join=True)
+            return _out(table, valid)
+
         if jt == "SEMI":
-            return _VT(probe.table.with_names(out_names),
-                       probe.vmask() & match, weight=probe.weight)
+            return _handed_on(probe.table.with_names(out_names),
+                              probe.vmask() & match)
         if jt == "ANTI":
             keep = ~match
             if getattr(rel, "null_aware", False):
@@ -1325,8 +1386,8 @@ class _Tracer:
                 build_nonempty = build_rows.any()
                 keep = (keep & ~build_has_null
                         & (pvalid | ~build_nonempty))
-            return _VT(probe.table.with_names(out_names),
-                       probe.vmask() & keep, weight=probe.weight)
+            return _out(probe.table.with_names(out_names),
+                        probe.vmask() & keep)
 
         def _pairs(build_cols: List[Column]) -> Table:
             if probe_is_left:
@@ -1346,12 +1407,11 @@ class _Tracer:
             match = match & pred
 
         if jt == "INNER":
-            return _VT(_pairs(gathered), probe.vmask() & match,
-                       weight=probe.weight)
+            return _handed_on(_pairs(gathered), probe.vmask() & match)
         # LEFT/RIGHT: every (valid) probe row survives; the build side is
         # NULL wherever the full ON condition (equi + residual) failed
         gathered = [c.with_mask(c.valid_mask() & match) for c in gathered]
-        return _VT(_pairs(gathered), probe.valid, weight=probe.weight)
+        return _out(_pairs(gathered), probe.valid)
 
     def _append_join_flags(self, jt, adj: jax.Array, raw_diffs) -> None:
         """Shared fallback policy for both join strategies. ``adj`` marks
@@ -2129,6 +2189,7 @@ def _build(plan: RelNode, context, scans, caps: Dict[str, int], key,
         meta["has_valid"] = out.valid is not None
         meta["ngroup_caps"] = list(tr.ngroup_caps)
         meta["agg_sites"] = list(tr.agg_sites)
+        meta["join_rows"] = tr.join_rows
         meta["n_out"] = n
         outs: List[jax.Array] = [flags]
         for c in out.table.columns:
@@ -2212,12 +2273,19 @@ def _compact_eligible(plan: RelNode) -> set:
     it is pure gather overhead (TPC-H Q6 measured 0.15 s -> 0.61 s).
     LogicalAggregate: a grouped aggregate straight over a join (projects
     between them aside), whose INPUT is compacted where the aggregate has
-    no static domain: a join hands on every probe row, matched or not."""
+    no static domain: a join hands on every probe row, matched or not.
+    LogicalJoin: an INNER or SEMI join that another join takes in, on
+    either side (projects and filters between them aside), whose OUTPUT is
+    compacted for the same reason."""
     out: set = set()
 
-    def walk(rel: RelNode, sorty_above: bool, parent_is_filter: bool):
+    def walk(rel: RelNode, sorty_above: bool, parent_is_filter: bool,
+             into_join: bool):
         is_filter = isinstance(rel, LogicalFilter)
+        is_join = isinstance(rel, LogicalJoin)
         if is_filter and sorty_above and not parent_is_filter:
+            out.add(id(rel))
+        if is_join and into_join and rel.join_type in ("INNER", "SEMI"):
             out.add(id(rel))
         if isinstance(rel, LogicalAggregate) and rel.group_keys:
             below = rel.input
@@ -2234,36 +2302,52 @@ def _compact_eligible(plan: RelNode) -> set:
                 and (rel.group_keys
                      or any(a.distinct and a.op not in ("MIN", "MAX")
                             for a in rel.aggs)))
+        into_join = is_join or (
+            into_join and isinstance(rel, (LogicalProject, LogicalFilter)))
         for i in rel.inputs:
-            walk(i, sorty, is_filter)
+            walk(i, sorty, is_filter, into_join)
 
-    walk(plan, False, False)
+    walk(plan, False, False, False)
     return out
 
 
 def _compact_attrs(meta: dict) -> dict:
     """Whether a program compacts, and at what capacity: the ``cmp*`` sites
     live in it (a site whose learned cap says the filter is unselective
-    leaves none) and the largest of their caps."""
-    caps = [cap for (_, _, tag), cap in zip(meta["agg_sites"],
-                                            meta["ngroup_caps"])
-            if tag.startswith("cmp")]
-    return {"compact_sites": len(caps), "compact_cap": max(caps, default=0)}
+    leaves none, and one that only counts compacts nothing) and the largest
+    of their caps; beside them the rows its joins take in, which is the
+    work the sites between two joins remove."""
+    caps = [cap for (n_rows, _, tag), cap in zip(meta["agg_sites"],
+                                                 meta["ngroup_caps"])
+            if tag.startswith("cmp") and cap < n_rows]
+    return {"compact_sites": len(caps), "compact_cap": max(caps, default=0),
+            "join_rows": meta.get("join_rows", 0)}
 
 
 def _check_flags(entry: _Compiled, flags) -> None:
     """Raise _NeedsRecompile on group-cap overflow; flags[0] => eager.
     Compaction sites (tag cmp*) additionally SHRINK: a cap far above the
     observed count recompiles once to a tight one (persisted, so future
-    processes trace tight directly)."""
+    processes trace tight directly), and a site that only counted so far
+    (``_maybe_compact``, ``after_join``) goes live where it is selective.
+
+    Sites stand in chains, in trace order: one that overflowed dropped
+    rows, so every count after it in this run is too low, and a cap shrunk
+    to such a count overflows in the next round.  Past the first overflow
+    nothing shrinks.  A round that recompiles anyway sets every site whose
+    count is true to its tight cap and pins the others where they are: a
+    default cap goes by the site's input rows, which the sites below are
+    about to change."""
     meta = entry.meta
-    ngroups = flags[2:]
     new_caps = dict(entry.caps)
-    grew = False
-    for i, (ng, cap) in enumerate(zip(ngroups, meta["ngroup_caps"])):
-        n_rows, hashed, tag = meta["agg_sites"][i]
+    recompile = False
+    exact = True
+    for (n_rows, hashed, tag), cap, ng in zip(meta["agg_sites"],
+                                              meta["ngroup_caps"],
+                                              flags[2:]):
+        ng = int(ng)
         if ng > cap:
-            if hashed and int(ng) > n_rows:
+            if hashed and ng > n_rows:
                 # ng = n+1 is the hashed path's SATURATED sentinel: the true
                 # group count is unknowable from this run.  Jump hard (x16,
                 # bounded by the input row count) instead of climbing a
@@ -2272,17 +2356,26 @@ def _check_flags(entry: _Compiled, flags) -> None:
                 # downstream) than one extra recompile does at warmup.
                 need = min(1 << (int(n_rows) - 1).bit_length(), cap * 16)
             else:
-                need = 1 << (int(ng) - 1).bit_length()
+                need = 1 << (ng - 1).bit_length()
             new_caps[tag] = max(need, cap * 2)
-            grew = True
+            recompile = True
+            exact = False
         elif tag.startswith("cmp"):
-            tight = 1 << max(int(max(int(ng), 1) - 1).bit_length(), 10)
-            if tight * 8 <= cap:
+            if not exact:
+                if cap < n_rows:
+                    new_caps[tag] = cap
+                continue
+            # twice the power of two above the count
+            tight = 2 << max((max(ng, 1) - 1).bit_length(), 10)
+            new_caps[tag] = min(tight, cap)
+            if cap >= n_rows:
+                # a counting site: worth a compile where it would compact
+                recompile = recompile or tight * 2 < n_rows
+            elif tight * 4 <= cap:
                 # one recompile to the tight cap: every downstream sort in
                 # the steady-state program shrinks by >= 8x
-                new_caps[tag] = max(tight * 2, 1024)
-                grew = True
-    if grew:
+                recompile = True
+    if recompile:
         raise _NeedsRecompile(new_caps)
 
 
