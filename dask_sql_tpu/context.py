@@ -575,22 +575,13 @@ class Context:
             for df_name, df in dataframes.items():
                 self.create_table(df_name, df, gpu=gpu)
 
-        # per-call wall breakdown, overwritten by every sql() call: the
-        # interesting split is host planning vs the (single)
-        # device round trip vs host decode — bench.py journals this so a
-        # slow query names its own bottleneck
-        import time as _time
         trace = None
         try:
             with _res.query_scope(timeout_s=timeout), \
                     _tel.trace_scope(sql) as trace, \
                     _sched.priority_scope(priority), ten_scope:
-                t0 = _time.perf_counter()
                 with _tel.span("parse"):
                     stmts = parse_sql(sql)
-                timings = {"parse_ms": (_time.perf_counter() - t0) * 1e3,
-                           "plan_ms": 0.0, "exec_ms": 0.0, "fetch_ms": 0.0}
-                self.last_timings = timings
                 result = None
                 for stmt in stmts:
                     result = self._execute_statement(stmt, sql,
@@ -603,11 +594,8 @@ class Context:
                         int(getattr(c.data, "nbytes", 0))
                         for c in result.columns)
                 if not return_futures and isinstance(result, Table):
-                    t0 = _time.perf_counter()
                     with _tel.span("fetch"):
                         result = result.to_pandas()
-                    timings["fetch_ms"] = (_time.perf_counter() - t0) * 1e3
-                    return result
                 return result
         finally:
             # the report is built when the trace CLOSES (the with-exit
@@ -616,34 +604,15 @@ class Context:
             # query's report instead of overwriting it
             if trace is not None and trace.report is not None:
                 self.last_report = trace.report
-                timings = getattr(self, "last_timings", None)
-                if timings is not None:
-                    # compile/materialize phase split joins the
-                    # bench-journaled breakdown (attributable BENCH_r*.json)
-                    for k in ("compile", "materialize"):
-                        v = trace.report.phases.get(k)
-                        if v is not None:
-                            timings[f"{k}_ms"] = v
 
     def _execute_statement(self, stmt: A.Statement, sql: str,
                            params: Optional[list] = None):
         from .physical.rel.custom import StatementDispatcher
         from .runtime import telemetry as _tel
 
-        import time as _time
-        timings = getattr(self, "last_timings", None)
         if isinstance(stmt, A.QueryStatement):
-            t0 = _time.perf_counter()
             with _tel.span("plan"):
                 plan = self._get_plan(stmt.query, sql, params=params)
-            if timings is not None:
-                timings["plan_ms"] += (_time.perf_counter() - t0) * 1e3
-                t0 = _time.perf_counter()
-                try:
-                    with _tel.span("execute"):
-                        return self._execute_query_plan(plan)
-                finally:
-                    timings["exec_ms"] += (_time.perf_counter() - t0) * 1e3
             with _tel.span("execute"):
                 return self._execute_query_plan(plan)
         handler = StatementDispatcher.get_plugin(type(stmt).__name__)

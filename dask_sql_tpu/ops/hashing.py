@@ -1,0 +1,427 @@
+"""Hashing kernels the compiled tier traces: 64-bit row hashes of key
+columns, the open-addressing hash table (joins, group codes), and group
+codes for statically enumerable key domains.
+
+Pure ``jnp`` over columns and arrays: no plan node, no tracer state.  The
+tracer (``physical/compiled.py``) decides which of them an operator takes
+and owns the flags they report through; the eager twins live in
+``ops/join.py`` and ``ops/groupby.py``.
+"""
+from __future__ import annotations
+
+from typing import List, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..table import Column, dict_sort_order
+from .kernels import (canon_f64, key_parts as _key_parts, orderable_int64,
+                      unify_string_codes)
+
+_U64_MAX = np.uint64(0xFFFFFFFFFFFFFFFF)
+_GOLDEN = np.uint64(0x9E3779B97F4A7C15)
+
+
+def _f64_hash_part(x: jax.Array) -> jax.Array:
+    """Deterministic u64 encoding of f64 for hashing without a 64-bit
+    bitcast: double-float (hi, lo) f32 split, each bitcast to i32 (supported
+    on TPU). ~48 mantissa bits — lossy encodings only add hash collisions,
+    which the join's collision flag catches; equality is verified on raw
+    values."""
+    x = canon_f64(x)
+    hi = x.astype(jnp.float32)
+    lo = (x - hi.astype(jnp.float64)).astype(jnp.float32)
+    hi_b = jax.lax.bitcast_convert_type(hi, jnp.int32).astype(jnp.uint64)
+    lo_b = jax.lax.bitcast_convert_type(lo, jnp.int32).astype(jnp.uint64)
+    return (hi_b << np.uint64(32)) | (lo_b & np.uint64(0xFFFFFFFF))
+
+
+def _mix64(z: jax.Array) -> jax.Array:
+    z = (z ^ (z >> np.uint64(30))) * np.uint64(0xBF58476D1CE4E5B9)
+    z = (z ^ (z >> np.uint64(27))) * np.uint64(0x94D049BB133111EB)
+    return z ^ (z >> np.uint64(31))
+
+
+def _hash_group_parts(parts) -> jax.Array:
+    """Mix all group-key parts (data + class flags) into one u64 per row.
+
+    Float parts ride the lossy double-float encoding (_f64_hash_part);
+    any loss only ever ADDS collisions, which the caller detects against
+    the raw parts and routes to the eager fallback."""
+    h = jnp.full(parts[0][0].shape, _GOLDEN, dtype=jnp.uint64)
+    for d, flag in parts:
+        if jnp.issubdtype(d.dtype, jnp.floating):
+            hp = _f64_hash_part(d)
+        else:
+            hp = d.astype(jnp.uint64)
+        h = _mix64(h + hp + _GOLDEN)
+        if flag is not None:
+            h = _mix64(h + flag.astype(jnp.uint64) + _GOLDEN)
+    return h
+
+
+STATIC_DOMAIN_CAP = 4096
+
+
+def _try_static_codes(cols: List[Column]):
+    """Direct group codes when every key has a statically-enumerable domain
+    (dictionary-encoded strings, booleans). Returns (codes[n] int64 in
+    [0, domain), domain, key_meta) or None; key_meta carries per-key
+    (size, nullable) so slots decode back to key values without touching
+    the data. Code order == eager group order (NULL slot first, then
+    dictionary rank order)."""
+    domain = 1
+    parts: List[Tuple[jax.Array, int]] = []
+    key_meta: List[Tuple[int, bool]] = []
+    for c in cols:
+        nullable = c.mask is not None
+        if c.stype.is_string:
+            size = len(c.dictionary)
+            code = c.dict_ranks().data.astype(jnp.int64)
+        elif c.data.dtype == jnp.bool_:
+            size = 2
+            code = c.data.astype(jnp.int64)
+        else:
+            return None
+        if nullable:
+            code = jnp.where(c.mask, code + 1, 0)
+            size += 1
+        size = max(size, 1)
+        domain *= size
+        if domain > STATIC_DOMAIN_CAP:
+            return None
+        parts.append((code, size))
+        key_meta.append((size, nullable))
+    combined = parts[0][0]
+    for code, size in parts[1:]:
+        combined = combined * size + code
+    return combined, domain, key_meta
+
+
+def _decode_static_keys(cols: List[Column], key_meta, domain: int
+                        ) -> List[Column]:
+    """Group-key output columns straight from the slot index: slot g encodes
+    (rank+null) digits in mixed radix, so the key values are arithmetic on
+    ``arange(domain)`` plus a static rank->dictionary-code gather — the row
+    data is never touched."""
+    g = jnp.arange(domain, dtype=jnp.int64)
+    stride = domain
+    out: List[Column] = []
+    for c, (size, nullable) in zip(cols, key_meta):
+        stride //= size
+        code = (g // stride) % size
+        mask = None
+        if nullable:
+            mask = code != 0
+            code = jnp.maximum(code - 1, 0)
+        if c.stype.is_string:
+            # code is a sort RANK; order[rank] = dictionary index
+            order = dict_sort_order(c.dictionary)
+            data = jnp.take(jnp.asarray(order.astype(np.int32)), code)
+            out.append(Column(data, c.stype, mask, c.dictionary))
+        else:
+            out.append(Column(code.astype(jnp.bool_), c.stype, mask))
+    return out
+
+
+def _join_key_parts(lcols: List[Column], rcols: List[Column]):
+    """Per-key (hash part u64, raw verify array) on a shared domain.
+
+    Hash parts may be lossy for f64 (double-float encoding); match
+    verification always compares the raw arrays, so a lossy hash can only
+    add collisions (caught by the collision flag), never wrong matches.
+    """
+    lparts, rparts = [], []
+    for lc, rc in zip(lcols, rcols):
+        if lc.stype.is_string or rc.stype.is_string:
+            la, ra = unify_string_codes([lc, rc])
+            la, ra = la.astype(jnp.int64), ra.astype(jnp.int64)
+            lh, rh = la.astype(jnp.uint64), ra.astype(jnp.uint64)
+        else:
+            dt = jnp.promote_types(lc.data.dtype, rc.data.dtype)
+            la = lc.data.astype(dt)
+            ra = rc.data.astype(dt)
+            if jnp.issubdtype(dt, jnp.floating):
+                # verify arrays keep NaN as NaN (NaN joins nothing, matching
+                # the eager path); only the hash canonicalizes NaN, and the
+                # resulting extra collisions trip the conservative flags
+                la = la.astype(jnp.float64) + 0.0
+                ra = ra.astype(jnp.float64) + 0.0
+                lh, rh = _f64_hash_part(la), _f64_hash_part(ra)
+            else:
+                la, ra = orderable_int64(la), orderable_int64(ra)
+                lh, rh = la.astype(jnp.uint64), ra.astype(jnp.uint64)
+        lparts.append((lh, la))
+        rparts.append((rh, ra))
+    return lparts, rparts
+
+
+def _hash_parts(parts, key_valid: jax.Array) -> jax.Array:
+    h = jnp.full(parts[0][0].shape, _GOLDEN, dtype=jnp.uint64)
+    for hp, _ in parts:
+        h = _mix64(h + hp + _GOLDEN)
+    h = jnp.where(h == _U64_MAX, _U64_MAX - np.uint64(1), h)
+    return jnp.where(key_valid, h, _U64_MAX)
+
+
+def _keys_valid(cols: List[Column], row_valid: Optional[jax.Array]) -> jax.Array:
+    v = jnp.ones(len(cols[0]), dtype=bool) if row_valid is None else row_valid
+    for c in cols:
+        if c.mask is not None:
+            v = v & c.mask
+    return v
+
+
+# ---------------------------------------------------------------------------
+# vectorized open-addressing hash table: joins and group-bys on every
+# backend (on a TPU, a join above ``compiled.SORT_ROWS_MAX`` probe rows).
+#
+# On XLA:CPU at 600k rows a u64 argsort costs ~354 ms and
+# searchsorted(method='sort') ~751 ms where gathers, scatters and segment_sum
+# cost ~1-2 ms; for a TPU a sort of millions of rows compiles for minutes.
+# So the table is built with whole-array scatter rounds, no sort: each round,
+# still-unresolved rows try to claim an EMPTY slot (scatter-min of row ids),
+# and every row whose round slot now holds an equal-hash resident adopts that
+# resident.  All rows of one key resolve together to one slot whose resident
+# is the key's first row.  A lax.while_loop runs only as many rounds as the
+# worst key chain needs (~log(keys)/log(1/load)).  u64 hash collisions
+# between DISTINCT raw keys are detected by the caller comparing raw key
+# parts against the resident's and routed to the runtime eager-fallback flag.
+# ---------------------------------------------------------------------------
+
+_HASH_MAX_ROUNDS = 64
+
+
+def _hash_table_size(n_keys: int) -> int:
+    """Power-of-2 table size at load factor <= 1/16.
+
+    Generous sizing buys two things off-TPU: fewer claim rounds when
+    hashing, and — the big one — direct addressing for sparse integer
+    keys: TPC-H orderkeys span ~16x the row count, so a 16x table lets
+    `key - lo` resolve in ONE round where a 4x table would fall back to
+    multi-round hashing.  The cost is one table-sized fill (~2 ms at 32 MB
+    on this machine), well under the rounds it saves.
+    """
+    return max(16, 1 << int(16 * max(n_keys, 1) - 1).bit_length())
+
+
+def _single_int_part(parts):
+    """The raw int64 array when the key is ONE non-nullable integer part
+    (TPC-H's hot case: orderkey/partkey/custkey, non-null dictionary
+    codes), else None.  Such keys get two shortcuts: ``_mix64`` is a
+    BIJECTION on u64, so the hash is collision-free and raw-key
+    verification is unnecessary; and the raw values drive the
+    direct-address fast path below."""
+    if len(parts) != 1 or parts[0][1] is not None:
+        return None
+    d = parts[0][0]
+    if not jnp.issubdtype(d.dtype, jnp.integer):
+        return None
+    return d.astype(jnp.int64)
+
+
+def _direct_info(raw: Optional[jax.Array], valid: jax.Array, size: int):
+    """(raw, lo, fits) for direct addressing: when the runtime key range
+    fits the table, round 0 gives every distinct key its OWN slot
+    (``key - lo``), the while loop exits after one iteration, and the
+    whole insert degenerates to one scatter + one gather.  The f64 span
+    keeps the subtraction overflow-safe; any rounding slack is ~2^-53 of
+    the span, far below the <= size threshold's granularity."""
+    if raw is None:
+        return None
+    i64 = jnp.iinfo(jnp.int64)
+    lo = jnp.min(jnp.where(valid, raw, i64.max))
+    hi = jnp.max(jnp.where(valid, raw, i64.min))
+    fits = (hi.astype(jnp.float64) - lo.astype(jnp.float64)) < size
+    fits = fits & valid.any()
+    return raw, lo, fits
+
+
+def _combined_int_key(part_sides):
+    """Mixed-radix combination of 2+ non-float key parts into ONE int64.
+
+    ``part_sides``: per key part, a list of (data, flag_or_None, valid)
+    triples — one per SIDE (group-by passes one side; joins pass build and
+    probe, so radix ranges come from the union of both).  Per-part runtime
+    ranges become radix strides; nullability flags ride as an extra binary
+    digit.  Returns (keys: one i64 array per side, ok[traced bool scalar],
+    span_prod[traced f64]) — ``ok`` means every stride product stayed
+    below 2^62, making the combination INJECTIVE, so ``_mix64(key)`` is a
+    collision-free hash and the key qualifies for direct addressing when
+    ``span_prod`` also fits the table.  Where ~ok the combined values are
+    meaningless and callers must keep the generic hash + raw verification.
+    None when any part is floating (ranges don't express float equality
+    classes).
+    """
+    for sides in part_sides:
+        for d, _, _ in sides:
+            if jnp.issubdtype(d.dtype, jnp.floating):
+                return None
+    i64 = jnp.iinfo(jnp.int64)
+    n_sides = len(part_sides[0])
+    keys = [jnp.zeros(part_sides[0][s][0].shape[0], dtype=jnp.int64)
+            for s in range(n_sides)]
+    span_prod = jnp.float64(1.0)
+    ok = jnp.bool_(True)
+    for sides in part_sides:
+        lo = jnp.int64(i64.max)
+        hi = jnp.int64(i64.min)
+        any_v = jnp.bool_(False)
+        svalids = []
+        for d, flag, valid in sides:
+            d = d.astype(jnp.int64)
+            sv = valid if flag is None else (valid & (flag == 1))
+            svalids.append(sv)
+            lo = jnp.minimum(lo, jnp.min(jnp.where(sv, d, i64.max)))
+            hi = jnp.maximum(hi, jnp.max(jnp.where(sv, d, i64.min)))
+            any_v = any_v | sv.any()
+        lo = jnp.where(any_v, lo, 0)
+        hi = jnp.where(any_v, hi, 0)
+        span_prod = span_prod * (hi.astype(jnp.float64)
+                                 - lo.astype(jnp.float64) + 1.0)
+        ok = ok & (span_prod < 2.0 ** 62)
+        stride = hi - lo + 1
+        has_flag = any(flag is not None for _, flag, _ in sides)
+        if has_flag:
+            span_prod = span_prod * 2.0
+            ok = ok & (span_prod < 2.0 ** 62)
+        for s, (d, flag, _) in enumerate(sides):
+            d = d.astype(jnp.int64)
+            # where ~ok these wrap harmlessly (the caller masks); where
+            # ok, d - lo is in [0, span) and the product fits int64
+            dn = jnp.where(svalids[s], d - lo, 0)
+            k = keys[s] * stride + dn
+            if has_flag:
+                fl = (jnp.ones_like(dn) if flag is None
+                      else flag.astype(jnp.int64))
+                k = k * 2 + fl
+            keys[s] = k
+    return keys, ok, span_prod
+
+
+def _slot_at_round(h: jax.Array, k, size: int, direct) -> jax.Array:
+    s = (_mix64(h + (2 * k + 1).astype(jnp.uint64) * _GOLDEN)
+         & jnp.uint64(size - 1)).astype(jnp.int32)
+    if direct is not None:
+        raw, lo, fits = direct
+        d = jnp.clip(raw - lo, 0, size - 1).astype(jnp.int32)
+        s = jnp.where((k == 0) & fits, d, s)
+    return s
+
+
+_TBL_EMPTY = jnp.iinfo(jnp.int64).max
+_TBL_ROW_MASK = jnp.int64((1 << 32) - 1)
+
+
+def _hash_table_insert(h: jax.Array, valid: jax.Array, size: int,
+                       direct=None):
+    """Resolve every valid row to one table slot per distinct u64 hash.
+
+    Claims are priority-encoded as ``(round+1) << 32 | row`` and written
+    with ONE scatter-min per round: earlier rounds always beat later ones
+    and the smallest row wins within a round, so occupied slots are
+    permanent and the claim is deterministic — with no table-sized
+    temporary or merge per round (those dominated the profile at 4M-slot
+    tables).
+
+    Returns (slot[i32 per row], resident[i32 per row: the hash group's
+    first row, n where unresolved], resolved[bool], table[i64 size-array:
+    priority-encoded claim, _TBL_EMPTY where free], rounds used).
+    """
+    n = h.shape[0]
+    n32 = jnp.int32(n)
+    rows = jnp.arange(n, dtype=jnp.int64)
+
+    def cond(st):
+        k, _, _, _, active = st
+        return (k < _HASH_MAX_ROUNDS) & active.any()
+
+    def body(st):
+        k, table, slot, resident, active = st
+        s_k = _slot_at_round(h, k, size, direct)
+        idx = jnp.where(active, s_k, size)
+        val = ((k + 1).astype(jnp.int64) << 32) | rows
+        table = table.at[idx].min(val, mode="drop")
+        tv = table[s_k]
+        res = (tv & _TBL_ROW_MASK).astype(jnp.int32)
+        ok = (active & (tv != _TBL_EMPTY)
+              & (h[jnp.clip(res, 0, n32 - 1)] == h))
+        slot = jnp.where(ok, s_k, slot)
+        resident = jnp.where(ok, res, resident)
+        return k + 1, table, slot, resident, active & ~ok
+
+    st = (jnp.int32(0), jnp.full(size, _TBL_EMPTY), jnp.zeros(n, jnp.int32),
+          jnp.full(n, n32), valid)
+    k, table, slot, resident, active = jax.lax.while_loop(cond, body, st)
+    return slot, resident, valid & ~active, table, k
+
+
+def _group_hashed_codes(key_cols: List[Column],
+                        row_valid: Optional[jax.Array], cap: int):
+    """Row-order dense group codes without any sort (CPU/GPU strategy).
+
+    Returns (codes[i64 per row, trash slot == cap for invalid rows],
+    first_rows[cap-sized original-row index per group], num_groups,
+    collision).  num_groups comes back as cap+1 when the table could not
+    resolve every key (more groups than cap, or pathological congestion),
+    which rides the existing ngroups escalation: the caller recompiles
+    with a doubled cap and therefore a doubled table.  Group numbering is
+    hash-slot order — unordered, as SQL allows.
+    """
+    n = len(key_cols[0])
+    parts = _key_parts(key_cols)
+    h = _hash_group_parts(parts)
+    valid = jnp.ones(n, bool) if row_valid is None else row_valid
+    size = _hash_table_size(cap)
+    single = _single_int_part(parts)
+    direct = _direct_info(single, valid, size)
+    combo_ok = None
+    if single is None:
+        combo = _combined_int_key([[(d, flag, valid)] for d, flag in parts])
+        if combo is not None:
+            # multi-part non-float keys: where the runtime radix product
+            # fits, the combination is injective — collision-free mix hash
+            # plus direct addressing when it also fits the table
+            (key,), combo_ok, span_prod = combo
+            h = jnp.where(combo_ok, _mix64(key.astype(jnp.uint64)), h)
+            direct = (key, jnp.int64(0),
+                      combo_ok & (span_prod <= jnp.float64(size)))
+    slot, resident, resolved, table, _ = _hash_table_insert(h, valid, size,
+                                                            direct)
+
+    coll = jnp.zeros((), bool)
+    if single is None:
+        # true u64 collisions: a resident with equal hash, different raw key
+        rc = jnp.clip(resident, 0, n - 1)
+        for d, flag in parts:
+            coll = coll | (resolved & (d[rc] != d)).any()
+            if flag is not None:
+                coll = coll | (resolved & (flag[rc] != flag)).any()
+        if combo_ok is not None:
+            # an injective combined key cannot collide; the raw check only
+            # matters where the combination overflowed
+            coll = coll & ~combo_ok
+    # else: _mix64 over one int part is a bijection — collisions impossible
+
+    # dense codes in first-occurrence order: rank the LEADER rows (a group's
+    # resident is its first row) and read every row's code through its
+    # resident — all O(n) ops, nothing table-sized
+    leader = resolved & (resident == jnp.arange(n, dtype=resident.dtype))
+    lrank = jnp.cumsum(leader.astype(jnp.int64)) - 1
+    real_groups = jnp.sum(leader.astype(jnp.int64))
+    unresolved = (valid & ~resolved).any()
+    # congestion (true group count unknowable) reports the impossible value
+    # n+1 — _check_flags reads any ng > input rows as "table saturated" and
+    # jumps the cap hard; a RESOLVED overflow reports the exact count, so
+    # the recompiled cap lands tight
+    num_groups = jnp.where(unresolved, jnp.int64(n + 1), real_groups)
+
+    codes_raw = lrank[jnp.clip(resident, 0, n - 1)]
+    codes = jnp.where(resolved, jnp.minimum(codes_raw, cap), cap)
+    fr_idx = jnp.where(leader & (codes < cap), codes, cap)
+    first_rows = (jnp.full(cap, n, dtype=jnp.int64)
+                  .at[fr_idx].min(jnp.arange(n, dtype=jnp.int64),
+                                  mode="drop"))
+    first_rows = jnp.clip(first_rows, 0, max(n - 1, 0))
+    return codes, first_rows, num_groups, coll

@@ -786,7 +786,7 @@ _PROG_CACHE_CAP = 64
 
 
 def _make_spmd_scan(node: RelNode, context) -> LogicalTableScan:
-    from ..physical.compiled import _stage_table_name
+    from ..physical.stage_exec import _stage_table_name
     return LogicalTableScan(
         schema_name=_SPMD_SCHEMA,
         table_name=_stage_table_name(node, context),
@@ -797,7 +797,7 @@ def _make_spmd_scan(node: RelNode, context) -> LogicalTableScan:
 def _make_stage_body(stage_plan: RelNode, context, scans, n_dev: int,
                      meta: Dict):
     """The shard_map body: rebuild per-device local tables from the flat
-    arg list (physical/compiled._flatten_tables order), walk the stage
+    arg list (physical/identity._flatten_tables order), walk the stage
     plan, emit every output through the uniform P(ROW_AXIS) out-spec."""
 
     def body(*flat):
@@ -864,7 +864,7 @@ def _mesh_sig(mesh) -> str:
 
 def _stage_digest(plan_fp: str, inputs_fp, mesh, meta: Dict) -> str:
     """Cross-process identity of one stage program: canonical plan (temp
-    names -> position-stable placeholders, mirroring compiled.py), input
+    names -> position-stable placeholders, mirroring physical/identity.py), input
     layout, mesh signature, the recorded dispatch decisions (a different
     statistics state compiles its own variant instead of colliding), and
     the lowering knobs baked into the trace.  The program store digest
@@ -885,7 +885,7 @@ def _stage_digest(plan_fp: str, inputs_fp, mesh, meta: Dict) -> str:
 
 def _pstore_load(digest: str, flat, n_outs: int):
     """Load + run this stage program from the persistent store (zero XLA
-    compiles); None on miss/corruption — mirrors compiled._pstore_attempt."""
+    compiles); None on miss/corruption — mirrors programs._pstore_attempt."""
     from ..runtime import program_store as _pstore
 
     store = _pstore.get_store()
@@ -934,7 +934,7 @@ def _annotate_stage_cost(fn) -> None:
     (EXPLAIN PROFILE and the query report's cost_err read it there).
     Env-gated before any profiler import; AOT/deserialized executables
     without a cost model just annotate nothing."""
-    from ..physical.compiled import _profile_on
+    from ..physical.programs import _profile_on
     if not _profile_on():
         return
     try:
@@ -1039,14 +1039,15 @@ def _compact(table: Table, valid) -> Table:
 
 def _run_stage(stage, context, mesh, counts: Dict[str, int]):
     """Execute one stage as a shard_map program; returns (table, valid,
-    meta).  Raises Unsupported / compiled.Unsupported / _Fallback."""
-    from ..physical import compiled as _C
+    meta).  Raises Unsupported / identity.Unsupported / _Fallback."""
+    from ..physical import identity as _I
+    from ..physical.programs import _profile_on
 
     n_dev = int(mesh.devices.size)
     scans: list = []
-    plan_fp = _C._fp_plan(stage.plan, context, scans)
-    inputs_fp = _C._fp_inputs(scans)
-    flat = _C._flatten_tables(scans)
+    plan_fp = _I._fp_plan(stage.plan, context, scans)
+    inputs_fp = _I._fp_inputs(scans)
+    flat = _I._flatten_tables(scans)
     for a in flat:
         if a.shape[0] % n_dev:
             raise Unsupported(f"global length {a.shape[0]} not divisible "
@@ -1075,7 +1076,7 @@ def _run_stage(stage, context, mesh, counts: Dict[str, int]):
             counts["spmd_join_flips"] = (counts.get("spmd_join_flips", 0)
                                          + len(e.tripped))
             continue
-        if valid is not None and _C._profile_on():
+        if valid is not None and _profile_on():
             # per-shard row counts -> skew ratio (max/mean): one host
             # fetch of the validity vector, paid only when profiling
             try:
@@ -1127,7 +1128,7 @@ def try_execute_spmd(plan: RelNode, context) -> Optional[Table]:
     """
     if not spmd_enabled(context):
         return None
-    from ..physical import compiled as _C
+    from ..physical import identity as _I
     from ..physical.stages import partition, stage_budget
     from ..runtime.statistics import record_choice
 
@@ -1139,7 +1140,7 @@ def try_execute_spmd(plan: RelNode, context) -> Optional[Table]:
         _gate_plan(core)
         graph = partition(core, stage_budget(None),
                           lambda sub: _make_spmd_scan(sub, context))
-    except (Unsupported, _C.Unsupported) as e:
+    except (Unsupported, _I.Unsupported) as e:
         _tel.inc("spmd_unsupported")
         logger.debug("spmd: unsupported plan (%s)", e)
         return None
@@ -1171,7 +1172,7 @@ def try_execute_spmd(plan: RelNode, context) -> Optional[Table]:
                 registered.append(name)
             else:
                 result = _apply_epilogue(_compact(table, valid), epilogue)
-    except (Unsupported, _C.Unsupported) as e:
+    except (Unsupported, _I.Unsupported) as e:
         _tel.inc("spmd_unsupported")
         logger.debug("spmd: unsupported at trace (%s)", e)
         return None

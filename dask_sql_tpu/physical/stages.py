@@ -1,12 +1,12 @@
 """Stage-graph partitioning: bound the size of every compiled program.
 
 XLA:TPU compile time grows superlinearly with the number of fused
-join/aggregate pipelines in one program (physical/compiled.py module
-docstring: ~50 s at 2 heavy nodes, ~400 s at 6, never-finishes at 8-9 in
-BENCH_r04/r05).  This module partitions a logical plan into a DAG of
-**stages**, each holding at most ``budget`` heavy nodes; the compiled
-executor traces and jits every stage as its own program, materializing
-stage outputs into padded capacity-class temp tables between them.
+join/aggregate pipelines in one program (~50 s at 2 heavy nodes, ~400 s at
+6, never finishes at 8-9: measured before the v5e bring-up).  This module
+partitions a logical plan into a DAG of **stages**, each holding at most
+``budget`` heavy nodes; the compiled executor (physical/stage_exec.py)
+traces and jits every stage as its own program, materializing stage outputs
+into padded capacity-class temp tables between them.
 
 What was measured for a v5e since (PR 27; AOT for a described chip, the
 chip's host compiles 2-3x slower): those minutes were the heavy nodes'
@@ -41,7 +41,7 @@ from ..plan.nodes import (LogicalAggregate, LogicalJoin, LogicalTableScan,
                           LogicalWindow, RelNode)
 
 #: Heavy-node budget per compiled program.  The default sits at the
-#: compile-time knee BENCH_r04/r05 measured (tens of seconds per program,
+#: compile-time knee measured then (tens of seconds per program,
 #: never minutes), and holds for a v5e: TPC-H Q5, six heavy nodes at SF1
 #: shapes, is one program of 94 s (module docstring).  Override with
 #: ``DSQL_STAGE_HEAVY`` (or the legacy ``DSQL_SPLIT_HEAVY``, kept for
@@ -68,7 +68,7 @@ def node_weight(rel: RelNode) -> int:
         # SEMI/ANTI with a non-equi residual lower through the payload
         # exist-test formulation whose compile cost dwarfs a plain
         # equi-join — TPC-H Q21 (two of them + two joins) SIGKILLed the
-        # TPU compile helper as one program (BENCH_r05).  Plain equi SEMI/ANTI
+        # TPU compile helper as one program.  Plain equi SEMI/ANTI
         # (Q4/Q20) compile like ordinary joins and keep weight 1.  The
         # residual test is the SAME decomposition the lowering uses
         # (_extract_equi_keys), so heuristic and lowering cannot drift.
@@ -99,7 +99,7 @@ class Stage:
     outputs this stage scans.
 
     The boundary scan's NAME is a content digest of the producing subtree
-    (canonical shape + scanned-table uids, physical/compiled.py
+    (canonical shape + scanned-table uids, physical/stage_exec.py
     ``_stage_table_name``) and doubles as the stage output's **subplan
     result-cache key** (runtime/result_cache.py): equal names imply equal
     data, so an overlapping query sharing this subtree may replay the

@@ -365,7 +365,7 @@ def _reorder_chain(root: LogicalJoin, filt_conjuncts: List[RexNode],
     # (possibly bushy) tree — a join node is a cross step only if no
     # connector within its subtree spans its two children. Linearizing the
     # original into a left-deep sequence would falsely count connected bushy
-    # joins as stranded and rewrite plans that need no help (ADVICE r1).
+    # joins as stranded and rewrite plans that need no help.
     leaf_iter = iter(range(len(leaves)))
 
     def tree_stranded(j: RelNode) -> Tuple[Set[int], int]:

@@ -6,15 +6,15 @@ device OOM) cannot be scheduled.  This module plants named injection sites
 at the layer boundaries —
 
   ``compile``        a stage/whole-plan program build+first call
-                     (physical/compiled.py _execute_single)
+                     (physical/programs.py obtain)
   ``materialize``    decoding a program's outputs to a host Table
                      (physical/compiled.py _materialize)
   ``stage_exec``     one stage-execution ATTEMPT of a stage-graph
-                     (physical/compiled.py _execute_stage_graph; fired
+                     (physical/stage_exec.py _execute_stage_graph; fired
                      once per attempt, so a replay fires it again)
   ``stage_replay``   a checkpointed stage REPLAY — the re-execution of a
                      failed stage from its materialized boundary temps
-                     (physical/compiled.py run_stage) — so CI can prove a
+                     (physical/stage_exec.py run_stage) — so CI can prove a
                      sabotaged replay path still degrades cleanly
   ``chunked_read``   uploading one out-of-HBM batch
                      (io/chunked.py ChunkedSource.batch_table)

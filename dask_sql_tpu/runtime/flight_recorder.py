@@ -326,7 +326,7 @@ def plan_history_bytes(plan, context) -> Optional[int]:
 
 
 # ---------------------------------------------------------------------------
-# recording hooks (telemetry._close_trace / physical.compiled.run_stage)
+# recording hooks (telemetry._close_trace / physical.stage_exec.run_stage)
 # ---------------------------------------------------------------------------
 
 def record_query(report, error: Optional[BaseException] = None) -> None:

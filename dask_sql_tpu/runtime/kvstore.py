@@ -5,7 +5,7 @@ boundaries with the SAME discipline — content-digest keys, atomic
 tmp+rename writes, and corrupt-file tolerance (a broken store file must
 degrade to "empty", never fail a query):
 
-- the learned-caps file (``DSQL_CAPS_FILE``, physical/compiled.py),
+- the learned-caps file (``DSQL_CAPS_FILE``, physical/caps.py),
 - the quarantine store (``DSQL_QUARANTINE_FILE``, runtime/quarantine.py),
 - the program store's metadata index (``DSQL_PROGRAM_STORE``,
   runtime/program_store.py).

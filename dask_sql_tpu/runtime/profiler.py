@@ -261,7 +261,7 @@ def plan_cost_bytes(plan, context) -> Optional[int]:
     process hasn't compiled (or store-loaded) the plan yet.  None =
     nothing captured, the caller keeps the shape heuristic."""
     try:
-        from ..physical.compiled import _fp_plan
+        from ..physical.identity import _fp_plan
         key = _fp_key(_fp_plan(plan, context, []))
     except Exception:
         return None

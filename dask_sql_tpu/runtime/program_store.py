@@ -1,10 +1,10 @@
 """Persistent cross-process program store: compiled stage executables on disk.
 
-BENCH_r05's wall was compilation, not execution: 19-615 s warm/compile per
-TPC-H query (an earlier backend and jax 0.4; not measured on the attached
-chip), re-paid by EVERY fresh process, while per-query execution was
+The wall of a benchmark run before the v5e bring-up was compilation, not
+execution: 19-615 s warm/compile per TPC-H query (an earlier backend and
+jax 0.4; not measured on the attached chip), re-paid by EVERY fresh process, while per-query execution was
 already sub-2 s.  The in-memory program cache
-(physical/compiled.py ``_cache``) and the learned-caps file soften repeat
+(physical/programs.py ``_cache``) and the learned-caps file soften repeat
 cost *within* a process lineage; this module removes the cross-process
 bill entirely: a successfully compiled stage program is serialized (the
 XLA executable itself, via ``jax.experimental.serialize_executable``) and
@@ -16,7 +16,7 @@ discipline (PAPERS.md) carried across process boundaries.
 Keying.  An entry is addressed by a digest of the executor's *canonical*
 program identity — the plan fingerprint with stage-boundary temp names
 rewritten to position-stable placeholders (boundary names embed per-process
-table uids, physical/compiled.py ``_stage_table_name``; the program itself
+table uids, physical/stage_exec.py ``_stage_table_name``; the program itself
 is uid-independent: it depends only on plan shape + input layout), the
 input-layout fingerprint (shapes/dtypes/dictionary CONTENT), and the
 backend strategy — folded with ``quarantine.device_fingerprint()`` and the

@@ -1,10 +1,10 @@
 """Cross-process failure quarantine + compile watchdog.
 
-BENCH_r05 names the failure domain this module contains: compile is both
-the dominant cost (per-query compiles up to 615 s on that run's backend)
-and the dominant failure site (10 compile_errors in one bench run), and a
+The failure domain this module contains: compile is both the dominant cost
+(per-query compiles up to 615 s on a backend before the v5e bring-up) and
+the dominant failure site (10 compile_errors in one benchmark run), and a
 compile that crashes or wedges the XLA helper dies WITH the process — the
-in-memory exile verdict (physical/compiled.py ``_cache[key] = _UNSUPPORTED``)
+in-memory exile verdict (physical/programs.py ``_cache[key] = _UNSUPPORTED``)
 is gone on restart, so every new process re-pays the doomed compile.
 Flare (PAPERS.md) keeps the same discipline for Spark native compilation:
 a hung or crashing program build must be remembered, not re-attempted.

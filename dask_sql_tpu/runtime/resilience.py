@@ -63,7 +63,7 @@ from typing import Callable, Optional, Tuple
 logger = logging.getLogger(__name__)
 
 # the declared compile-layer degradation policy, top rung first (the old
-# implicit "two-strike" special case in physical/compiled.py, made explicit)
+# implicit "two-strike" special case in physical/programs.py, made explicit)
 LADDER: Tuple[str, ...] = ("whole", "stages", "eager", "fail")
 
 

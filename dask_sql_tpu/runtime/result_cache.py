@@ -175,7 +175,7 @@ def _canon_rel(rel, acc: _Canon) -> None:
         if rel.schema_name != _SPLIT_SCHEMA:
             acc.scans.append((rel.schema_name, rel.table_name))
         # a __split__ boundary name is already a content digest of its
-        # producing subtree (physical/compiled._stage_table_name)
+        # producing subtree (physical/stage_exec._stage_table_name)
         acc.parts.append(f"Scan({rel.schema_name}.{rel.table_name})[{schema}]")
         return
     acc.parts.append(f"{t}(")
@@ -273,7 +273,7 @@ def plan_key(plan, context) -> Optional[CacheKey]:
 
 def stage_key(name: str) -> CacheKey:
     """Key for a stage-boundary subplan output.  ``name`` is the boundary
-    temp-table digest (physical/compiled._stage_table_name), which already
+    temp-table digest (physical/stage_exec._stage_table_name), which already
     content-addresses the subtree INCLUDING the uids of every scanned table
     — a catalog mutation changes the uids and therefore the name."""
     return CacheKey(f"stage:{name}", ())

@@ -589,7 +589,7 @@ def join_decision(rel, left_cols, right_cols, context
 
 
 # ---------------------------------------------------------------------------
-# compiled-path capacity hints (physical/compiled.py, physical/stages.py)
+# compiled-path capacity hints (physical/caps.py, physical/stages.py)
 # ---------------------------------------------------------------------------
 
 def _pad_pow2(n: int, lo: int = 64, hi: int = 1 << 20) -> int:

@@ -119,7 +119,7 @@ def _queries() -> Table:
 def _active() -> Table:
     import os
 
-    from ..physical import compiled as _compiled
+    from ..physical import tiering as _tiering
     from . import flight_recorder as _fr
     from . import scheduler as _sched
 
@@ -137,7 +137,7 @@ def _active() -> Table:
                      "elapsed_ms": w["waitedMillis"],
                      "est_bytes": w["estBytes"], "stages_done": 0,
                      "stages_total": 0, "pid": os.getpid()})
-    for fp in _compiled.inflight_background_compiles():
+    for fp in _tiering.inflight_background_compiles():
         rows.append({"state": "bg-compile",
                      "query": f"<background-compile:{fp[:32]}>",
                      "phase": "compile", "tier": "background",

@@ -32,7 +32,7 @@ resilience ``_bump`` path — every increment is atomic under one lock.
 **Metric-name stability contract.**  The counter keys in
 ``STABLE_COUNTERS`` and the histogram names in ``STABLE_HISTOGRAMS`` are a
 public, append-only interface: dashboards, ``GET /metrics`` scrapers and
-the BENCH_r*.json trajectory all key on them.  Renaming or repurposing one
+the chip benchmark (``chipbench/``) all key on them.  Renaming or repurposing one
 is a breaking change; add new names instead, and never reuse a retired
 name for a different meaning.  Prometheus names derive mechanically:
 counter ``k`` exports as ``dsql_<k>_total``, histogram ``h`` as
@@ -88,7 +88,7 @@ STABLE_COUNTERS: Tuple[str, ...] = (
     "stage_execs", "stage_replays", "stage_replay_saved_stages",
     "quarantine_skips", "quarantine_probes", "quarantine_marks",
     "watchdog_trips",
-    # tiered execution (physical/compiled.py): queries answered on the
+    # tiered execution (physical/tiering.py): queries answered on the
     # eager tier while their stage programs compiled in the background,
     # background compiles that landed / errored, and compile-worker
     # halvings under consecutive-compile-failure pressure
@@ -729,7 +729,7 @@ class QueryReport:
         tier: Optional[str] = None
         stored = False
         subplan_hits = 0
-        # execution tier (tiered execution, physical/compiled.py):
+        # execution tier (tiered execution, physical/tiering.py):
         # "compiled" / "eager" / "eager-compiling" (served on the eager
         # tier while the stage programs build in the background)
         exec_tier: Optional[str] = None
@@ -938,7 +938,7 @@ def _export_chrome_trace(report: QueryReport) -> None:
 
 def close_background_trace(trace: QueryTrace) -> QueryReport:
     """Close a NON-query trace (background compile daemon threads carry
-    their own — physical/compiled._background_compile): builds the report
+    their own — physical/tiering._background_compile): builds the report
     and exports the chrome trace WITHOUT counting a query, arming the
     slow-query log, or recording a history envelope."""
     trace.root.t1 = time.monotonic_ns()

@@ -210,7 +210,7 @@ def _stats(state: str, info: Optional["_QueryInfo"] = None) -> dict:
             out["cacheTier"] = info.cache_tier
         if info.subplan_cache_hits:
             out["subplanCacheHits"] = info.subplan_cache_hits
-        # execution tier (tiered execution, physical/compiled.py):
+        # execution tier (tiered execution, physical/tiering.py):
         # "compiled" / "eager" / "eager-compiling", plus the persistent
         # program-store loads this query was served warm from
         if info.tier:
@@ -520,7 +520,7 @@ def _engine_snapshot(state: "_AppState") -> dict:
     per-stage progress (flight recorder's live registry), scheduler queue
     depths, memory-ledger occupancy, cache tiers, quarantine verdicts,
     program-store stats, and the history ring's location."""
-    from ..physical import compiled as _compiled
+    from ..physical import tiering as _tiering
     from ..runtime import flight_recorder as _fr
     from ..runtime import program_store as _pstore
     from ..runtime import quarantine as _quar
@@ -566,7 +566,7 @@ def _engine_snapshot(state: "_AppState") -> dict:
             "bytes": pstore.total_bytes() if pstore.enabled() else 0,
         },
         "backgroundCompiles": {
-            "inflight": len(_compiled.inflight_background_compiles()),
+            "inflight": len(_tiering.inflight_background_compiles()),
             "done": int(counters.get("background_compiles_done", 0)),
             "errors": int(counters.get("background_compile_errors", 0)),
         },
@@ -1452,7 +1452,7 @@ def _make_handler(state: _AppState, base_url: str):
                 # no-op once the future started): the cancel token makes
                 # the running query raise QueryCancelled at its next
                 # checkpoint — queued stages are abandoned and in-flight
-                # compiles orphaned (physical/compiled.py stage graph)
+                # compiles orphaned (physical/stage_exec.py)
                 if cancel is not None:
                     cancel.set()
                 fut.cancel()

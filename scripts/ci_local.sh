@@ -92,13 +92,6 @@ echo "=== [2k] profile smoke (device-level query profiler) ==="
 # never even import the profiler
 python scripts/profile_smoke.py
 
-echo "=== [2l] perf sentinel (bench regression gate) ==="
-# the committed bench trajectory must sit inside the tolerance bands of
-# the published baseline, and the sentinel must prove it still catches a
-# doctored 2x regression
-python scripts/perf_sentinel.py
-python scripts/perf_sentinel.py --self-test
-
 echo "=== [2m] matview smoke (incremental view maintenance) ==="
 # a 1k-row append into a 1M-row base must refresh the maintained view
 # >=5x faster than recomputing the defining query, stay pandas-oracle

@@ -180,9 +180,9 @@ def main() -> int:
 
 if __name__ == "__main__":
     rc = main()
-    # skip interpreter teardown (same discipline as bench.py's stage
-    # children): the XLA CPU client occasionally aborts in its destructor
-    # after heavy device-buffer churn, long after every check has passed
+    # skip interpreter teardown: the XLA CPU client occasionally aborts in
+    # its destructor after heavy device-buffer churn, long after every check
+    # has passed
     sys.stdout.flush()
     sys.stderr.flush()
     os._exit(rc)

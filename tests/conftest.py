@@ -106,7 +106,7 @@ def _quarantine_off(request, monkeypatch):
 
 @pytest.fixture(autouse=True)
 def _tiering_off(request, monkeypatch):
-    """Tiered execution (physical/compiled.py, on by default in
+    """Tiered execution (physical/tiering.py, on by default in
     production) would answer every COLD query on the eager tier while the
     programs compile in the background — which would break every suite
     that asserts compiled-path usage or counts compiles synchronously.
@@ -223,14 +223,14 @@ def _mesh_off(request, monkeypatch):
 @pytest.fixture(autouse=True, scope="module")
 def _bounded_executable_lifetime():
     yield
-    from dask_sql_tpu.physical import compiled
+    from dask_sql_tpu.physical import caps, programs, tiering
     from dask_sql_tpu.runtime import faults, result_cache
-    compiled._cache.clear()
-    compiled._learned_caps.clear()
-    compiled._runtime_eager.clear()
-    with compiled._tier_lock:
-        compiled._tier_done.clear()
-        compiled._tier_inflight.clear()
+    programs._cache.clear()
+    caps._learned_caps.clear()
+    programs._runtime_eager.clear()
+    with tiering._tier_lock:
+        tiering._tier_done.clear()
+        tiering._tier_inflight.clear()
     result_cache.get_cache().clear()
     faults.reset()
     jax.clear_caches()

@@ -11,7 +11,7 @@ import os
 import pandas as pd
 import pytest
 
-from dask_sql_tpu.physical import compiled
+from dask_sql_tpu.physical import caps, compiled, identity, programs
 
 
 _needs_compiled = pytest.mark.skipif(
@@ -119,7 +119,7 @@ def test_cache_hit_on_repeat(c):
 def test_group_capacity_escalation(c, monkeypatch):
     # force a tiny initial capacity: the first run overflows, the host
     # recompiles with a doubled capacity, the result is still exact
-    monkeypatch.setattr(compiled, "DEFAULT_GROUP_CAP", 2)
+    monkeypatch.setattr(caps, "DEFAULT_GROUP_CAP", 2)
     rec = compiled.stats["recompiles"]
     comp, eager = _both_paths(
         c, "SELECT b, COUNT(*) AS n FROM df GROUP BY b")
@@ -135,8 +135,8 @@ def test_group_caps_persist_to_file(c, monkeypatch, tmp_path):
     # recompile costs a whole program compile
     caps_file = tmp_path / "caps.json"
     monkeypatch.setenv("DSQL_CAPS_FILE", str(caps_file))
-    monkeypatch.setattr(compiled, "DEFAULT_GROUP_CAP", 2)
-    monkeypatch.setattr(compiled, "_caps_disk", None)
+    monkeypatch.setattr(caps, "DEFAULT_GROUP_CAP", 2)
+    monkeypatch.setattr(caps, "_caps_disk", None)
     # distinct from the escalation test's query: the learned cap survives in
     # the restored in-memory dict after this test, and sharing a fingerprint
     # would rob that test of its recompile
@@ -146,10 +146,10 @@ def test_group_caps_persist_to_file(c, monkeypatch, tmp_path):
     assert compiled.stats["recompiles"] > rec
     assert caps_file.exists()
     # cold process: no programs, no in-memory caps — only the file
-    monkeypatch.setattr(compiled, "_cache", type(compiled._cache)())
-    monkeypatch.setattr(compiled, "_learned_caps",
-                        type(compiled._learned_caps)())
-    monkeypatch.setattr(compiled, "_caps_disk", None)
+    monkeypatch.setattr(programs, "_cache", type(programs._cache)())
+    monkeypatch.setattr(caps, "_learned_caps",
+                        type(caps._learned_caps)())
+    monkeypatch.setattr(caps, "_caps_disk", None)
     rec = compiled.stats["recompiles"]
     comp, eager = _both_paths(c, q)
     _assert_same(comp, eager, ordered=False)
@@ -409,7 +409,7 @@ def test_compiled_path_uses_device_string_bitmap(monkeypatch):
 
     from dask_sql_tpu import Context
     from dask_sql_tpu.ops import strings_fast
-    from dask_sql_tpu.physical import compiled
+    from dask_sql_tpu.physical import caps, compiled, identity, programs
 
     monkeypatch.setattr(strings_fast, "DEVICE_STRING_THRESHOLD", 1)
     c = Context()
@@ -430,7 +430,7 @@ def test_plan_splitting_matches_whole(monkeypatch, workers):
     """Plans above the heavy-node budget execute as a stage graph of
     bounded compiled programs with materialized temps between them (XLA:TPU
     compile time grows superlinearly with fused join count; TPC-H Q2's
-    9-heavy program never finished compiling in BENCH_r04).  Forced low
+    9-heavy program never finished compiling before stages).  Forced low
     budget via the legacy DSQL_SPLIT_HEAVY knob (compat path): the staged
     answer must agree with the eager answer and leave no temp schema
     behind — in both the serial and the worker-pool executor."""
@@ -474,8 +474,8 @@ def test_learned_split_hint(monkeypatch, tmp_path):
     from dask_sql_tpu.physical import compiled as cm
 
     monkeypatch.setenv("DSQL_CAPS_FILE", str(tmp_path / "caps.json"))
-    monkeypatch.setattr(cm, "_caps_disk", None)
-    monkeypatch.setattr(cm, "_learned_caps", type(cm._learned_caps)())
+    monkeypatch.setattr(caps, "_caps_disk", None)
+    monkeypatch.setattr(caps, "_learned_caps", type(caps._learned_caps)())
     data = generate_tpch(0.005)
     c = Context()
     for n, f in data.items():
@@ -484,9 +484,9 @@ def test_learned_split_hint(monkeypatch, tmp_path):
     staged = []  # stage counts of each graph execution
     orig = cm._execute_stage_graph
 
-    def spy(graph, context, query_fp, split_limit):
+    def spy(graph, context, query_fp, split_limit, run_program):
         staged.append(len(graph.stages))
-        return orig(graph, context, query_fp, split_limit)
+        return orig(graph, context, query_fp, split_limit, run_program)
 
     monkeypatch.setattr(cm, "_execute_stage_graph", spy)
 
@@ -497,13 +497,10 @@ def test_learned_split_hint(monkeypatch, tmp_path):
     # write the hint for this exact plan shape, as the failure path would
     # (which fingerprints the PARAMETERIZED plan — literals hoisted)
     from dask_sql_tpu.sql.parser import parse_sql
-    plan = cm._maybe_parameterize(
+    plan = identity._maybe_parameterize(
         c._get_plan(parse_sql(QUERIES[3])[0].query), count=False)
-    from dask_sql_tpu.ops.pallas_kernels import _strategy_on_tpu
-    scans = []
-    key = (cm._fp_plan(plan, c, scans), cm._fp_inputs(scans),
-           bool(_strategy_on_tpu()), cm._mesh_signature(c))
-    cm._learned_caps_put(key, {"__split__": 1})
+    caps._learned_caps_put(identity.program_key(plan, c).key,
+                           {"__split__": 1})
 
     got2 = c.sql(QUERIES[3], return_futures=False)
     assert staged and staged[0] >= 2, "hint must force the staged path"
@@ -512,8 +509,8 @@ def test_learned_split_hint(monkeypatch, tmp_path):
                                   check_dtype=False, rtol=1e-5, atol=1e-8)
 
     # a FRESH process state (cleared memo) still reads the hint from disk
-    monkeypatch.setattr(cm, "_caps_disk", None)
-    monkeypatch.setattr(cm, "_learned_caps", type(cm._learned_caps)())
+    monkeypatch.setattr(caps, "_caps_disk", None)
+    monkeypatch.setattr(caps, "_learned_caps", type(caps._learned_caps)())
     staged.clear()
     c.sql(QUERIES[3], return_futures=False)
     assert staged and staged[0] >= 2

@@ -157,15 +157,6 @@ def test_report_survives_query_error(ctx):
     assert rep.root.attrs.get("error")
 
 
-def test_last_timings_carries_phase_split(ctx):
-    ctx.sql(JOIN_GROUPBY, return_futures=False)
-    t = ctx.last_timings
-    for key in ("parse_ms", "plan_ms", "exec_ms", "fetch_ms"):
-        assert key in t
-    if os.environ.get("DSQL_COMPILE") != "0" and "compile_ms" in t:
-        assert t["compile_ms"] <= t["exec_ms"] + 1e-6
-
-
 def test_explain_analyze_returns_meta_table(ctx):
     """EXPLAIN ANALYZE is plain SQL returning a meta Table with a PLAN
     column — the shape the server's wire encoder (and any client) already

@@ -20,7 +20,7 @@ import pytest
 
 from benchmarks.tpch import QUERIES, generate_tpch
 from dask_sql_tpu import Context
-from dask_sql_tpu.physical import compiled
+from dask_sql_tpu.physical import caps, compiled, programs
 from dask_sql_tpu.runtime import faults, quarantine as Q
 from dask_sql_tpu.runtime import resilience as R
 from tests.conftest import assert_eq
@@ -34,9 +34,9 @@ AGG_Q = "SELECT user_id, SUM(b) AS sb FROM user_table_1 GROUP BY user_id"
 
 @pytest.fixture(autouse=True)
 def _fresh(monkeypatch):
-    compiled._cache.clear()
-    compiled._learned_caps.clear()
-    compiled._runtime_eager.clear()
+    programs._cache.clear()
+    caps._learned_caps.clear()
+    programs._runtime_eager.clear()
     faults.reset()
     monkeypatch.setenv("DSQL_RETRY_BASE_MS", "1")
     monkeypatch.delenv("DSQL_QUARANTINE_FILE", raising=False)
@@ -148,9 +148,9 @@ def test_sabotaged_replay_still_degrades_cleanly(c, monkeypatch):
 def _fresh_process():
     """Model a process restart: every in-memory verdict dies; only the
     quarantine FILE (and the catalog data) survives."""
-    compiled._cache.clear()
-    compiled._learned_caps.clear()
-    compiled._runtime_eager.clear()
+    programs._cache.clear()
+    caps._learned_caps.clear()
+    programs._runtime_eager.clear()
 
 
 @_needs_compiled

@@ -23,7 +23,7 @@ import pytest
 import jax
 
 from dask_sql_tpu import Context
-from dask_sql_tpu.physical import compiled
+from dask_sql_tpu.physical import caps, programs, tiering
 from dask_sql_tpu.runtime import program_store as ps
 from dask_sql_tpu.runtime import result_cache as rc
 from dask_sql_tpu.runtime import telemetry as tel
@@ -35,12 +35,12 @@ def _deltas(c0):
 
 
 def _forget_programs():
-    compiled._cache.clear()
-    compiled._learned_caps.clear()
-    compiled._runtime_eager.clear()
-    with compiled._tier_lock:
-        compiled._tier_done.clear()
-        compiled._tier_inflight.clear()
+    programs._cache.clear()
+    caps._learned_caps.clear()
+    programs._runtime_eager.clear()
+    with tiering._tier_lock:
+        tiering._tier_done.clear()
+        tiering._tier_inflight.clear()
     jax.clear_caches()
 
 

@@ -10,11 +10,12 @@ hang past the deadline, never a leaked ``__split__`` temp."""
 import os
 import time
 
+import numpy as np
 import pandas as pd
 import pytest
 
 from dask_sql_tpu import Context
-from dask_sql_tpu.physical import compiled
+from dask_sql_tpu.physical import caps, compiled, programs
 from dask_sql_tpu.runtime import faults, resilience as R
 from tests.conftest import assert_eq
 
@@ -32,9 +33,9 @@ _needs_compiled = pytest.mark.skipif(
 def _fresh(monkeypatch):
     """Per-test isolation: cached programs would bypass the compile site,
     and an armed spec must never leak into the next test."""
-    compiled._cache.clear()
-    compiled._learned_caps.clear()
-    compiled._runtime_eager.clear()
+    programs._cache.clear()
+    caps._learned_caps.clear()
+    programs._runtime_eager.clear()
     faults.reset()
     monkeypatch.setenv("DSQL_RETRY_BASE_MS", "1")
     yield
@@ -142,6 +143,29 @@ def test_persistent_compile_fault_walks_whole_stages_eager(c, monkeypatch):
     assert compiled.stats["split_hints"] >= h0 + 1, "whole→stages rung"
     assert compiled.stats["degradations"] >= d0 + 2, "stages→eager rung"
     _no_split_leak(c)
+
+
+@_needs_compiled
+def test_whole_to_stages_keeps_the_order_by_the_host_was_to_apply():
+    """Off the TPU a terminal ORDER BY / LIMIT is no part of the program
+    (``identity.program_key``); the plan that re-enters as stages after the
+    whole program failed to compile has to be the one that still holds it."""
+    rng = np.random.RandomState(3)
+    ctx = Context()
+    ctx.create_table("a", pd.DataFrame({"k": rng.randint(0, 40, 400)}))
+    ctx.create_table("b", pd.DataFrame({"k": np.arange(40),
+                                        "w": np.arange(40) * 0.5}))
+    query = ("SELECT a.k, SUM(b.w) AS s FROM a JOIN b ON a.k = b.k "
+             "GROUP BY a.k ORDER BY s DESC, a.k LIMIT 2")
+    expected = _eager_oracle(ctx, query)
+    h0, g0 = compiled.stats["split_hints"], compiled.stats["stage_graphs"]
+    with faults.inject("compile:1:fatal"):
+        got = ctx.sql(query, return_futures=False)
+    assert compiled.stats["split_hints"] == h0 + 1
+    assert compiled.stats["stage_graphs"] == g0 + 1
+    assert len(got) == 2
+    assert_eq(got, expected)
+    _no_split_leak(ctx)
 
 
 @_needs_compiled

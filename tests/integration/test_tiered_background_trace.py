@@ -1,5 +1,5 @@
 """Chrome-trace capture for background compile daemon threads
-(physical/compiled._background_compile): the daemon carries its own
+(physical/tiering._background_compile): the daemon carries its own
 ``background_compile`` trace, so DSQL_CHROME_TRACE_DIR sees the compile
 spans that previously ran outside any QueryTrace and vanished."""
 import json

@@ -11,7 +11,9 @@ import pytest
 
 from chipbench.data import tpch_gen
 from dask_sql_tpu import Context
-from dask_sql_tpu.physical import compiled as cm
+from dask_sql_tpu.physical import compiled as cm, programs
+from dask_sql_tpu.physical.caps import (_NeedsRecompile, _check_flags,
+                                        _learned_caps)
 from dask_sql_tpu.plan.nodes import LogicalJoin
 from dask_sql_tpu.sql.parser import parse_sql
 
@@ -43,8 +45,8 @@ def tpch():
 def tpu_strategy(monkeypatch):
     monkeypatch.setenv("DSQL_STRATEGY", "tpu")
     monkeypatch.delenv("DSQL_CAPS_FILE", raising=False)
-    cm._cache.clear()
-    cm._learned_caps.clear()
+    programs._cache.clear()
+    _learned_caps.clear()
 
 
 @pytest.mark.parametrize("name,sites", [
@@ -64,7 +66,7 @@ def test_the_sites_are_the_joins_that_another_join_takes_in(tpch, name,
 
 
 def _programs():
-    return [e for e in cm._cache.values() if e is not cm._UNSUPPORTED]
+    return [e for e in programs._cache.values() if e is not programs._UNSUPPORTED]
 
 
 def _live_sites(entry):
@@ -126,20 +128,20 @@ def test_a_site_that_overflows_recompiles_to_the_same_answer(tpch,
     shape = _shape("q5")
     ctx.sql(shape.SQL.format(**shape.params_at(shape.FIRST)),
             return_futures=False)
-    (base_key, learned), = cm._learned_caps.items()
+    (base_key, learned), = _learned_caps.items()
     tight = learned["cmpj2"]
     # a process that had learned on less data: the third join's output
     # does not fit, rows are dropped, and the flags say so
-    cm._learned_caps[base_key] = {**learned, "cmpj2": 1024}
-    cm._cache.clear()
+    _learned_caps[base_key] = {**learned, "cmpj2": 1024}
+    programs._cache.clear()
     recompiles = cm.stats["recompiles"]
     params = shape.params_at(shape.FIRST + 40)
     got = ctx.sql(shape.SQL.format(**params), return_futures=False)
     _assert_answer(shape, got, frames, params)
     assert cm.stats["recompiles"] == recompiles + 1
-    assert 1024 < cm._learned_caps[base_key]["cmpj2"] <= tight
+    assert 1024 < _learned_caps[base_key]["cmpj2"] <= tight
     # and the sites below it, whose counts were true, stayed as they were
-    assert {t: c for t, c in cm._learned_caps[base_key].items()
+    assert {t: c for t, c in _learned_caps[base_key].items()
             if t != "cmpj2"} == {t: c for t, c in learned.items()
                                  if t != "cmpj2"}
 
@@ -222,8 +224,8 @@ def _learned(sites, counts, caps=None):
                             for tag, n, _ in sites],
               "ngroup_caps": [cap for _, _, cap in sites]})
     try:
-        cm._check_flags(entry, np.array([0, 0] + list(counts)))
-    except cm._NeedsRecompile as again:
+        _check_flags(entry, np.array([0, 0] + list(counts)))
+    except _NeedsRecompile as again:
         return again.caps
     return None
 

@@ -5,7 +5,7 @@ The reference's HepPlanner never reorders connected trees either (its
 JoinCommuteRule/JoinAssociateRule set is not enabled in dask-sql's default
 program); reorder_joins exists to rescue comma-FROM queries whose textual
 order strands a leaf, and must leave connected plans — including BUSHY
-ones — exactly as written (ADVICE r1 finding 2).
+ones — exactly as written (a review's finding).
 """
 from dask_sql_tpu.plan.nodes import (
     Field, LogicalJoin, LogicalTableScan, RexCall, RexInputRef,

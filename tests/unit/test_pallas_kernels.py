@@ -218,7 +218,7 @@ def test_fixedpoint_zero_row_and_empty_input():
 
 
 # ---------------------------------------------------------------------------
-# TPU-compilability regression (ADVICE r5 high): the f64 fixed-point path
+# TPU-compilability regression (a review's finding): the f64 fixed-point path
 # must not trace frexp/ldexp — they lower to an s64 bitcast-convert the TPU
 # X64 rewrite does not implement, which silently exiled every f64
 # static-domain aggregate (the Q1 path) to eager.  The CPU-lowered HLO is
@@ -265,7 +265,7 @@ def test_dispatch_compile_smoke_no_64bit_bitcast():
 
 
 def test_fixedpoint_masked_outlier_does_not_coarsen_grid():
-    """ADVICE r5 medium: absmax must cover mask-CONTRIBUTING values only —
+    """A review's finding: absmax must cover mask-CONTRIBUTING values only —
     a filtered-out 1e300 row must not zero the valid sums."""
     vals = jnp.asarray([[1.0, 2.0, 1e300, 3.0]])
     codes = jnp.asarray([0, 0, 1, 1])

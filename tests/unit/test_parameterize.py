@@ -162,11 +162,11 @@ def test_kill_switch(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# fingerprint identity (physical/compiled._fp_plan)
+# fingerprint identity (physical/identity._fp_plan)
 # ---------------------------------------------------------------------------
 
 def _fp(ctx, plan):
-    from dask_sql_tpu.physical.compiled import _fp_plan
+    from dask_sql_tpu.physical.identity import _fp_plan
     params = []
     return _fp_plan(plan, ctx, [], params), params
 

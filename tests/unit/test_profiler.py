@@ -1,11 +1,9 @@
-"""Device-level query profiler (runtime/profiler.py) + perf sentinel.
+"""Device-level query profiler (runtime/profiler.py).
 
 Degradation is the contract under test: every consumer must survive a
 backend with no cost model (``cost_analysis`` absent/raising/None/empty),
-the disabled path must never import the profiler module, and the sentinel
-must judge old-format bench artifacts without a headline block.
+and the disabled path must never import the profiler module.
 """
-import json
 import os
 import subprocess
 import sys
@@ -15,11 +13,6 @@ import pytest
 from dask_sql_tpu import Context
 from dask_sql_tpu.runtime import profiler as prof
 from dask_sql_tpu.runtime import telemetry as tel
-
-SCRIPTS = os.path.join(os.path.dirname(__file__), "..", "..", "scripts")
-sys.path.insert(0, SCRIPTS)
-
-import perf_sentinel as ps  # noqa: E402
 
 
 @pytest.fixture(autouse=True)
@@ -308,103 +301,3 @@ def test_system_devices_table():
     assert len(out) == len(jax.local_devices())
     assert sorted(out["device_id"]) == sorted(
         d.id for d in jax.local_devices())
-
-
-# ---------------------------------------------------------------------------
-# perf sentinel
-# ---------------------------------------------------------------------------
-
-HL = {"schema": 1, "warm_exec_geomean_sec": 1.0, "first_arrival_sec": 4.0,
-      "program_store_hit_rate": 0.9, "vs_pandas_geomean": 2.0,
-      "compile_errors": 0}
-
-
-def test_extract_headline_new_format():
-    doc = {"metric": "tpch_q1_q22_geomean_wall", "value": 1.0,
-           "headline": dict(HL), "detail": {}}
-    assert ps.extract_headline(doc) == HL
-    # wrapped artifact form
-    assert ps.extract_headline({"n": 6, "rc": 0, "parsed": doc}) == HL
-
-
-def test_extract_headline_derives_from_old_detail():
-    doc = {"metric": "tpch_q1_q22_geomean_wall", "value": 0.5,
-           "vs_baseline": 1.3,
-           "detail": {"first_arrival_sec": {"1": 2.0, "3": 8.0},
-                      "program_store_hit_rate": 0.8,
-                      "compiled_stats": {"compile_errors": 2}}}
-    hl = ps.extract_headline(doc)
-    assert hl["warm_exec_geomean_sec"] == 0.5
-    assert hl["first_arrival_sec"] == pytest.approx(4.0)
-    assert hl["program_store_hit_rate"] == 0.8
-    assert hl["vs_pandas_geomean"] == 1.3
-    assert hl["compile_errors"] == 2
-
-
-def test_extract_headline_unusable():
-    assert ps.extract_headline({"n": 3, "rc": 124, "parsed": None}) is None
-    assert ps.extract_headline({"metric": "other_metric",
-                                "value": 9, "detail": {}}) is None
-
-
-def test_compare_directions():
-    base = dict(HL)
-    # identical: clean
-    reg, verd = ps.compare(base, dict(base), 0.25)
-    assert not reg and len(verd) == 5
-    # lower-better regresses upward
-    cur = dict(base, warm_exec_geomean_sec=2.0)
-    reg, _ = ps.compare(base, cur, 0.25)
-    assert [r["metric"] for r in reg] == ["warm_exec_geomean_sec"]
-    # higher-better regresses downward
-    cur = dict(base, program_store_hit_rate=0.5)
-    reg, _ = ps.compare(base, cur, 0.25)
-    assert [r["metric"] for r in reg] == ["program_store_hit_rate"]
-    # improvements never flag
-    cur = dict(base, warm_exec_geomean_sec=0.1, vs_pandas_geomean=10.0)
-    reg, _ = ps.compare(base, cur, 0.25)
-    assert not reg
-    # inside the band: tolerated
-    cur = dict(base, warm_exec_geomean_sec=1.2)
-    reg, _ = ps.compare(base, cur, 0.25)
-    assert not reg
-    # compile_errors may never increase, tolerance or not
-    cur = dict(base, compile_errors=1)
-    reg, _ = ps.compare(base, cur, 0.25)
-    assert [r["metric"] for r in reg] == ["compile_errors"]
-    # None on either side: metric skipped, not crashed
-    cur = dict(base, first_arrival_sec=None)
-    reg, verd = ps.compare(base, cur, 0.25)
-    assert not reg and len(verd) == 4
-
-
-def test_run_pass_and_fail(tmp_path):
-    base = tmp_path / "base.json"
-    cur = tmp_path / "cur.json"
-    base.write_text(json.dumps({"headline": dict(HL)}))
-    cur.write_text(json.dumps(
-        {"headline": dict(HL, warm_exec_geomean_sec=0.9)}))
-    code, report = ps.run(str(tmp_path), str(cur), str(base))
-    assert code == 0 and report["status"] == "pass"
-    cur.write_text(json.dumps(
-        {"headline": dict(HL, warm_exec_geomean_sec=5.0)}))
-    code, report = ps.run(str(tmp_path), str(cur), str(base))
-    assert code == 1 and report["regressions"]
-    # unreadable explicit input is an error, not a silent pass
-    code, _ = ps.run(str(tmp_path), str(tmp_path / "missing.json"),
-                     str(base))
-    assert code == 2
-
-
-def test_run_nothing_comparable_passes(tmp_path):
-    code, report = ps.run(str(tmp_path))
-    assert code == 0
-    assert "nothing comparable" in report["status"]
-
-
-def test_sentinel_on_repo_artifacts():
-    """The committed trajectory must pass the committed baseline — the
-    same invocation ci_local.sh [2l] runs."""
-    root = os.path.join(os.path.dirname(__file__), "..", "..")
-    code, report = ps.run(root)
-    assert code == 0, report

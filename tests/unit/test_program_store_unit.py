@@ -8,7 +8,7 @@ import time
 
 import pytest
 
-from dask_sql_tpu.physical import compiled
+from dask_sql_tpu.physical import caps, identity, programs
 from dask_sql_tpu.runtime import kvstore as kv
 from dask_sql_tpu.runtime import program_store as ps
 from dask_sql_tpu.runtime import telemetry as tel
@@ -58,12 +58,12 @@ def test_kvstore_mtime_cached_file(tmp_path):
 def test_caps_file_rides_kvstore(tmp_path, monkeypatch):
     path = str(tmp_path / "caps.json")
     monkeypatch.setenv("DSQL_CAPS_FILE", path)
-    monkeypatch.setattr(compiled, "_caps_disk", None)
+    monkeypatch.setattr(caps, "_caps_disk", None)
     base_key = ("plan", (("x",),), True)
-    compiled._learned_caps_put(base_key, {"agg0": 8192})
-    compiled._learned_caps.clear()
-    monkeypatch.setattr(compiled, "_caps_disk", None)
-    assert compiled._learned_caps_get(base_key) == {"agg0": 8192}
+    caps._learned_caps_put(base_key, {"agg0": 8192})
+    caps._learned_caps.clear()
+    monkeypatch.setattr(caps, "_caps_disk", None)
+    assert caps._learned_caps_get(base_key) == {"agg0": 8192}
 
 
 # ---------------------------------------------------------------------------
@@ -209,18 +209,18 @@ def test_canonical_key_rewrites_boundary_names():
            "Scan(__split__.tfedcba9876543210)[y]<>>")
     fp2 = ("Join(T|C=[@0])[s]<Scan(__split__.taaaabbbbccccdddd)[x]<>,"
            "Scan(__split__.t1111222233334444)[y]<>>")
-    k1 = compiled._canonical_program_key((fp1, "inputs", True))
-    k2 = compiled._canonical_program_key((fp2, "inputs", True))
+    k1 = identity._canonical_program_key((fp1, "inputs", True))
+    k2 = identity._canonical_program_key((fp2, "inputs", True))
     # different per-process uids, same structure -> same canonical key
     assert k1 == k2
     assert "__split__.#0" in k1[0] and "__split__.#1" in k1[0]
     # REPEATED boundary names must keep their equality structure
     fp3 = ("U<Scan(__split__.t0123456789abcdef)[x]<>,"
            "Scan(__split__.t0123456789abcdef)[x]<>>")
-    k3 = compiled._canonical_program_key((fp3, "i", True))
+    k3 = identity._canonical_program_key((fp3, "i", True))
     assert k3[0].count("__split__.#0") == 2
     # base-table scans are untouched
-    k4 = compiled._canonical_program_key(("Scan(root.t)[x]", "i", True))
+    k4 = identity._canonical_program_key(("Scan(root.t)[x]", "i", True))
     assert k4[0] == "Scan(root.t)[x]"
 
 
@@ -230,35 +230,35 @@ def test_canonical_key_rewrites_boundary_names():
 
 @pytest.fixture()
 def _clean_streak(monkeypatch):
-    monkeypatch.setattr(compiled, "_compile_fail_streak", 0)
+    monkeypatch.setattr(programs, "_compile_fail_streak", 0)
     monkeypatch.setenv("DSQL_COMPILE_WORKERS", "4")
     monkeypatch.setenv("DSQL_COMPILE_BACKOFF_AFTER", "2")
     yield
-    compiled._compile_fail_streak = 0
+    programs._compile_fail_streak = 0
 
 
 def test_compile_backoff_halves_and_recovers(_clean_streak):
-    assert compiled._compile_workers() == 4
+    assert programs._compile_workers() == 4
     before = tel.REGISTRY.get("compile_backoffs")
-    compiled._note_compile_result(False)
-    assert compiled._compile_workers() == 4  # one failure: not yet
-    compiled._note_compile_result(False)
-    assert compiled._compile_workers() == 2  # 2 consecutive -> halved
+    programs._note_compile_result(False)
+    assert programs._compile_workers() == 4  # one failure: not yet
+    programs._note_compile_result(False)
+    assert programs._compile_workers() == 2  # 2 consecutive -> halved
     assert tel.REGISTRY.get("compile_backoffs") == before + 1
-    compiled._note_compile_result(False)
-    compiled._note_compile_result(False)
-    assert compiled._compile_workers() == 1  # 4 consecutive -> quartered
+    programs._note_compile_result(False)
+    programs._note_compile_result(False)
+    assert programs._compile_workers() == 1  # 4 consecutive -> quartered
     assert tel.REGISTRY.get("compile_backoffs") == before + 2
     for _ in range(20):
-        compiled._note_compile_result(False)
-    assert compiled._compile_workers() == 1  # floor of one worker
-    compiled._note_compile_result(True)
-    assert compiled._compile_workers() == 4  # any success restores
+        programs._note_compile_result(False)
+    assert programs._compile_workers() == 1  # floor of one worker
+    programs._note_compile_result(True)
+    assert programs._compile_workers() == 4  # any success restores
 
 
 def test_compile_backoff_respects_stage_cap(_clean_streak):
-    assert compiled._compile_workers(2) == 2
-    compiled._note_compile_result(False)
-    compiled._note_compile_result(False)
-    assert compiled._compile_workers(8) == 2
-    assert compiled._compile_workers(1) == 1
+    assert programs._compile_workers(2) == 2
+    programs._note_compile_result(False)
+    programs._note_compile_result(False)
+    assert programs._compile_workers(8) == 2
+    assert programs._compile_workers(1) == 1

@@ -221,12 +221,12 @@ def test_epoch_bumps_on_every_mutation_path(ctx):
 def test_stage_table_name_uses_canonical_shape(ctx):
     """Two subplans differing only in VALUES contents must get distinct
     stage-boundary digests (the subplan cache replays by that name)."""
-    from dask_sql_tpu.physical import compiled
+    from dask_sql_tpu.physical import stage_exec
 
     p1 = _plan(ctx, "SELECT * FROM (VALUES (1), (2)) AS v(x)")
     p2 = _plan(ctx, "SELECT * FROM (VALUES (3), (4)) AS v(x)")
-    assert compiled._stage_table_name(p1, ctx) != \
-        compiled._stage_table_name(p2, ctx)
+    assert stage_exec._stage_table_name(p1, ctx) != \
+        stage_exec._stage_table_name(p2, ctx)
 
 
 # ---------------------------------------------------------------------------

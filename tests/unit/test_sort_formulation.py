@@ -11,7 +11,7 @@ import pytest
 
 from dask_sql_tpu import Context
 from dask_sql_tpu.ops.kernels import _u32_channels, lexsort_by_passes
-from dask_sql_tpu.physical import compiled as cm
+from dask_sql_tpu.physical import caps, compiled as cm, programs
 
 RNG = np.random.RandomState(27)
 N = 3000
@@ -111,8 +111,8 @@ def _traced(monkeypatch, sort_rows_max, lexsort_rows_max):
     monkeypatch.delenv("DSQL_CAPS_FILE", raising=False)
     monkeypatch.setattr(cm, "SORT_ROWS_MAX", sort_rows_max)
     monkeypatch.setattr(cm, "LEXSORT_ROWS_MAX", lexsort_rows_max)
-    cm._cache.clear()
-    cm._learned_caps.clear()
+    programs._cache.clear()
+    caps._learned_caps.clear()
     jaxprs = []
     build = cm._build
 
@@ -174,8 +174,8 @@ def test_a_stage_span_says_which_stage_it_is(monkeypatch):
     of a plan cut into stage programs; the dispatch inside a stage carries
     what a whole-plan dispatch carries."""
     monkeypatch.setenv("DSQL_STAGE_HEAVY", "1")
-    cm._cache.clear()
-    cm._learned_caps.clear()
+    programs._cache.clear()
+    caps._learned_caps.clear()
     rng = np.random.RandomState(5)
     ctx = Context()
     ctx.create_table("items", pd.DataFrame({
@@ -206,8 +206,8 @@ def test_a_grouped_aggregate_over_a_join_compacts_its_input(monkeypatch):
     program), and answers as the uncompacted one does."""
     monkeypatch.setenv("DSQL_STRATEGY", "tpu")
     monkeypatch.delenv("DSQL_CAPS_FILE", raising=False)
-    cm._cache.clear()
-    cm._learned_caps.clear()
+    programs._cache.clear()
+    caps._learned_caps.clear()
     rng = np.random.RandomState(9)
     n = 1 << 17
     items = pd.DataFrame({"okey": rng.randint(0, 40000, n),

@@ -518,31 +518,29 @@ def test_staged_report_sums_the_phases_over_its_stages(tpch, monkeypatch):
 def _program(context, sql):
     """(entry, lowered text with locations) of the program that serves
     ``sql`` whole."""
-    from dask_sql_tpu.physical import compiled as cm
+    from dask_sql_tpu.physical import programs
 
     context.sql(sql, return_futures=False)      # compiles, or is served
     context.sql(sql, return_futures=False)      # served from the cache
     dispatch, = [s for s in context.last_report.root.walk()
                  if s.name == "dispatch"]
-    entry, = [e for e in cm._cache.values()
-              if e is not cm._UNSUPPORTED
+    entry, = [e for e in programs._cache.values()
+              if e is not programs._UNSUPPORTED
               and e.name == dispatch.attrs["program"]]
-    return entry, entry.fn.lower(*_flat_shapes(context, sql, cm)).as_text(
+    return entry, entry.fn.lower(*_flat_shapes(context, sql)).as_text(
         debug_info=True)
 
 
-def _flat_shapes(context, sql, cm):
+def _flat_shapes(context, sql):
     """The argument list ``_execute_single`` would bind, as shapes."""
     import jax
 
+    from dask_sql_tpu.physical import compiled, identity
     from dask_sql_tpu.sql.parser import parse_sql
-    plan = cm._maybe_parameterize(
-        context._get_plan(parse_sql(sql)[0].query), count=False)
-    if type(plan).__name__ == "LogicalSort":
-        plan = plan.input       # off the TPU a terminal sort runs on the host
-    scans, params = [], []
-    cm._fp_plan(plan, context, scans, params)
-    flat = cm._flatten_tables(scans) + cm._param_args(params)
+    pk = identity.program_key(identity._maybe_parameterize(
+        context._get_plan(parse_sql(sql)[0].query), count=False), context)
+    flat = identity._flatten_tables(pk.scans) \
+        + compiled._param_args(pk.params)
     return [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in flat]
 
 
