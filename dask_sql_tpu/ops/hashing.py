@@ -9,7 +9,7 @@ and owns the flags they report through; the eager twins live in
 """
 from __future__ import annotations
 
-from typing import List, Optional, Tuple
+from typing import List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -221,21 +221,50 @@ def _single_int_part(parts):
     return d.astype(jnp.int64)
 
 
+class _Direct(NamedTuple):
+    """Direct addressing of one side's keys: where ``fits``, round 0's slot
+    of a key is ``raw - lo`` and every key of ``[lo, hi]`` owns its slot."""
+    raw: jax.Array
+    lo: jax.Array
+    hi: jax.Array
+    fits: jax.Array
+
+
+def _int_span(lo: jax.Array, hi: jax.Array) -> jax.Array:
+    """``hi - lo`` as f64, exact below 2^53 at any magnitude of the keys.
+    The f64 difference alone is overflow-safe but rounds each operand (by
+    up to 2^10 near int64's limits); the int64 difference is exact but
+    wraps from 2^63.  Where the first reads under 2^62 the second cannot
+    have wrapped."""
+    approx = hi.astype(jnp.float64) - lo.astype(jnp.float64)
+    return jnp.where(approx < 2.0 ** 62, (hi - lo).astype(jnp.float64),
+                     approx)
+
+
 def _direct_info(raw: Optional[jax.Array], valid: jax.Array, size: int):
-    """(raw, lo, fits) for direct addressing: when the runtime key range
-    fits the table, round 0 gives every distinct key its OWN slot
+    """``_Direct`` for direct addressing: when the runtime key range fits
+    the table, round 0 gives every distinct key its OWN slot
     (``key - lo``), the while loop exits after one iteration, and the
-    whole insert degenerates to one scatter + one gather.  The f64 span
-    keeps the subtraction overflow-safe; any rounding slack is ~2^-53 of
-    the span, far below the <= size threshold's granularity."""
+    whole insert degenerates to one scatter + one gather.  ``fits`` is
+    exact (``_int_span``): a probe that skips the hash check on its word
+    (``_direct_probe``) has nothing else to catch a key clipped into a
+    neighbour's slot."""
     if raw is None:
         return None
     i64 = jnp.iinfo(jnp.int64)
     lo = jnp.min(jnp.where(valid, raw, i64.max))
     hi = jnp.max(jnp.where(valid, raw, i64.min))
-    fits = (hi.astype(jnp.float64) - lo.astype(jnp.float64)) < size
-    fits = fits & valid.any()
-    return raw, lo, fits
+    fits = (_int_span(lo, hi) < size) & valid.any()
+    return _Direct(raw, lo, hi, fits)
+
+
+def _combined_direct(key: jax.Array, ok: jax.Array, span_prod: jax.Array,
+                     size: int) -> _Direct:
+    """``_Direct`` of a ``_combined_int_key``: its keys lie in
+    ``[0, span_prod)``, so they address the table where that fits it."""
+    fits = ok & (span_prod <= jnp.float64(size))
+    hi = jnp.where(fits, span_prod, 1.0).astype(jnp.int64) - 1
+    return _Direct(key, jnp.int64(0), hi, fits)
 
 
 def _combined_int_key(part_sides):
@@ -278,8 +307,7 @@ def _combined_int_key(part_sides):
             any_v = any_v | sv.any()
         lo = jnp.where(any_v, lo, 0)
         hi = jnp.where(any_v, hi, 0)
-        span_prod = span_prod * (hi.astype(jnp.float64)
-                                 - lo.astype(jnp.float64) + 1.0)
+        span_prod = span_prod * (_int_span(lo, hi) + 1.0)
         ok = ok & (span_prod < 2.0 ** 62)
         stride = hi - lo + 1
         has_flag = any(flag is not None for _, flag, _ in sides)
@@ -304,9 +332,8 @@ def _slot_at_round(h: jax.Array, k, size: int, direct) -> jax.Array:
     s = (_mix64(h + (2 * k + 1).astype(jnp.uint64) * _GOLDEN)
          & jnp.uint64(size - 1)).astype(jnp.int32)
     if direct is not None:
-        raw, lo, fits = direct
-        d = jnp.clip(raw - lo, 0, size - 1).astype(jnp.int32)
-        s = jnp.where((k == 0) & fits, d, s)
+        d = jnp.clip(direct.raw - direct.lo, 0, size - 1).astype(jnp.int32)
+        s = jnp.where((k == 0) & direct.fits, d, s)
     return s
 
 
@@ -357,6 +384,30 @@ def _hash_table_insert(h: jax.Array, valid: jax.Array, size: int,
     return slot, resident, valid & ~active, table, k
 
 
+def _row_id_table(table: jax.Array, n: int) -> jax.Array:
+    """What a probe needs of the insert's claim table, in 32 bits: the
+    resident's row, ``n`` where the slot is free.  The round in a claim's
+    upper half orders the insert's scatter-min and is never read again, and
+    a 64-bit array is two 32-bit ones on the chip: a gather out of this
+    table is one where a gather out of ``table`` is two."""
+    return jnp.where(table == _TBL_EMPTY, jnp.int32(n),
+                     (table & _TBL_ROW_MASK).astype(jnp.int32))
+
+
+def _direct_probe(rowtab: jax.Array, direct: _Direct, n: int) -> jax.Array:
+    """Round 0 of a probe whose table is direct-addressed: one 32-bit gather
+    and a range test.  Where ``direct.fits`` every build key owns slot
+    ``key - lo``, so a claimed slot at ``raw - lo`` holds the probe's own
+    key and the resident's hash has nothing to add; a key outside
+    ``[lo, hi]`` has no slot (and ``raw - lo`` may have wrapped: it is read
+    only inside the range).  Returns the candidate build row per probe row,
+    ``n`` where there is none or the table is not direct-addressed."""
+    in_range = direct.fits & (direct.raw >= direct.lo) \
+        & (direct.raw <= direct.hi)
+    d = jnp.where(in_range, direct.raw - direct.lo, 0).astype(jnp.int32)
+    return jnp.where(in_range, rowtab[d], jnp.int32(n))
+
+
 def _group_hashed_codes(key_cols: List[Column],
                         row_valid: Optional[jax.Array], cap: int):
     """Row-order dense group codes without any sort (CPU/GPU strategy).
@@ -385,8 +436,7 @@ def _group_hashed_codes(key_cols: List[Column],
             # plus direct addressing when it also fits the table
             (key,), combo_ok, span_prod = combo
             h = jnp.where(combo_ok, _mix64(key.astype(jnp.uint64)), h)
-            direct = (key, jnp.int64(0),
-                      combo_ok & (span_prod <= jnp.float64(size)))
+            direct = _combined_direct(key, combo_ok, span_prod, size)
     slot, resident, resolved, table, _ = _hash_table_insert(h, valid, size,
                                                             direct)
 

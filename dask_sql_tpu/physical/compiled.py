@@ -57,12 +57,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops import groupby as G
-from ..ops.hashing import (_TBL_EMPTY, _TBL_ROW_MASK, _U64_MAX,
-                           _combined_int_key, _decode_static_keys,
-                           _direct_info, _group_hashed_codes, _hash_parts,
+from ..ops.hashing import (_U64_MAX, _combined_direct, _combined_int_key,
+                           _decode_static_keys, _direct_info,
+                           _direct_probe, _group_hashed_codes, _hash_parts,
                            _hash_table_insert, _hash_table_size,
                            _join_key_parts, _keys_valid, _mix64,
-                           _slot_at_round, _try_static_codes)
+                           _row_id_table, _slot_at_round, _try_static_codes)
 from ..ops.kernels import (_INT64_MIN, canon_f64, compact_indices,
                            comparable_data, lexsort_by_passes,
                            orderable_int64, unify_string_codes)
@@ -219,6 +219,9 @@ class _Tracer:
         self._join_site_counter = 0
         # rows the program's joins take in, probe + build of each: static
         self.join_rows = 0
+        # one device bool a hash-table join, in trace order: whether its
+        # table was direct-addressed, so its probe was ``_direct_probe``
+        self.direct_probes: List[jax.Array] = []
         # filter nodes (by id) eligible for learned-capacity compaction —
         # computed by _compact_eligible over the whole plan before tracing
         self.compact_ok: set = set()
@@ -1044,7 +1047,9 @@ class _Tracer:
         """Open-addressing hash join, the CPU/GPU strategy: insert build
         row ids into a power-of-2 table (empty-slot claim rounds, see
         _hash_table_insert), probe with one gather chain per round actually
-        used.  Verification always compares raw key parts, so lossy hashes
+        used; where the data lets the table be direct-addressed, round 0 is
+        one 32-bit gather and the only round (``_direct_probe``).
+        Verification always compares raw key parts, so lossy hashes
         only add collisions — caught by the flags and rerun eager.  SEMI/
         ANTI residual exist-tests aggregate (count, min, max) per slot with
         cheap scatters, which the sorted-gather strategy could not express.
@@ -1066,8 +1071,7 @@ class _Tracer:
             bh = _mix64(braw1.astype(jnp.uint64))   # clamp-free, clean
             ph = _mix64(praw1.astype(jnp.uint64))
             direct_b = _direct_info(braw1, bvalid, size)
-            if direct_b is not None:
-                direct_p = (praw1, direct_b[1], direct_b[2])
+            direct_p = direct_b._replace(raw=praw1)
         else:
             # multi-part keys: mixed-radix combination over the UNION of
             # both sides' runtime ranges — injective where the radix
@@ -1082,12 +1086,12 @@ class _Tracer:
                                _mix64(bkey.astype(jnp.uint64)), bh)
                 ph = jnp.where(combo_ok,
                                _mix64(pkey.astype(jnp.uint64)), ph)
-                fits = combo_ok & (span_prod <= jnp.float64(size))
-                direct_b = (bkey, jnp.int64(0), fits)
-                direct_p = (pkey, jnp.int64(0), fits)
+                direct_b = _combined_direct(bkey, combo_ok, span_prod, size)
+                direct_p = direct_b._replace(raw=pkey)
         with jax.named_scope("dsql.join_build"):
             slot, resident, resolved, table, rounds = _hash_table_insert(
                 bh, bvalid, size, direct_b)
+            rowtab = _row_id_table(table, nb)
 
         raw_mismatch = jnp.zeros((), bool)
         if not bij:
@@ -1118,9 +1122,8 @@ class _Tracer:
         def probe_body(st):
             k, cand = st
             s_k = _slot_at_round(ph, k, size, direct_p)
-            tv = table[s_k]
-            r = (tv & _TBL_ROW_MASK).astype(jnp.int32)
-            hit = (tv != _TBL_EMPTY) & (bh[jnp.clip(r, 0, nb32 - 1)] == ph)
+            r = rowtab[s_k]
+            hit = (r != nb32) & (bh[jnp.clip(r, 0, nb32 - 1)] == ph)
             cand = jnp.where((cand == nb32) & hit, r, cand)
             return k + 1, cand
 
@@ -1129,8 +1132,18 @@ class _Tracer:
             return k < rounds
 
         with jax.named_scope("dsql.join_probe"):
+            # a direct-addressed insert ends after round 0, which is peeled
+            # here, so the loop below runs no round at all; any other table
+            # discards the peeled candidates and loops from round 0
+            if direct_p is None:
+                direct = jnp.zeros((), bool)
+                cand0 = jnp.full(npr, nb32)
+            else:
+                direct = direct_p.fits
+                cand0 = _direct_probe(rowtab, direct_p, nb)
             _, cand = jax.lax.while_loop(
-                probe_cond, probe_body, (jnp.int32(0), jnp.full(npr, nb32)))
+                probe_cond, probe_body, (direct.astype(jnp.int32), cand0))
+        self.direct_probes.append(direct)
         found = cand < nb32
         cc = jnp.clip(cand, 0, nb - 1)
         match = found & pvalid
@@ -1235,8 +1248,11 @@ def _build(plan: RelNode, context, scans, caps: Dict[str, int], key,
         fb = jnp.zeros((), dtype=bool)
         for f in tr.fallback:
             fb = fb | f
+        # the tail is read by count from the END (``_materialize``): what
+        # ``_check_flags`` reads keeps its positions
         flags = jnp.stack([fb.astype(jnp.int64), count]
-                          + [g.astype(jnp.int64) for g in tr.ngroups])
+                          + [g.astype(jnp.int64)
+                             for g in tr.ngroups + tr.direct_probes])
         meta["names"] = list(out.table.names)
         meta["cols"] = [(c.stype, c.mask is not None, c.dictionary)
                         for c in out.table.columns]
@@ -1244,6 +1260,7 @@ def _build(plan: RelNode, context, scans, caps: Dict[str, int], key,
         meta["ngroup_caps"] = list(tr.ngroup_caps)
         meta["agg_sites"] = list(tr.agg_sites)
         meta["join_rows"] = tr.join_rows
+        meta["hash_table_joins"] = len(tr.direct_probes)
         meta["n_out"] = n
         outs: List[jax.Array] = [flags]
         for c in out.table.columns:
@@ -1320,6 +1337,18 @@ def _compact_attrs(meta: dict) -> dict:
             "join_rows": meta.get("join_rows", 0)}
 
 
+def _count_direct_probes(hash_table_joins: int, flags) -> None:
+    """``fits`` is the data's: which of a program's hash-table joins probed
+    with ``_direct_probe`` is known when its flags are in (their tail, one
+    bit a join)."""
+    if not hash_table_joins:
+        return
+    direct = int(flags[len(flags) - hash_table_joins:].sum())
+    _tel.annotate(hash_table_joins=hash_table_joins, direct_probes=direct)
+    _tel.inc("join_probes_direct", direct)
+    _tel.inc("join_probes_looped", hash_table_joins - direct)
+
+
 def _materialize(entry: _Compiled, outs) -> Table:
     _faults.maybe_fail("materialize")
     meta = entry.meta
@@ -1335,6 +1364,7 @@ def _materialize(entry: _Compiled, outs) -> Table:
         _tel.inc("fallbacks")
         return None
     _check_flags(entry, flags)
+    _count_direct_probes(meta.get("hash_table_joins", 0), flags)
     count = int(flags[1])
     cut = meta["has_valid"] and count < meta["n_out"]
     sel = np.nonzero(host[-1])[0] if small and cut else None

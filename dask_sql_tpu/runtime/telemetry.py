@@ -221,6 +221,10 @@ STABLE_COUNTERS: Tuple[str, ...] = (
     "ingest_backpressure_rejects", "ingest_wal_torn_lines",
     "server_ingest_requests", "fault_ingest",
     "mv_delta_compactions",
+    # hash-table joins of the compiled tier by how their probe ran (PR 30,
+    # physical/compiled.py _count_direct_probes): the data let the table be
+    # direct-addressed (one 32-bit gather a probe row), or the probe looped
+    "join_probes_direct", "join_probes_looped",
 )
 
 STABLE_HISTOGRAMS: Tuple[str, ...] = (
