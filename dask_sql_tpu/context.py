@@ -246,19 +246,12 @@ class Context:
                          schema_name, table_name, source.n_rows,
                          source.n_batches)
             return
-        table = InputUtil.to_table(input_table, file_format=format,
-                                   table_name=table_name, **kwargs)
-        row_valid = None
-        if self.mesh is not None:
-            from .parallel.mesh import shard_table_with_validity
-            table, row_valid = shard_table_with_validity(table, self.mesh)
-        # ingest-time statistics (runtime/statistics.py): NDV/min-max/null
-        # fraction/dense-int detection per column — the base layer of the
-        # adaptive-dispatch vertical.  Best-effort: a failed collection
-        # leaves entry.stats None and every consumer falls back to the
-        # pre-stats behavior.
-        from .runtime.statistics import collect_table_stats
-        stats = collect_table_stats(table, row_valid=row_valid)
+        from .runtime import telemetry as _tel
+        with _tel.load_scope(table=table_name.lower()) as load:
+            table, row_valid, stats = self._load(
+                load, InputUtil.to_frame_or_table(
+                    input_table, file_format=format, table_name=table_name,
+                    **kwargs))
         entry = TableEntry(table=table, statistics=statistics,
                            filepath=input_table if isinstance(input_table, str) else None,
                            gpu=gpu, row_valid=row_valid, stats=stats)
@@ -284,6 +277,54 @@ class Context:
                 logger.debug("ingest replay failed", exc_info=True)
         logger.debug("Registered table %s.%s (%d rows)", schema_name,
                      table_name, table.num_rows)
+
+    def _load(self, load, source):
+        """A frame (or a plugin's device Table) to ``(table, row_valid,
+        stats)``, resident.  Three steps, each a span under ``load`` and a
+        ``load_*_ms`` counter: ``load_encode`` (frame to host arrays:
+        strings to codes, dates, casts), ``load_stats`` (ingest statistics,
+        runtime/statistics.py: NDV/min-max/null fraction/dense-int detection
+        per column, the base layer of the adaptive-dispatch vertical;
+        best-effort: a failed collection leaves ``stats`` None and every
+        consumer falls back to the pre-stats behavior) and ``load_transfer``
+        (until the arrays are resident).  A frame's statistics are read off
+        its host arrays before the upload, so nothing comes back from the
+        device; a device Table has no host arrays and its statistics read
+        the device, after the mesh has placed it."""
+        import jax
+
+        from .runtime import telemetry as _tel
+        from .runtime.statistics import collect_table_stats
+
+        spans = {}
+        host = None
+        if not isinstance(source, Table):
+            with _tel.span("load_encode") as spans["encode"]:
+                host = Table.host_from_pandas(source)
+            with _tel.span("load_stats") as spans["stats"]:
+                stats = collect_table_stats(host)
+        with _tel.span("load_transfer") as spans["transfer"]:
+            table = source if host is None else host.to_device()
+            row_valid = None
+            if self.mesh is not None:
+                from .parallel.mesh import shard_table_with_validity
+                table, row_valid = shard_table_with_validity(table, self.mesh)
+            jax.block_until_ready([(c.data, c.mask) for c in table.columns])
+        if host is None:
+            with _tel.span("load_stats") as spans["stats"]:
+                stats = collect_table_stats(table, row_valid=row_valid)
+        rows = (source if host is None else host).num_rows
+        nbytes = sum(a.nbytes for c in table.columns
+                     for a in (c.data, c.mask) if a is not None)
+        load.attrs.update(rows=rows, bytes=nbytes, string_columns=sum(
+            c.stype.is_string for c in table.columns))
+        _tel.inc("load_tables")
+        _tel.inc("load_rows", rows)
+        _tel.inc("load_bytes", nbytes)
+        for step in ("encode", "stats", "transfer"):
+            _tel.inc(f"load_{step}_ms", int(round(
+                spans[step].wall_ms)) if step in spans else 0)
+        return table, row_valid, stats
 
     def drop_table(self, table_name: str, schema_name: Optional[str] = None):
         schema_name = schema_name or self.schema_name

@@ -31,6 +31,21 @@ class InputUtil(Pluggable):
                 return plugin.to_table(input_item, **kwargs)
         raise ValueError(f"Do not understand the input type {type(input_item)}")
 
+    @classmethod
+    def to_frame_or_table(cls, input_item: Any, **kwargs):
+        """What ``Context.create_table`` loads from: the pandas frame of a
+        plugin that reads one (so encoding, statistics and upload are the
+        load's own three steps), else the plugin's device Table."""
+        if not isinstance(input_item, list):
+            for plugin in cls.get_plugins():
+                if plugin.is_correct_input(input_item, **kwargs):
+                    read = (plugin.to_frame
+                            if isinstance(plugin, FrameInputPlugin)
+                            else plugin.to_table)
+                    return read(input_item, **kwargs)
+        # a list of inputs, or the error for a type no plugin knows
+        return cls.to_table(input_item, **kwargs)
+
 
 class BaseInputPlugin:
     def is_correct_input(self, input_item, **kwargs) -> bool:
@@ -38,6 +53,16 @@ class BaseInputPlugin:
 
     def to_table(self, input_item, **kwargs) -> Table:
         raise NotImplementedError
+
+
+class FrameInputPlugin(BaseInputPlugin):
+    """A plugin whose input becomes a pandas frame on its way to the device."""
+
+    def to_frame(self, input_item, **kwargs):
+        raise NotImplementedError
+
+    def to_table(self, input_item, **kwargs) -> Table:
+        return Table.from_pandas(self.to_frame(input_item, **kwargs))
 
 
 class DeviceTableInputPlugin(BaseInputPlugin):
@@ -50,18 +75,18 @@ class DeviceTableInputPlugin(BaseInputPlugin):
         return input_item
 
 
-class PandasLikeInputPlugin(BaseInputPlugin):
+class PandasLikeInputPlugin(FrameInputPlugin):
     """pandas DataFrame / Series (reference pandaslike.py:12)."""
 
     def is_correct_input(self, input_item, **kwargs):
         import pandas as pd
         return isinstance(input_item, (pd.DataFrame, pd.Series))
 
-    def to_table(self, input_item, **kwargs):
+    def to_frame(self, input_item, **kwargs):
         import pandas as pd
         if isinstance(input_item, pd.Series):
             input_item = input_item.to_frame()
-        return Table.from_pandas(input_item)
+        return input_item
 
 
 class DictInputPlugin(BaseInputPlugin):
@@ -74,7 +99,7 @@ class DictInputPlugin(BaseInputPlugin):
         return Table.from_pydict(input_item)
 
 
-class ArrowInputPlugin(BaseInputPlugin):
+class ArrowInputPlugin(FrameInputPlugin):
     def is_correct_input(self, input_item, **kwargs):
         try:
             import pyarrow as pa
@@ -82,18 +107,18 @@ class ArrowInputPlugin(BaseInputPlugin):
         except ImportError:
             return False
 
-    def to_table(self, input_item, **kwargs):
-        return Table.from_pandas(input_item.to_pandas())
+    def to_frame(self, input_item, **kwargs):
+        return input_item.to_pandas()
 
 
-class LocationInputPlugin(BaseInputPlugin):
+class LocationInputPlugin(FrameInputPlugin):
     """File path -> reader by extension (reference location.py:10-34)."""
 
     def is_correct_input(self, input_item, **kwargs):
         return isinstance(input_item, str)
 
-    def to_table(self, input_item: str, file_format: Optional[str] = None,
-                 **kwargs) -> Table:
+    def to_frame(self, input_item: str, file_format: Optional[str] = None,
+                 **kwargs):
         import pandas as pd
 
         if not file_format:
@@ -116,7 +141,7 @@ class LocationInputPlugin(BaseInputPlugin):
             df = pd.read_orc(input_item, **read_kwargs)
         else:
             raise AttributeError(f"Do not understand input format {file_format}")
-        return Table.from_pandas(df)
+        return df
 
 
 class HiveInputPlugin(BaseInputPlugin):

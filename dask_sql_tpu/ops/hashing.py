@@ -193,8 +193,22 @@ def _keys_valid(cols: List[Column], row_valid: Optional[jax.Array]) -> jax.Array
 _HASH_MAX_ROUNDS = 64
 
 
+#: What a hash table takes a slot (the u64 claim and the int32 row id of
+#: ``_hash_table_insert``), and the most a table takes while halving it
+#: keeps its load factor at or under a half.  SF1's largest (1.5 M build
+#: rows, 2^25 slots, 403 MB) lies under it, so no table there changes; a
+#: build side of 15 M rows (TPC-H SF10's orders) asks 2^28 slots at 16x,
+#: 3.2 GB beside 7.8 GB of resident columns, and gets 2^26 (805 MB), which
+#: still direct-addresses its orderkeys: their span is four times the rows.
+_SLOT_BYTES = 12
+_TABLE_BYTES_MAX = 1 << 30
+
+
 def _hash_table_size(n_keys: int) -> int:
-    """Power-of-2 table size at load factor <= 1/16.
+    """Power-of-2 table size at load factor <= 1/16, while that costs at
+    most ``_TABLE_BYTES_MAX``; above it the largest power of two that does,
+    and never a load factor over a half.  Decided from the static row count
+    alone, so a program's table is part of its text.
 
     Generous sizing buys two things off-TPU: fewer claim rounds when
     hashing, and — the big one — direct addressing for sparse integer
@@ -203,7 +217,11 @@ def _hash_table_size(n_keys: int) -> int:
     multi-round hashing.  The cost is one table-sized fill (~2 ms at 32 MB
     on this machine), well under the rounds it saves.
     """
-    return max(16, 1 << int(16 * max(n_keys, 1) - 1).bit_length())
+    n_keys = max(n_keys, 1)
+    size = max(16, 1 << int(16 * n_keys - 1).bit_length())
+    while size * _SLOT_BYTES > _TABLE_BYTES_MAX and size >= 4 * n_keys:
+        size >>= 1
+    return size
 
 
 def _single_int_part(parts):

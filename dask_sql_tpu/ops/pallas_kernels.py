@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import functools
 import os
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -159,9 +160,10 @@ def _exact_pow2(n: jax.Array) -> jax.Array:
     return jnp.where(n >= 0, out, 1.0 / out)
 
 
-def _segmented_sums_limbs(vals: jax.Array, codes: jax.Array,
+def _segmented_sums_limbs(vals: Optional[jax.Array], codes: jax.Array,
                           mask: jax.Array, num_groups: int,
-                          row_classes, interpret: bool) -> jax.Array:
+                          row_classes, interpret: bool, *,
+                          slab_of=None, absmax=None) -> jax.Array:
     """Masked segmented sums of f64 rows as fixed-point MXU contractions.
 
     The f64 scan this replaces (``segmented_sums_xla_blocked``) was the
@@ -186,9 +188,19 @@ def _segmented_sums_limbs(vals: jax.Array, codes: jax.Array,
       truncation error is n * 2**(e-83) <= 2**(e-60) at n = 2**23 rows —
       below one ulp of the row maximum, i.e. tighter than ANY f64
       accumulation order, for data of any magnitude.
+
+    ``vals`` is the (rows, n) matrix, whole.  Where that matrix is too
+    large to exist (``segmented_sums_slabwise``) it is None: ``slab_of(take)``
+    then builds the (rows, SLAB_EXACT) matrix of one slab from ``take``, the
+    slab's slice of any full-length array, and ``absmax`` is each row's
+    runtime maximum (read for float rows only).
     """
-    a, n = vals.shape
     cls = list(row_classes)
+    if vals is None:
+        a, n = len(cls), codes.shape[0]
+        assert n > SLAB_EXACT, n
+    else:
+        a, n = vals.shape
     assert len(cls) == a, (len(cls), a)
     if n == 0:
         return jnp.zeros((a, num_groups), jnp.float64)
@@ -209,9 +221,10 @@ def _segmented_sums_limbs(vals: jax.Array, codes: jax.Array,
     # (it would truncate all valid contributions to 0 — silently wrong).
     is_float = np.asarray([c == "float" for c in cls])
     if is_float.any():
-        absmax = jnp.max(
-            jnp.where(mask.astype(bool)[None, :], jnp.abs(vals), 0.0),
-            axis=1)
+        if vals is not None:
+            absmax = jnp.max(
+                jnp.where(mask.astype(bool)[None, :], jnp.abs(vals), 0.0),
+                axis=1)
         e = jnp.floor(jnp.log2(jnp.maximum(absmax, 1e-300))
                       ).astype(jnp.int32) + 2
         k = jnp.where(jnp.asarray(is_float) & (absmax > 0),
@@ -310,8 +323,12 @@ def _segmented_sums_limbs(vals: jax.Array, codes: jax.Array,
             m = (jax.lax.dynamic_slice(mask, (start,), (SLAB_EXACT,))
                  & (start + lane >= s0))
             c = jax.lax.dynamic_slice(codes, (start,), (SLAB_EXACT,))
-            v = jax.lax.dynamic_slice(vals, (jnp.int32(0), start),
-                                      (a, SLAB_EXACT))
+            if vals is None:
+                v = slab_of(lambda x: jax.lax.dynamic_slice(
+                    x, (start,), (SLAB_EXACT,)))
+            else:
+                v = jax.lax.dynamic_slice(vals, (jnp.int32(0), start),
+                                          (a, SLAB_EXACT))
             return acc + slab_partials(v, c, m), None
 
         out, _ = jax.lax.scan(
@@ -498,6 +515,18 @@ def segmented_sums_dispatch(vals: jax.Array, codes: jax.Array,
     return segmented_sums(vals, codes, mask, num_groups, interpret=interpret)
 
 
+def _with_nonfinite_rows(vals: jax.Array) -> jax.Array:
+    """(4a, n): the rows with NaN and +-Inf zeroed, then a 0/1 indicator
+    row of each kind for each of them."""
+    isnan = jnp.isnan(vals)
+    ispos = jnp.isposinf(vals)
+    isneg = jnp.isneginf(vals)
+    clean = jnp.where(isnan | ispos | isneg, 0.0, vals)
+    return jnp.concatenate([
+        clean, isnan.astype(vals.dtype), ispos.astype(vals.dtype),
+        isneg.astype(vals.dtype)])
+
+
 def _nonfinite_safe(backend):
     """Wrap a sanitized-sum backend with NaN/Inf indicator reassembly."""
     def wrapped(vals, codes, mask, num_groups):
@@ -505,17 +534,59 @@ def _nonfinite_safe(backend):
             return backend(vals, codes, mask, num_groups)
         from .kernels import ieee_reassemble
         a = vals.shape[0]
-        isnan = jnp.isnan(vals)
-        ispos = jnp.isposinf(vals)
-        isneg = jnp.isneginf(vals)
-        clean = jnp.where(isnan | ispos | isneg, 0.0, vals)
-        stacked = jnp.concatenate([
-            clean, isnan.astype(vals.dtype), ispos.astype(vals.dtype),
-            isneg.astype(vals.dtype)])
-        sums = backend(stacked, codes, mask, num_groups)
+        sums = backend(_with_nonfinite_rows(vals), codes, mask, num_groups)
         return ieee_reassemble(sums[:a], sums[a:2 * a], sums[2 * a:3 * a],
                                sums[3 * a:])
     return wrapped
+
+
+#: The most the limb kernel's input may take as ONE matrix: every value row
+#: with its three non-finite indicator rows, all n rows wide, as the TPU
+#: holds it (32-bit planes).  TPC-H Q1 at SF1 is 68 rows by 6 M, 1.6 GB, and
+#: stays one matrix; at SF10 it would be 16.3 GB beside 7.8 GB of resident
+#: columns, and its rows are built a slab at a time inside the kernel's loop
+#: (``segmented_sums_slabwise``).
+STACK_BYTES_MAX = 1 << 31
+
+
+def stack_fits(n_rows: int, n: int) -> bool:
+    return 4 * n_rows * n * 4 <= STACK_BYTES_MAX
+
+
+def segmented_sums_slabwise(rows_of, full_rows, codes: jax.Array,
+                            mask: jax.Array, num_groups: int,
+                            row_classes) -> jax.Array:
+    """``segmented_sums_dispatch`` of f64 rows too many and too long to
+    stack (``stack_fits``): ``rows_of(take)`` builds the rows of one slab
+    from ``take``, the slab's slice of any full-length array, inside the
+    limb kernel's loop, so nothing as long as the input but the input
+    exists.  ``full_rows`` are the same rows at full length, as
+    expressions: read here by one max-reduction each (the float rows' grid)
+    and never stored.  The same sums, bit for bit, as the stacked path
+    gives: the grid, the limbs and the order of accumulation are its own."""
+    a = len(full_rows)
+    n = codes.shape[0]
+    if not (os.environ.get("DSQL_PALLAS") == "force" or _on_tpu()) \
+            or n <= SLAB_EXACT:
+        return segmented_sums_dispatch(jnp.stack(full_rows), codes, mask,
+                                       num_groups, row_classes=row_classes)
+    interpret = not _backend_is_tpu()
+    if not interpret:
+        from ..runtime import telemetry as _tel
+        _tel.inc("pallas_kernel_traces")
+    from .kernels import ieee_reassemble
+    contributes = mask.astype(bool)
+    absmax = jnp.stack(
+        [jnp.max(jnp.where(contributes & jnp.isfinite(row), jnp.abs(row),
+                           0.0)) if c == "float" else jnp.float64(0.0)
+         for row, c in zip(full_rows, row_classes)]
+        + [jnp.float64(0.0)] * (3 * a))
+    sums = _segmented_sums_limbs(
+        None, codes, mask, num_groups, list(row_classes) + ["unit"] * (3 * a),
+        interpret, absmax=absmax,
+        slab_of=lambda take: _with_nonfinite_rows(jnp.stack(rows_of(take))))
+    return ieee_reassemble(sums[:a], sums[a:2 * a], sums[2 * a:3 * a],
+                           sums[3 * a:])
 
 
 def reference_segmented_sums(vals, codes, mask, num_groups):

@@ -168,6 +168,17 @@ SORT_ROWS_MAX = 1 << 18
 LEXSORT_ROWS_MAX = 1 << 10
 
 
+#: The most rows a scan may hold for its plan's first arrival to be answered
+#: by the eager tier while the program compiles.  ``RelExecutor`` holds
+#: every intermediate of a scan whole (480 MB an f64 column of TPC-H SF10's
+#: lineitem, beside 7.8 GB resident) and compiles a small program for each
+#: size it meets (216 for the four shapes of the ``power`` mix, 1.5 s each
+#: at SF1): above this the first arrival waits for its one program, as a
+#: join-heavy plan's does (``_eager_bridge_sorts``).  SF1's lineitem is
+#: 6 M rows, so nothing at SF1 moves.
+EAGER_SCAN_ROWS_MAX = 1 << 24
+
+
 def _sort_formulation(rows: int) -> bool:
     """True when a join whose sorts would see ``rows`` rows is traced in
     its sort formulation: the strategy is the TPU's and the sorts are small
@@ -485,62 +496,81 @@ class _Tracer:
 
         from ..types import exact_decimal_scale
 
-        mxu_rows = [kmask.astype(jnp.float64)]  # row 0: occupancy counts
-        row_classes = ["unit"]  # per-row grid for the limb MXU kernel
-        slots = []
-        for j, agg in enumerate(rel.aggs):
-            f = rel.schema[len(rel.group_keys) + j]
-            col = src.table.columns[agg.args[0]] if agg.args else None
-            fmask = self._agg_filter(agg, src)
-            # exact decimal money math rides the MXU too: integer-valued
-            # f64 matmuls are exact below 2^53 (SF100 cents sums ~6e15)
-            factor = 1.0
-            if col is not None and agg.op in ("SUM", "$SUM0", "AVG"):
-                ds = exact_decimal_scale(col.stype)
-                if ds is not None:
-                    factor = 10.0 ** ds
-            if col is None:
-                vmask = jnp.ones(n, bool) if fmask is None else fmask
-                vrow = vmask.astype(jnp.float64)
-                crow = vrow
-                rc = "unit"
-            elif agg.op == "COUNT":
-                # COUNT(col): only the 0/1 count row is ever read — ship it
-                # in the value slot too; no 2^53 magnitude guard (sums are
-                # never used, so a huge BIGINT column must not fall back)
-                vmask = col.valid_mask() if fmask is None \
-                    else (col.valid_mask() & fmask)
-                vrow = vmask.astype(jnp.float64)
-                crow = vrow
-                rc = "unit"
-            else:
-                vmask = col.valid_mask() if fmask is None \
-                    else (col.valid_mask() & fmask)
-                data = col.data.astype(jnp.float64)
-                if factor != 1.0:
-                    data = jnp.round(data * factor)
-                vrow = jnp.where(vmask, data, 0.0)
-                crow = vmask.astype(jnp.float64)
-                is_int = factor != 1.0 or jnp.issubdtype(col.data.dtype,
-                                                         jnp.integer)
-                if is_int:
-                    # the int grid is bit-exact only below 2^53; decimal
-                    # scales are pre-gated (p<=15) but a raw BIGINT
-                    # column's magnitude is data-dependent (initial= keeps
-                    # the trace alive on 0-row inputs)
-                    self.fallback.append(
-                        jnp.max(jnp.abs(vrow), initial=0.0) >= 2.0 ** 53)
-                rc = "int" if is_int else "float"
-            slots.append((j, agg, f, len(mxu_rows), factor))
-            mxu_rows.append(vrow)
-            row_classes.append(rc)
-            mxu_rows.append(crow)
-            row_classes.append("unit")
+        masks = {}  # an aggregate's full-length mask, made once
 
+        def rows_of(take, flags: bool):
+            """The kernel's value rows, their classes and the aggregates'
+            slots, built from ``take`` of every full-length input: the
+            identity for the rows whole, a slab's slice inside the limb
+            kernel's loop.  ``flags``: append the int rows' magnitude
+            checks, which read whole rows."""
+            km = take(kmask)
+            mxu_rows = [km.astype(jnp.float64)]  # row 0: occupancy counts
+            row_classes = ["unit"]  # per-row grid for the limb MXU kernel
+            slots = []
+            for j, agg in enumerate(rel.aggs):
+                f = rel.schema[len(rel.group_keys) + j]
+                col = src.table.columns[agg.args[0]] if agg.args else None
+                if j not in masks:
+                    fmask = self._agg_filter(agg, src)
+                    if col is None:
+                        masks[j] = jnp.ones(n, bool) if fmask is None \
+                            else fmask
+                    else:
+                        masks[j] = col.valid_mask() if fmask is None \
+                            else (col.valid_mask() & fmask)
+                vmask = take(masks[j])
+                # exact decimal money math rides the MXU too: integer-valued
+                # f64 matmuls are exact below 2^53 (SF100 cents sums ~6e15)
+                factor = 1.0
+                if col is not None and agg.op in ("SUM", "$SUM0", "AVG"):
+                    ds = exact_decimal_scale(col.stype)
+                    if ds is not None:
+                        factor = 10.0 ** ds
+                if col is None or agg.op == "COUNT":
+                    # COUNT(col): only the 0/1 count row is ever read — ship
+                    # it in the value slot too; no 2^53 magnitude guard (sums
+                    # are never used, so a huge BIGINT column must not fall
+                    # back)
+                    vrow = vmask.astype(jnp.float64)
+                    crow = vrow
+                    rc = "unit"
+                else:
+                    data = take(col.data).astype(jnp.float64)
+                    if factor != 1.0:
+                        data = jnp.round(data * factor)
+                    vrow = jnp.where(vmask, data, 0.0)
+                    crow = vmask.astype(jnp.float64)
+                    is_int = factor != 1.0 or jnp.issubdtype(col.data.dtype,
+                                                             jnp.integer)
+                    if is_int and flags:
+                        # the int grid is bit-exact only below 2^53; decimal
+                        # scales are pre-gated (p<=15) but a raw BIGINT
+                        # column's magnitude is data-dependent (initial=
+                        # keeps the trace alive on 0-row inputs)
+                        self.fallback.append(
+                            jnp.max(jnp.abs(vrow), initial=0.0) >= 2.0 ** 53)
+                    rc = "int" if is_int else "float"
+                slots.append((j, agg, f, len(mxu_rows), factor))
+                mxu_rows.append(vrow)
+                row_classes.append(rc)
+                mxu_rows.append(crow)
+                row_classes.append("unit")
+            return mxu_rows, row_classes, slots
+
+        mxu_rows, row_classes, slots = rows_of(lambda whole: whole, True)
         with jax.named_scope("dsql.groupby_limbs"):
-            stack = jnp.stack(mxu_rows)
-            red = pk.segmented_sums_dispatch(stack, codes, kmask, domain,
-                                             row_classes=row_classes)
+            if pk.stack_fits(len(mxu_rows), n):
+                stack = jnp.stack(mxu_rows)
+                red = pk.segmented_sums_dispatch(stack, codes, kmask, domain,
+                                                 row_classes=row_classes)
+            else:
+                # rows too many and too long to exist at once (TPC-H Q1 at
+                # SF10: 17 rows of 60 M, 68 with their indicator rows): the
+                # kernel's loop builds each slab's
+                red = pk.segmented_sums_slabwise(
+                    lambda take: rows_of(take, False)[0], mxu_rows, codes,
+                    kmask, domain, row_classes)
         occupancy = red[0] > 0
 
         from ..types import physical_dtype
@@ -1397,7 +1427,8 @@ def _materialize(entry: _Compiled, outs) -> Table:
 def _eager_bridge_sorts(plan: RelNode, context, on_tpu: bool) -> bool:
     """True where the eager tier is the slower way over a compile: under
     the TPU strategy, a plan with a join both of whose sides scan more than
-    ``SORT_ROWS_MAX`` rows.  The eager join (``ops/join.py``) has the sort
+    ``SORT_ROWS_MAX`` rows, or with a scan of more than
+    ``EAGER_SCAN_ROWS_MAX``.  The eager join (``ops/join.py``) has the sort
     formulation only, an ``argsort`` of the build side and a
     ``searchsorted`` per probe row, each a program of its own that XLA:TPU
     compiles for minutes at these sizes (``SORT_ROWS_MAX`` has the table),
@@ -1422,7 +1453,7 @@ def _eager_bridge_sorts(plan: RelNode, context, on_tpu: bool) -> bool:
             return True
         return any(walk(i) for i in rel.inputs)
 
-    return walk(plan)
+    return rows(plan) > EAGER_SCAN_ROWS_MAX or walk(plan)
 
 
 def tier_probe(plan: RelNode, context) -> str:

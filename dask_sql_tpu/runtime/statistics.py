@@ -138,6 +138,8 @@ def collect_table_stats(table, row_valid=None) -> Optional[TableStats]:
     that resists profiling is simply absent from the stats dict, and any
     failure returns None (the engine then behaves exactly as pre-stats).
     """
+    from ..table import map_columns as _map_columns
+
     t0 = time.perf_counter()
     try:
         rows = int(table.num_rows)
@@ -146,8 +148,13 @@ def collect_table_stats(table, row_valid=None) -> Optional[TableStats]:
             valid_rows = np.asarray(row_valid).reshape(-1)
             rows = int(valid_rows.sum())
         ts = TableStats(rows=rows)
-        for name, col in zip(table.names, table.columns):
-            cs = _collect_column(name, col, rows, valid_rows)
+        host = all(isinstance(c.data, np.ndarray) for c in table.columns)
+        collected = _map_columns(
+            lambda named: _collect_column(*named, rows, valid_rows),
+            list(zip(table.names, table.columns)),
+            # a device table's columns come to the host one at a time
+            rows if host else 0)
+        for name, cs in zip(table.names, collected):
             if cs is not None:
                 ts.cols[name] = cs
         ts.collected_ms = (time.perf_counter() - t0) * 1e3

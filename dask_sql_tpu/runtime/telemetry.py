@@ -495,9 +495,9 @@ class QueryTrace:
     __slots__ = ("query", "root", "lock", "counters0", "report",
                  "started_unix")
 
-    def __init__(self, query: str = ""):
+    def __init__(self, query: str = "", root: Optional[Span] = None):
         self.query = query
-        self.root = Span("query", {"seq": next(_query_seq)})
+        self.root = root or Span("query", {"seq": next(_query_seq)})
         self.lock = threading.Lock()
         self.counters0 = REGISTRY.counters()
         self.report: Optional["QueryReport"] = None
@@ -509,6 +509,7 @@ class _Tls(threading.local):
     span: Optional[Span] = None
     node_recorder = None
     last_report: Optional["QueryReport"] = None
+    last_load: Optional[Span] = None
 
 
 _tls = _Tls()
@@ -609,6 +610,33 @@ def annotation(name: str, **args):
     query's trace has closed); ``args`` (the query's ``seq``, counts) ride
     as the event's arguments."""
     return _TraceAnnotation("dsql:" + name, **args)
+
+
+@contextmanager
+def load_scope(**attrs):
+    """``Context.create_table``'s span tree: a ``load`` span whose children
+    ``load_encode``, ``load_stats`` and ``load_transfer`` open with
+    ``span()`` like a query's, each a ``dsql:<name>`` event on a profiler's
+    trace.  Inside a query (CREATE TABLE AS) it is a child of the query's
+    current span; outside it is a tree of its own, no query and no report,
+    kept for ``last_load()``."""
+    if _tls.trace is not None:
+        with span("load", **attrs) as s:
+            yield s
+        return
+    root = Span("load", attrs)
+    with scoped(QueryTrace(root=root)), _TraceAnnotation("dsql:load"):
+        try:
+            yield root
+        finally:
+            root.t1 = time.monotonic_ns()
+            _tls.last_load = root
+
+
+def last_load() -> Optional[Span]:
+    """The ``load`` span of this thread's last ``create_table`` outside a
+    query, children and attributes filled in."""
+    return _tls.last_load
 
 
 # ---------------------------------------------------------------------------

@@ -292,14 +292,26 @@ class Table:
     # -- constructors ------------------------------------------------------
     @staticmethod
     def from_pandas(df) -> "Table":
-        import pandas as pd
+        return Table.host_from_pandas(df).to_device()
 
-        names, cols = [], []
-        for name in df.columns:
-            s = df[name]
-            names.append(str(name))
-            cols.append(_series_to_column(s))
-        return Table(names, cols)
+    @staticmethod
+    def host_from_pandas(df) -> "Table":
+        """The frame encoded as the device will hold it, still on the host:
+        every column's ``data`` and ``mask`` are numpy arrays.  What
+        ``Context.create_table`` takes its statistics from before
+        ``to_device`` uploads (the values never come back from the chip)."""
+        series = [df[name] for name in df.columns]
+        cols = [Column(data, stype, mask, dictionary)
+                for data, mask, stype, dictionary
+                in map_columns(host_encode_series, series, len(df))]
+        return Table([str(name) for name in df.columns], cols)
+
+    def to_device(self) -> "Table":
+        """A host-encoded table's columns uploaded, one ``jnp.asarray`` a
+        column; an all-valid mask is dropped, as at every ingestion."""
+        return Table(self.names, [
+            Column(jnp.asarray(c.data), c.stype, _as_mask(c.mask),
+                   c.dictionary) for c in self.columns])
 
     @staticmethod
     def from_pydict(data: dict) -> "Table":
@@ -398,6 +410,24 @@ class Table:
         return f"Table[{self.num_rows} rows]({parts})"
 
 
+#: a load works its columns side by side from this many rows on: hashing
+#: strings to codes, casts and reductions over whole columns leave the
+#: interpreter's lock, and below it the threads cost more than they save
+PARALLEL_LOAD_ROWS = 1 << 20
+_LOAD_WORKERS = 8
+
+
+def map_columns(fn, columns, rows: int) -> list:
+    """``[fn(c) for c in columns]``, the columns of a table of
+    ``PARALLEL_LOAD_ROWS`` rows or more taken side by side."""
+    if rows < PARALLEL_LOAD_ROWS or len(columns) < 2:
+        return [fn(c) for c in columns]
+    from concurrent.futures import ThreadPoolExecutor
+
+    with ThreadPoolExecutor(min(_LOAD_WORKERS, len(columns))) as pool:
+        return list(pool.map(fn, columns))
+
+
 _PANDAS_NULLABLE_NUMPY = {
     "Int8": np.int8, "Int16": np.int16, "Int32": np.int32, "Int64": np.int64,
     "UInt8": np.uint8, "UInt16": np.uint16, "UInt32": np.uint32, "UInt64": np.uint64,
@@ -417,11 +447,11 @@ def host_encode_numpy(values: np.ndarray, stype: Optional[SqlType] = None,
     SORTED global dictionary for string columns (shared across batches so
     every batch compiles to the same program)."""
     values = np.asarray(values)
-    if values.dtype.kind == "O" and (stype is None or not stype.is_string):
+    if (values.dtype.kind == "O" and (stype is None or not stype.is_string)
+            and _first_present_is_decimal(values)):
         import decimal as _decimal
 
-        isna = np.array([v is None or (isinstance(v, float)
-                                       and np.isnan(v)) for v in values])
+        isna = np.array([_is_null_object(v) for v in values], dtype=bool)
         present = values[~isna]
         if len(present) and all(isinstance(v, _decimal.Decimal)
                                 and v.is_finite() for v in present):
@@ -477,53 +507,109 @@ def host_encode_numpy(values: np.ndarray, stype: Optional[SqlType] = None,
     return values.astype(dtype, copy=False), mask, stype, None
 
 
-def _decode_bytes_objects(values: np.ndarray) -> np.ndarray:
-    """bytes values become str via utf-8/surrogateescape so binary columns
-    behave as strings end to end (SQL literals are strings; repr-strings
-    like \"b'aa'\" would leak otherwise).  Must be applied identically in
-    the dictionary pass and the encode pass to stay self-consistent."""
-    if any(isinstance(v, (bytes, bytearray)) for v in values):
-        values = np.array(
-            [v.decode("utf-8", "surrogateescape")
-             if isinstance(v, (bytes, bytearray)) else v for v in values],
-            dtype=object)
-    return values
+def _is_null_object(v) -> bool:
+    return v is None or (isinstance(v, float) and v != v)
 
 
-def string_uniques(values: np.ndarray) -> np.ndarray:
-    """Sorted unique strings of an object array (NULLs -> \"\"), the shared
+def _first_present_is_decimal(values: np.ndarray) -> bool:
+    """Whether the first non-NULL value of an object array is a
+    ``decimal.Decimal``: the one look that decides whether the column is
+    worth the per-element scan of the DECIMAL branch (a string column of
+    sixty million rows is not)."""
+    import decimal as _decimal
+
+    for v in values:
+        if not _is_null_object(v):
+            return isinstance(v, _decimal.Decimal)
+    return False
+
+
+def _factorize_strings(values):
+    """``(codes, uniques)`` of a string-ish column by hashing: ``codes`` are
+    positions in ``uniques`` in order of first appearance (-1 for a NULL
+    row), ``uniques`` an object array of ``str``.  ``values`` is an object
+    ndarray or a pandas string array (pandas >= 3 hands out arrow-backed
+    ones, which never become Python objects here).  Returns None where
+    hashing cannot stand in for ``str()`` of every row: a value that is
+    neither ``str`` nor ``bytes`` (1, 1.0 and True hash alike and print
+    differently), or one that cannot be hashed."""
+    import pandas as pd
+
+    try:
+        codes, uniques = pd.factorize(values, use_na_sentinel=True)
+    except TypeError:
+        return None
+    uniques = np.asarray(uniques, dtype=object)
+    kind = pd.api.types.infer_dtype(uniques, skipna=False)
+    if kind not in ("string", "empty"):
+        if not all(isinstance(u, (str, bytes)) for u in uniques):
+            return None
+        uniques = np.array(
+            [u.decode("utf-8", "surrogateescape") if isinstance(u, bytes)
+             else u for u in uniques], dtype=object)
+    return codes, uniques
+
+
+def _strings_by_element(values: np.ndarray):
+    """``(safe, isna)`` of an object array, one Python step per row: bytes
+    decode by utf-8/surrogateescape, NULL and NaN become "", anything
+    else its ``str()``.  Only columns ``_factorize_strings`` declines."""
+    values = np.array(
+        [v.decode("utf-8", "surrogateescape")
+         if isinstance(v, (bytes, bytearray)) else v for v in values],
+        dtype=object)
+    isna = np.array([_is_null_object(v) for v in values], dtype=bool)
+    return np.where(isna, "", values).astype(str), isna
+
+
+def string_uniques(values) -> np.ndarray:
+    """Sorted unique strings of an object array (NULLs -> ""), the shared
     null-semantics for ingestion and the chunked reader's dictionary pass."""
-    values = _decode_bytes_objects(np.asarray(values, dtype=object))
-    isna = np.array([v is None or (isinstance(v, float) and np.isnan(v))
-                     for v in values])
-    safe = np.where(isna, "", values).astype(str)
-    return np.unique(safe).astype(object)
+    return _host_encode_strings(values, None)[3]
 
 
-def _host_encode_strings(values: np.ndarray, mask: Optional[np.ndarray],
+def _host_encode_strings(values, mask: Optional[np.ndarray],
                          dictionary: Optional[np.ndarray] = None):
-    values = _decode_bytes_objects(np.asarray(values, dtype=object))
-    isna = np.array([v is None or (isinstance(v, float) and np.isnan(v)) for v in values])
-    safe = np.where(isna, "", values).astype(str)
+    """Dictionary-encode a string-ish column on the host: int32 codes into
+    a SORTED dictionary (``Column.dict_ranks``, ``dict_sort_order`` and every
+    string comparison rest on the order), NULL and NaN as "" with the mask
+    cleared, ``bytes`` decoded by utf-8/surrogateescape.  The rows are
+    hashed to codes; what is sorted, decoded and searched is the
+    dictionary, which is small."""
+    if isinstance(values, np.ndarray) and values.dtype.kind != "O":
+        values = values.astype(object)
+    hashed = _factorize_strings(values)
+    if hashed is None:
+        safe, isna = _strings_by_element(np.asarray(values, dtype=object))
+        uniques, codes = np.unique(safe, return_inverse=True)
+    else:
+        codes, uniques = hashed
+        isna = codes < 0
+        uniques = uniques.astype(str)
+    has_null = bool(isna.any())
+    if hashed is not None and has_null:
+        codes = np.where(isna, len(uniques), codes)
+        uniques = np.append(uniques, "")
     if dictionary is None:
-        dictionary, codes = np.unique(safe, return_inverse=True)
+        dictionary, renumber = np.unique(uniques, return_inverse=True)
         dictionary = dictionary.astype(object)
     else:
         # shared global dictionary (sorted): encode via binary search.  The
         # two-pass chunked reader guarantees membership; verify anyway — an
         # absent value would silently take a neighbor's code otherwise.
         dict_str = dictionary.astype(str)
-        codes = np.searchsorted(dict_str, safe)
-        clipped = np.clip(codes, 0, len(dict_str) - 1)
-        if not np.array_equal(dict_str[clipped], safe):
-            missing = np.unique(safe[dict_str[clipped] != safe])[:5]
+        renumber = np.clip(np.searchsorted(dict_str, uniques), 0,
+                           max(len(dict_str) - 1, 0))
+        absent = (dict_str[renumber] != uniques if len(dict_str)
+                  else np.ones(len(uniques), dtype=bool))
+        if absent.any():
+            missing = np.unique(uniques[absent])[:5]
             raise ValueError(
                 "string batch contains values absent from the shared "
                 f"dictionary (first few: {missing.tolist()!r}); the "
                 "dictionary pass missed this column's values")
-        codes = clipped
-    codes = codes.astype(np.int32)
-    if isna.any():
+    codes = renumber.astype(np.int32)[codes]
+    if has_null:
         m = ~isna if mask is None else (np.asarray(mask, bool) & ~isna)
     else:
         m = mask
@@ -545,8 +631,7 @@ def host_encode_series(s, dictionary: Optional[np.ndarray] = None):
     if str(dtype) in ("string", "str") or (
         hasattr(pd, "StringDtype") and isinstance(dtype, pd.StringDtype)
     ):
-        vals = s.to_numpy(dtype=object, na_value=None)
-        return host_encode_numpy(vals, dictionary=dictionary)
+        return _host_encode_strings(s.array, None, dictionary)
     if isinstance(dtype, pd.CategoricalDtype):
         if dictionary is not None:
             # a shared global dictionary overrides the per-batch categories:
@@ -566,11 +651,6 @@ def host_encode_series(s, dictionary: Optional[np.ndarray] = None):
             s = s.dt.tz_convert("UTC").dt.tz_localize(None)
         return host_encode_numpy(s.to_numpy(), dictionary=dictionary)
     return host_encode_numpy(s.to_numpy(), dictionary=dictionary)
-
-
-def _series_to_column(s) -> Column:
-    data, mask, stype, dictionary = host_encode_series(s)
-    return Column(jnp.asarray(data), stype, _as_mask(mask), dictionary)
 
 
 def _has_none(v) -> bool:
