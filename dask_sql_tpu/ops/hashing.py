@@ -426,6 +426,90 @@ def _direct_probe(rowtab: jax.Array, direct: _Direct, n: int) -> jax.Array:
     return jnp.where(in_range, rowtab[d], jnp.int32(n))
 
 
+# ---------------------------------------------------------------------------
+# the ordered probe: a build side that is a base table's rows in load order,
+# on a key column that strictly increases, is its own index.  Nothing is
+# inserted: the row of key ``k`` is ``k - lo`` where the keys are dense, and
+# found by a search of the column itself where they are not.  The tracer
+# (``compiled._join_hash_table``) takes it on an ingest statistic's word and
+# the program checks that word over the physical column.
+# ---------------------------------------------------------------------------
+
+def _ordered_check(k: jax.Array, dense: bool, narrow: bool):
+    """(lo, hi, ok) of a build key column the program was told increases
+    strictly (and is ``dense``: ``hi - lo + 1`` rows; or ``narrow``: a span
+    under 2^31): the ends are the first and the last row, no reduction, and
+    ``ok`` is one elementwise pass.  A strictly increasing int64 column of
+    ``n`` rows spans ``n - 1`` to ``2^64 - 1``, so the wrapped difference
+    ``hi - lo`` reads a value in ``[0, 2^63)`` only where the span is it."""
+    lo, hi = k[0], k[-1]
+    ok = (k[1:] > k[:-1]).all()
+    if dense:
+        ok = ok & (hi - lo == k.shape[0] - 1)
+    elif narrow:
+        ok = ok & (hi - lo >= 0) & (hi - lo < 2 ** 31)
+    return lo, hi, ok
+
+
+def _ordered_dense(lo: jax.Array, hi: jax.Array, raw: jax.Array):
+    """(candidate build row, found) per probe key where the build keys are
+    every integer of ``[lo, hi]`` in order: no table and no gather.  ``raw -
+    lo`` may wrap outside the range and is read only inside it."""
+    found = (raw >= lo) & (raw <= hi)
+    return jnp.where(found, raw - lo, 0).astype(jnp.int32), found
+
+
+def _ordered_search(k: jax.Array, lo: jax.Array, hi: jax.Array,
+                    raw: jax.Array, narrow: bool):
+    """(candidate build row, found) per probe key in the strictly increasing
+    ``k``: an interpolation search whose window is the column's own.
+
+    ``guess`` maps a key to a row linearly between the column's ends, in
+    float32 and monotone non-decreasing whatever it rounds (a conversion, a
+    product with a positive scalar, a floor).  One elementwise pass over the
+    build column reads how far the guess of a key lies before and beyond
+    that key's row (``under``, ``over``); a probe key between two build keys
+    is guessed between their guesses, so the greatest row whose key is at
+    most the probe's lies in ``[guess - over - 1, guess + under]``, taken
+    one row wider on both sides.  A binary search of that window is
+    ``bit_length(width)`` rounds of one gather at the probe's rows, and one
+    more for the equality: 3 to 5 where the keys lie evenly (TPC-H's order
+    keys), ``ceil(log2(n))`` + 3 at the worst, which the tracer's rule of
+    rows counts.  ``narrow``: keys and gathers are ``k - lo`` in 32 bits (a
+    64-bit gather costs four to five on the chip)."""
+    n = k.shape[0]
+    found = (raw >= lo) & (raw <= hi)
+    if narrow:
+        keys = (k - lo).astype(jnp.int32)
+        want = jnp.where(found, raw - lo, 0).astype(jnp.int32)
+        fkeys, fwant = keys, want
+    else:
+        keys, want = k, jnp.where(found, raw, lo)
+        # the wrapped difference, read unsigned, is the true one
+        fkeys = (keys - lo).astype(jnp.uint64)
+        fwant = (want - lo).astype(jnp.uint64)
+    fkeys, fwant = fkeys.astype(jnp.float32), fwant.astype(jnp.float32)
+    scale = jnp.float32(n - 1) / jnp.maximum(fkeys[-1], 1.0)
+
+    def guess(x):
+        return jnp.clip(jnp.floor(x * scale), 0, n - 1).astype(jnp.int32)
+
+    off = guess(fkeys) - jnp.arange(n, dtype=jnp.int32)
+    over, under = jnp.max(off), jnp.max(-off)       # off[0] == 0
+    start = jnp.maximum(guess(fwant) - over - 2, 0)
+    width = over + under + 4
+    last = jnp.minimum(start + width - 1, n - 1)
+    rounds = 32 - jax.lax.clz(width - 1)
+
+    def body(i, pos):
+        nxt = pos + (jnp.int32(1) << (rounds - 1 - i))
+        take = (nxt <= last) & (keys[jnp.minimum(nxt, n - 1)] <= want)
+        return jnp.where(take, nxt, pos)
+
+    pos = jax.lax.fori_loop(0, rounds, body, start)
+    return pos, found & (keys[pos] == want)
+
+
 def _group_hashed_codes(key_cols: List[Column],
                         row_valid: Optional[jax.Array], cap: int):
     """Row-order dense group codes without any sort (CPU/GPU strategy).

@@ -109,6 +109,10 @@ def starting_caps(pk, context, count: bool = True) -> Dict[str, int]:
             if count:
                 _tel.inc("stats_cap_hints")
                 _tel.annotate(cap_hint=f"{tag}={cap}")
+    # which joins may probe a build side's key column in place of a table:
+    # a hint one run's check refuted stays learned as 0
+    for tag, level in _stats.ordered_probe_hints(pk.plan, context).items():
+        caps.setdefault(tag, level)
     return caps
 
 
@@ -124,6 +128,24 @@ def split_hint(base_key) -> Optional[int]:
 class _NeedsRecompile(Exception):
     def __init__(self, caps):
         self.caps = caps
+
+
+def _check_ordered(entry, flags) -> None:
+    """Raise _NeedsRecompile where a join probed its build side's key
+    column on a hint (``ord*``, runtime/statistics.py) the column did not
+    keep: after the sites' counts the flags hold one entry a hinted join
+    (``meta["ordered"]``, trace order), set where the program's check of
+    the physical column failed.  Such a run's answer is worth nothing and
+    neither are its other flags, the eager bit among them: the next round
+    clears the hint and builds the table, and the cleared hint is
+    learned."""
+    tags = entry.meta.get("ordered")
+    if not tags:
+        return
+    refuted = flags[2 + len(entry.meta["agg_sites"]):][:len(tags)]
+    if refuted.any():
+        raise _NeedsRecompile({**entry.caps, **{
+            tag: 0 for tag, bad in zip(tags, refuted) if bad}})
 
 
 def _check_flags(entry, flags) -> None:

@@ -62,6 +62,7 @@ from ..ops.hashing import (_U64_MAX, _combined_direct, _combined_int_key,
                            _direct_probe, _group_hashed_codes, _hash_parts,
                            _hash_table_insert, _hash_table_size,
                            _join_key_parts, _keys_valid, _mix64,
+                           _ordered_check, _ordered_dense, _ordered_search,
                            _row_id_table, _slot_at_round, _try_static_codes)
 from ..ops.kernels import (_INT64_MIN, canon_f64, compact_indices,
                            comparable_data, lexsort_by_passes,
@@ -73,7 +74,7 @@ from ..plan.nodes import (
     RexCall, RexInputRef,
 )
 from ..runtime import (faults as _faults, resilience as _res,
-                       telemetry as _tel)
+                       statistics as _stats, telemetry as _tel)
 from ..table import Column, Scalar, Table
 from . import caps as _caps, programs as _programs, tiering as _tiering
 from .caps import _NeedsRecompile, _check_flags, _learned_caps  # noqa: F401
@@ -106,21 +107,29 @@ class _VT:
 
     ``hash_joins`` is set on a stream compacted at a join's output: the
     joins above it keep the hash table though their probe side is small
-    now (``_LogicalJoin`` has the reason)."""
+    now (``_LogicalJoin`` has the reason).
 
-    __slots__ = ("table", "valid", "weight", "hash_joins")
+    ``load_order`` is set while the rows are still a scan's rows in the
+    order they were loaded (a project, a filter that only masks): a join
+    may then probe such a build side's key column itself
+    (``_join_hash_table``).  Whatever moves rows clears it."""
+
+    __slots__ = ("table", "valid", "weight", "hash_joins", "load_order")
 
     def __init__(self, table: Table, valid: Optional[jax.Array],
-                 weight: Optional[int] = None, hash_joins: bool = False):
+                 weight: Optional[int] = None, hash_joins: bool = False,
+                 load_order: bool = False):
         self.table = table
         self.valid = valid
         self.weight = weight if weight is not None else table.num_rows
         self.hash_joins = hash_joins
+        self.load_order = load_order
 
     def carry(self, table: Table, valid: Optional[jax.Array]) -> "_VT":
         """This stream after an operator that hands its rows on: what the
         joins above decide by rides along."""
-        return _VT(table, valid, self.weight, self.hash_joins)
+        return _VT(table, valid, self.weight, self.hash_joins,
+                   self.load_order)
 
     @property
     def n(self) -> int:
@@ -158,6 +167,15 @@ class _VT:
 #: class higher the join takes the hash table and pays on the chip, not
 #: in the set-up.
 SORT_ROWS_MAX = 1 << 18
+
+#: The most 32-bit gathers a build row at which an ordered probe searches a
+#: sparse key column in place of building a table (``_ordered_hint``).  On a
+#: v5e (PERF.md section 6, PR 34) the insert it saves costs 198-211 ns a
+#: build row (15 M rows: 2975 ms alone, 3167 in TPC-H Q12 at SF10) and a
+#: gather at a probe row 7.3 ns where XLA places it well and 22.6 where it
+#: does not (1 M rows out of 15 M int32 keys; a 64-bit one 26-41), so under
+#: 200 / 23 of them the search cannot lose whatever the placement.
+ORDERED_GATHERS_A_BUILD_ROW = 8
 
 #: The most rows an ORDER BY is traced at as ONE multi-key sort
 #: (``jnp.lexsort``) for a TPU.  Up to here it compiles in under a second
@@ -233,6 +251,12 @@ class _Tracer:
         # one device bool a hash-table join, in trace order: whether its
         # table was direct-addressed, so its probe was ``_direct_probe``
         self.direct_probes: List[jax.Array] = []
+        # id(join) -> "ord<j>" (``statistics.join_tags``, set by _build),
+        # and per join that built no table and probed its build side's key
+        # column on a hint: (the hint's tag, the program's check of it,
+        # whether the column is dense, so that the probe is direct)
+        self.join_tags: Dict[int, str] = {}
+        self.ordered: List[Tuple[str, jax.Array, bool]] = []
         # filter nodes (by id) eligible for learned-capacity compaction —
         # computed by _compact_eligible over the whole plan before tracing
         self.compact_ok: set = set()
@@ -282,7 +306,7 @@ class _Tracer:
         want = [f.name for f in rel.schema]
         if t.names != want:
             t = t.limit_to(want)
-        return _VT(t, valid)
+        return _VT(t, valid, load_order=True)
 
     def _LogicalProject(self, rel: LogicalProject) -> _VT:
         src = self.run(rel.input)
@@ -791,10 +815,10 @@ class _Tracer:
             # sort costs 350-750 ms — hash-table join, no sort of either
             # side.  On a TPU above SORT_ROWS_MAX probe rows too: there the
             # sorts are what does not compile
-            match, gathered = self._join_hash_table(jt, probe, build,
-                                                    pparts, bparts,
-                                                    pvalid, ph, bh,
-                                                    exist_test)
+            match, gathered = self._join_hash_table(
+                jt, probe, build, pparts, bparts, pvalid, ph, bh, exist_test,
+                self._ordered_hint(rel, probe_is_left, probe, build, bk_cols,
+                                   bparts, exist_test))
 
         def _out(table: Table, valid) -> _VT:
             return _VT(table, valid, weight=probe.weight,
@@ -1071,9 +1095,75 @@ class _Tracer:
             gathered.append(Column(data, c0.stype, mask, c0.dictionary))
         return match, gathered
 
+    def _ordered_hint(self, rel, probe_is_left: bool, probe: _VT, build: _VT,
+                      bk_cols: List[Column], bparts, exist_test):
+        """(tag, level) where a hash-table join may probe its build side's
+        key column in place of a table, else None.  The build side is a
+        scan's rows in load order, its key one integer column without a
+        mask, and the request's capacities carry a hint (``ord<j>l`` /
+        ``ord<j>r``: ``statistics.ordered_probe_hints``, or a learned one)
+        that the column increases strictly.  A dense column always pays: its
+        probe is arithmetic.  A search pays where its gathers cost less
+        than the inserts it saves, and the static row counts bound them:
+        ``ceil(log2(nb))`` + 3 a probe row at the worst (evenly spread keys
+        take 4 or 5, which only the data says), a 64-bit one counted as
+        four.  TPC-H Q12 at SF10: 1 M compacted lineitem rows x 27 against
+        ``ORDERED_GATHERS_A_BUILD_ROW`` x 15 M; its first-round program,
+        whose probe side is still at the default cap (16.8 M rows), keeps
+        the table."""
+        key = bk_cols[0]
+        if (exist_test is not None or not build.load_order
+                or len(bparts) != 1 or key.mask is not None
+                or key.stype.is_string
+                or not jnp.issubdtype(bparts[0][1].dtype, jnp.integer)):
+            return None
+        tag = self.join_tags.get(id(rel))
+        if tag is None:
+            return None
+        tag += "r" if probe_is_left else "l"
+        level = self.caps.get(tag, 0)
+        if not level:
+            return None
+        if level != _stats.ORDERED_DENSE:
+            gathers = probe.n * ((build.n - 1).bit_length() + 3) \
+                * (4 if level == _stats.ORDERED_WIDE else 1)
+            if gathers >= ORDERED_GATHERS_A_BUILD_ROW * build.n:
+                return None
+        return tag, level
+
+    def _join_ordered(self, jt, probe: _VT, build: _VT, praw: jax.Array,
+                      braw: jax.Array, pvalid: jax.Array, tag: str,
+                      level: int):
+        """The ordered probe (kernels in ops/hashing.py): the build side's
+        key column is strictly increasing in row order, so the row of a
+        key is found in the column itself and nothing is built.  A strictly
+        increasing key is unique, so the table's ``dup`` / ``unresolved`` /
+        ``raw_mismatch`` flags have nothing to say; what there is to check
+        is the hint, one elementwise pass under ``dsql.join_build`` into
+        the flags (``caps._check_ordered``: a refuted hint recompiles with
+        the table, and never answers)."""
+        dense = level == _stats.ORDERED_DENSE
+        narrow = level == _stats.ORDERED_NARROW
+        k = braw.astype(jnp.int64)
+        raw = praw.astype(jnp.int64)
+        with jax.named_scope("dsql.join_build"):
+            lo, hi, ok = _ordered_check(k, dense, narrow)
+        self.ordered.append((tag, ok, dense))
+        with jax.named_scope("dsql.join_probe"):
+            if dense:
+                cand, found = _ordered_dense(lo, hi, raw)
+            else:
+                cand, found = _ordered_search(k, lo, hi, raw, narrow)
+            match = found & pvalid
+            if build.valid is not None:
+                match = match & build.valid[cand]
+        if jt in ("SEMI", "ANTI"):
+            return match, None
+        return match, [c.take(cand) for c in build.table.columns]
+
     def _join_hash_table(self, jt, probe: _VT, build: _VT, pparts, bparts,
                          pvalid: jax.Array, ph: jax.Array, bh: jax.Array,
-                         exist_test=None):
+                         exist_test=None, ordered=None):
         """Open-addressing hash join, the CPU/GPU strategy: insert build
         row ids into a power-of-2 table (empty-slot claim rounds, see
         _hash_table_insert), probe with one gather chain per round actually
@@ -1083,7 +1173,12 @@ class _Tracer:
         only add collisions — caught by the flags and rerun eager.  SEMI/
         ANTI residual exist-tests aggregate (count, min, max) per slot with
         cheap scatters, which the sorted-gather strategy could not express.
+        ``ordered`` (``_ordered_hint``): the build side's key column is its
+        own index, and the join inserts nothing (``_join_ordered``).
         """
+        if ordered is not None:
+            return self._join_ordered(jt, probe, build, pparts[0][1],
+                                      bparts[0][1], pvalid, *ordered)
         nb, npr = build.n, probe.n
         size = _hash_table_size(nb)
         bvalid = bh != _U64_MAX          # _hash_parts marks invalid keys
@@ -1269,6 +1364,7 @@ def _build(plan: RelNode, context, scans, caps: Dict[str, int], key,
             # TPU only: off-TPU the hash kernels already cost O(valid rows)
             # and gathers/scatters are ~1 ms — compaction buys nothing there
             tr.compact_ok = _compact_eligible(plan)
+        tr.join_tags = _stats.join_tags(plan)
         out = tr.run(plan)
         n = out.n
         if out.valid is None:
@@ -1278,11 +1374,13 @@ def _build(plan: RelNode, context, scans, caps: Dict[str, int], key,
         fb = jnp.zeros((), dtype=bool)
         for f in tr.fallback:
             fb = fb | f
-        # the tail is read by count from the END (``_materialize``): what
-        # ``_check_flags`` reads keeps its positions
+        # after the sites, one entry an ordered probe: its hint refuted
+        # (``_check_ordered``).  The tail is read by count from the END
+        # (``_materialize``): what ``_check_flags`` reads keeps its positions
         flags = jnp.stack([fb.astype(jnp.int64), count]
-                          + [g.astype(jnp.int64)
-                             for g in tr.ngroups + tr.direct_probes])
+                          + [g.astype(jnp.int64) for g in
+                             tr.ngroups + [~ok for _, ok, _ in tr.ordered]
+                             + tr.direct_probes])
         meta["names"] = list(out.table.names)
         meta["cols"] = [(c.stype, c.mask is not None, c.dictionary)
                         for c in out.table.columns]
@@ -1291,6 +1389,8 @@ def _build(plan: RelNode, context, scans, caps: Dict[str, int], key,
         meta["agg_sites"] = list(tr.agg_sites)
         meta["join_rows"] = tr.join_rows
         meta["hash_table_joins"] = len(tr.direct_probes)
+        meta["ordered"] = [tag for tag, _, _ in tr.ordered]
+        meta["ordered_dense"] = sum(dense for _, _, dense in tr.ordered)
         meta["n_out"] = n
         outs: List[jax.Array] = [flags]
         for c in out.table.columns:
@@ -1367,16 +1467,27 @@ def _compact_attrs(meta: dict) -> dict:
             "join_rows": meta.get("join_rows", 0)}
 
 
-def _count_direct_probes(hash_table_joins: int, flags) -> None:
-    """``fits`` is the data's: which of a program's hash-table joins probed
-    with ``_direct_probe`` is known when its flags are in (their tail, one
-    bit a join)."""
-    if not hash_table_joins:
+def _count_probes(meta: dict, flags) -> None:
+    """How the joins of the hash-table formulation probed, known when a
+    program's flags are in.  ``hash_table_joins``: all of them, static.
+    ``direct_probes``: those that addressed their build row directly,
+    through a direct-addressed table (``fits`` is the data's: the flags'
+    tail, one bit a table) or in a dense key column.  ``ordered_probes``:
+    those that built no table and probed the build side's key column, dense
+    or searched; a searched one is neither direct nor looped."""
+    tables = meta.get("hash_table_joins", 0)
+    ordered = len(meta.get("ordered", ()))
+    if not tables + ordered:
         return
-    direct = int(flags[len(flags) - hash_table_joins:].sum())
-    _tel.annotate(hash_table_joins=hash_table_joins, direct_probes=direct)
-    _tel.inc("join_probes_direct", direct)
-    _tel.inc("join_probes_looped", hash_table_joins - direct)
+    direct = int(flags[len(flags) - tables:].sum()) if tables else 0
+    dense = meta.get("ordered_dense", 0)
+    _tel.annotate(hash_table_joins=tables + ordered,
+                  direct_probes=direct + dense)
+    _tel.inc("join_probes_direct", direct + dense)
+    _tel.inc("join_probes_looped", tables - direct)
+    if ordered:
+        _tel.annotate(ordered_probes=ordered)
+        _tel.inc("join_probes_ordered", ordered)
 
 
 def _materialize(entry: _Compiled, outs) -> Table:
@@ -1390,11 +1501,12 @@ def _materialize(entry: _Compiled, outs) -> Table:
     # two-phase (flags, then data) costs double
     host = jax.device_get(list(outs)) if small else None
     flags = host[0] if small else np.asarray(outs[0])
+    _caps._check_ordered(entry, flags)
     if flags[0]:
         _tel.inc("fallbacks")
         return None
     _check_flags(entry, flags)
-    _count_direct_probes(meta.get("hash_table_joins", 0), flags)
+    _count_probes(meta, flags)
     count = int(flags[1])
     cut = meta["has_valid"] and count < meta["n_out"]
     sel = np.nonzero(host[-1])[0] if small and cut else None

@@ -106,6 +106,10 @@ class ColumnStats:
     #: int column whose domain (max-min+1) fits dense_domain_cap()
     dense: bool = False
     domain: Optional[int] = None
+    #: integer column without NULLs whose values strictly increase in load
+    #: order: the column is its own index (the compiled tier's ordered
+    #: probe, physical/compiled.py), and ``ndv`` is its exact row count
+    increasing: bool = False
 
     def to_row(self) -> dict:
         return {
@@ -117,6 +121,7 @@ class ColumnStats:
             "is_int": bool(self.is_int),
             "dense": bool(self.dense),
             "domain": -1 if self.domain is None else int(self.domain),
+            "increasing": bool(self.increasing),
         }
 
 
@@ -198,9 +203,12 @@ def _collect_column(name, col, rows: int, valid_rows) -> Optional[ColumnStats]:
         is_int = bool(np.issubdtype(data.dtype, np.integer))
         domain = None
         ndv: Optional[int] = None
+        increasing = bool(is_int and mask is None and _increasing(vals))
+        if increasing:
+            ndv = int(vals.size)  # every value once
         if is_int:
             domain = int(mx) - int(mn) + 1
-            if 0 < domain <= _NDV_PROBE_DOMAIN:
+            if ndv is None and 0 < domain <= _NDV_PROBE_DOMAIN:
                 # exact NDV in O(n + domain): one bincount over the domain
                 counts = np.bincount((vals.astype(np.int64) - int(mn)),
                                      minlength=domain)
@@ -214,12 +222,21 @@ def _collect_column(name, col, rows: int, valid_rows) -> Optional[ColumnStats]:
             mnf = mxf = None  # type: ignore[assignment]
         return ColumnStats(name=name, ndv=ndv, min=mnf, max=mxf,
                            null_frac=null_frac, is_int=is_int, dense=dense,
-                           domain=domain)
+                           domain=domain, increasing=increasing)
     except (KeyboardInterrupt, SystemExit):
         raise
     except Exception:
         logger.debug("column stats failed for %s", name, exc_info=True)
         return None
+
+
+def _increasing(vals: np.ndarray) -> bool:
+    """``vals[1:] > vals[:-1]`` everywhere: one pass beside the min / max
+    pass, after a look at the head, where an unsorted column gives itself
+    away before the whole of it is compared."""
+    head = vals[:4096]
+    return bool((head[1:] > head[:-1]).all()
+                and (vals[1:] > vals[:-1]).all())
 
 
 def _sampled_ndv(vals: np.ndarray) -> int:
@@ -643,6 +660,87 @@ def compiled_cap_hints(plan, context) -> Dict[str, int]:
         return {}
 
 
+#: What an ordered-probe hint says of a join's build key column (the
+#: compiled tier's ``_join_hash_table``): it increases strictly in load
+#: order, and beyond that nothing (the search gathers 64 bits), or its span
+#: is under 2^31 (the search runs in 32), or it holds every integer of its
+#: range (no search).  0 is a hint a program's own check refuted.
+ORDERED_WIDE, ORDERED_NARROW, ORDERED_DENSE = 1, 2, 3
+
+
+def _tagged_joins(plan) -> list:
+    """``("ord<j>", join)`` for the plan's joins, each numbered after those
+    of its left and of its right input.  A scalar subquery's are not among
+    them."""
+    from ..plan import nodes as N
+
+    joins: list = []
+
+    def walk(rel) -> None:
+        for i in rel.inputs:
+            walk(i)
+        if isinstance(rel, N.LogicalJoin):
+            joins.append((f"ord{len(joins)}", rel))
+
+    walk(plan)
+    return joins
+
+
+def join_tags(plan) -> Dict[int, str]:
+    """``id(join) -> "ord<j>"`` for the plan's joins.  The hints' walk and
+    the tracer's read the same numbering, so a scalar subquery (whose joins
+    get no tag and no hint) cannot make the two disagree the way
+    trace-order counters would."""
+    return {id(rel): tag for tag, rel in _tagged_joins(plan)}
+
+
+def _load_order_level(rel, ordinal: int, context) -> int:
+    """The ``ORDERED_*`` level of output ``ordinal`` of ``rel`` where ``rel``
+    hands on a scan's rows in load order (projects and filters over a
+    scan) and the column's ingest statistics say it increases; else 0."""
+    from ..plan import nodes as N
+
+    below = rel
+    while isinstance(below, (N.LogicalProject, N.LogicalFilter)):
+        below = below.input
+    if not isinstance(below, N.LogicalTableScan):
+        return 0
+    cs = column_stats_for(rel, ordinal, context)
+    if cs is None or not cs.increasing or cs.domain is None:
+        return 0
+    if cs.domain == cs.ndv:
+        return ORDERED_DENSE
+    return ORDERED_NARROW if cs.domain <= 1 << 31 else ORDERED_WIDE
+
+
+def ordered_probe_hints(plan, context) -> Dict[str, int]:
+    """Starting hints ``ord<j>l`` / ``ord<j>r`` for the joins on ONE key
+    whose left / right input is a base table in the order of that key: the
+    statistic is data, not layout, so it rides with the capacities into
+    the program's key, and the program checks what it was told
+    (``caps._check_ordered``).  Which side builds, and whether a search
+    pays at the rows the join meets, is the tracer's to say."""
+    if not adaptive_enabled():
+        return {}
+    hints: Dict[str, int] = {}
+    try:
+        for tag, rel in _tagged_joins(plan):
+            pairs = _equi_pairs(rel)
+            if len(pairs) != 1:
+                continue
+            for side, key, input_ in (("l", pairs[0][0], rel.left),
+                                      ("r", pairs[0][1], rel.right)):
+                level = _load_order_level(input_, key, context)
+                if level:
+                    hints[tag + side] = level
+    except (KeyboardInterrupt, SystemExit):
+        raise
+    except Exception:
+        logger.debug("ordered-probe hints failed", exc_info=True)
+        return {}
+    return hints
+
+
 def estimate_plan_bytes_stats(plan, context) -> Optional[int]:
     """Stats-driven working-set estimate for the scheduler: the resident
     scan bytes (they are touched regardless) plus every heavy operator's
@@ -805,7 +903,8 @@ def system_rows(context) -> List[dict]:
                 rows.append({**base, "column": "", "ndv": -1,
                              "min": float("nan"), "max": float("nan"),
                              "null_frac": 0.0, "is_int": False,
-                             "dense": False, "domain": -1})
+                             "dense": False, "domain": -1,
+                             "increasing": False})
             for name in ts.cols:
                 rows.append({**base, **ts.cols[name].to_row()})
     return rows

@@ -239,6 +239,7 @@ def _table_stats(context=None) -> Table:
         "is_int": _col(rows, "is_int", np.bool_, False),
         "dense": _col(rows, "dense", np.bool_, False),
         "domain": _col(rows, "domain", np.int64, -1),
+        "increasing": _col(rows, "increasing", np.bool_, False),
         "collected_ms": _col(rows, "collected_ms", np.float64, 0.0),
     })
 

@@ -222,9 +222,14 @@ STABLE_COUNTERS: Tuple[str, ...] = (
     "server_ingest_requests", "fault_ingest",
     "mv_delta_compactions",
     # hash-table joins of the compiled tier by how their probe ran (PR 30,
-    # physical/compiled.py _count_direct_probes): the data let the table be
+    # physical/compiled.py _count_probes): the data let the table be
     # direct-addressed (one 32-bit gather a probe row), or the probe looped
     "join_probes_direct", "join_probes_looped",
+    # and the joins of that formulation that built no table: the build
+    # side's key column, strictly increasing as loaded, was probed itself
+    # (PR 34, _count_probes): a dense column's probe counts as direct too,
+    # a searched one's as neither direct nor looped
+    "join_probes_ordered",
 )
 
 STABLE_HISTOGRAMS: Tuple[str, ...] = (

@@ -56,6 +56,78 @@ def test_collect_wide_domain_not_dense():
     assert k.domain > stats.dense_domain_cap()
 
 
+@pytest.mark.parametrize("name,values,increasing", [
+    ("arange", np.arange(5000), True),
+    ("arange_times_4", np.arange(5000) * 4, True),
+    ("from_a_negative", np.arange(-70, 70, 7), True),
+    ("int32", np.arange(100, dtype=np.int32), True),
+    ("one_row", np.array([9]), True),
+    ("a_repeat", np.array([1, 2, 2, 3]), False),
+    ("a_repeat_beyond_the_head", np.r_[np.arange(9000), 8999], False),
+    ("a_descent", np.array([1, 3, 2, 4]), False),
+    ("decreasing", np.arange(50)[::-1].copy(), False),
+    ("nullable", pd.array([1, 2, None, 4], "Int64"), False),
+    # loads without a mask
+    ("a_nullable_type_without_a_null", pd.array([1, 2, 3, 4], "Int64"), True),
+    ("float", np.arange(100) * 1.0, False),
+    ("bool", np.array([False, True]), False),
+    ("string", np.array(["a", "b", "c"]), False)])
+def test_increasing_is_a_strictly_rising_integer_column_without_a_mask(
+        name, values, increasing):
+    c = _ctx(t=pd.DataFrame({"k": values}))
+    k = c.schema["root"].tables["t"].stats.col("k")
+    assert k.increasing is increasing
+    assert k.to_row()["increasing"] is increasing
+    if increasing:
+        # every value once: the count is the NDV, exactly
+        assert k.ndv == len(values)
+    system = c.sql("SELECT \"column\", increasing FROM system.table_stats "
+                   "WHERE \"table\" = 't'", return_futures=False)
+    assert list(system["increasing"]) == [increasing]
+
+
+def test_increasing_is_collected_again_when_a_table_is_replaced():
+    c = _ctx(t=pd.DataFrame({"k": np.arange(100) * 3}))
+    assert c.schema["root"].tables["t"].stats.col("k").increasing
+    c.create_table("t", pd.DataFrame({"k": np.arange(100)[::-1] * 3}))
+    assert not c.schema["root"].tables["t"].stats.col("k").increasing
+    c.create_table("t", pd.DataFrame({"k": np.arange(100) + 5}))
+    k = c.schema["root"].tables["t"].stats.col("k")
+    assert k.increasing and k.domain == k.ndv == 100
+
+
+@pytest.mark.parametrize("keys,level", [
+    (np.arange(100) + 5, "ORDERED_DENSE"),
+    (np.arange(100) * 4, "ORDERED_NARROW"),
+    (np.r_[np.arange(99), 2 ** 31 - 1], "ORDERED_NARROW"),
+    (np.r_[np.arange(99), 2 ** 31], "ORDERED_WIDE"),
+    (np.array([np.iinfo(np.int64).min, 0, np.iinfo(np.int64).max]),
+     "ORDERED_WIDE"),
+    (np.arange(100)[::-1].copy(), None),
+    (np.arange(100) // 2, None)])
+def test_ordered_probe_hints_grade_a_join_s_build_key(keys, level):
+    rng = np.random.default_rng(0)
+    c = _ctx(p=pd.DataFrame({"k": rng.integers(0, 100, 300)}),
+             b=pd.DataFrame({"k": keys, "v": np.arange(len(keys)) * 1.0}))
+    plan = _plan(c, "SELECT p.k, b.v FROM p JOIN b ON p.k = b.k")
+    want = {} if level is None else {"ord0r": getattr(stats, level)}
+    assert stats.ordered_probe_hints(plan, c) == want
+    # the side is the plan's, not the size's
+    plan = _plan(c, "SELECT p.k, b.v FROM b JOIN p ON p.k = b.k")
+    want = {} if level is None else {"ord0l": getattr(stats, level)}
+    assert stats.ordered_probe_hints(plan, c) == want
+
+
+def test_ordered_probe_hints_silent_when_off(monkeypatch):
+    c = _ctx(p=pd.DataFrame({"k": [1, 2, 2]}),
+             b=pd.DataFrame({"k": [1, 2, 3]}))
+    plan = _plan(c, "SELECT p.k FROM p JOIN b ON p.k = b.k")
+    assert stats.ordered_probe_hints(plan, c) == {
+        "ord0r": stats.ORDERED_DENSE}
+    monkeypatch.setenv("DSQL_ADAPTIVE", "0")
+    assert stats.ordered_probe_hints(plan, c) == {}
+
+
 def test_dense_domain_cap_env(monkeypatch):
     monkeypatch.setenv("DSQL_DENSE_DOMAIN_CAP", "8")
     assert stats.dense_domain_cap() == 8
