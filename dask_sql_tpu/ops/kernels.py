@@ -215,6 +215,45 @@ def mask_to_indices(mask: jax.Array) -> jax.Array:
     return jnp.nonzero(mask, size=count)[0]
 
 
+#: ``compact_indices``' two formulations, chosen from the static n and
+#: ``cap`` alone (``compact_slab_rows``).  A v5e alone in a program
+#: (PERF.md section 6, PR 37; ms):
+#:
+#:     n           cap         whole-array sort    slab form
+#:     60 002 228  1 048 576   180.3               34.1
+#:     60 002 228  2 097 152   180.4               51.3
+#:      5 999 954     65 536    12.3                3.1
+#:      5 999 954    262 144    12.1                4.4
+#:      5 999 954  2 097 152    12.2               18.3
+#:      1 500 000     65 536     3.3                1.8
+#:      1 500 000    524 288     3.3                5.2
+#:      2 097 152    262 144     3.5                3.3
+#:        524 288    131 072     1.5                2.0
+#:
+#: The sort is a network of log2(n) (log2(n) + 1) / 2 passes over all n
+#: positions (351 at 60 M), the same at every ``cap``.  The slab form is
+#: the slabs' sorts (36 passes at 256 rows, none across HBM: 12.2 ms at
+#: 60 M), two scatters of n / rows marks (10 ns a mark) and, growing with
+#: ``cap``, two running sums and one gather (8 ns a slot together).  So it
+#: wins where ``cap`` is a small share of many rows: the two cross near
+#: cap 1.1 M at n = 6 M (n / 5) and near 20 M at 60 M (n / 3), and from
+#: 2 M rows down the sort is 3.5 ms or less, of which the slab form saves
+#: 1.5 at best.  Rows a slab 128 / 256 / 512 / 1024 / 2048 at n = 60 M,
+#: cap 1 048 576: 33.7 / 34.1 / 36.0 / 38.7 / 42.4 ms.
+COMPACT_SLAB_ROWS = 256
+COMPACT_SLAB_ROWS_MIN = 1 << 22
+COMPACT_SLAB_CAP_SHARE = 8
+
+
+def compact_slab_rows(n: int, cap: int) -> int:
+    """The rows of a slab where ``compact_indices(mask[n], cap)`` sorts
+    inside slabs, 0 where it sorts all n positions: static, the one place
+    that chooses (readings beside ``COMPACT_SLAB_ROWS``)."""
+    if n >= COMPACT_SLAB_ROWS_MIN and cap * COMPACT_SLAB_CAP_SHARE <= n:
+        return COMPACT_SLAB_ROWS
+    return 0
+
+
 def compact_indices(mask: jax.Array, cap: int) -> Tuple[jax.Array, jax.Array]:
     """(positions of the first ``cap`` set rows of ``mask``, number of set
     rows): element for element ``jnp.nonzero(mask, size=cap,
@@ -224,17 +263,57 @@ def compact_indices(mask: jax.Array, cap: int) -> Tuple[jax.Array, jax.Array]:
     Not ``jnp.nonzero(size=)`` itself: JAX builds that as a ``bincount``
     of the mask's running sum, a scatter-add of all n rows into ``cap``
     bins whatever the selectivity, and a TPU serializes a scatter's
-    updates: 495 ms at n = 6.0 M on a v5e, at every cap.  Here the set
-    rows' positions are sorted to the front by one single-operand sort
-    (no scatter): 11.8 ms on the same
-    chip, the same at every cap.  Positions are int32 while n allows.
+    updates: 495 ms at n = 6.0 M on a v5e, at every cap (PR 26).  Here
+    the set rows' positions are sorted to the front: inside slabs where
+    ``compact_slab_rows`` says so (``_compact_in_slabs``), else by one
+    single-operand sort of all n positions, which is 12.2 ms at n = 6.0 M
+    and 180 ms at 60 M (TPC-H SF10's lineitem), at every cap.  Positions
+    are int32 while n allows.
     """
     n = mask.shape[0]
     if cap > n:
         raise ValueError(f"compact_indices: cap {cap} over {n} rows")
     itype = jnp.int32 if n < 2 ** 31 else jnp.int64
+    rows = compact_slab_rows(n, cap)
+    if rows:
+        return _compact_in_slabs(mask, cap, rows, itype)
     pos = jnp.sort(jnp.where(mask, jnp.arange(n, dtype=itype), n))[:cap]
     return jnp.where(pos < n, pos, 0), mask.sum()
+
+
+def _compact_in_slabs(mask: jax.Array, cap: int, rows: int,
+                      itype) -> Tuple[jax.Array, jax.Array]:
+    """``compact_indices`` by slabs of ``rows`` rows: each slab's set
+    lanes are sorted to its front (a sort along the slab, which stays in
+    vector memory), the slabs' running counts say where a slab's rows
+    start among the output's slots, and a slot finds its slab without a
+    search: every slab leaves a mark at the slot its rows end at, and the
+    marks' running sum over the slots is the number of slabs that ended
+    before.  The only work that grows with ``cap`` is two running sums
+    and the one gather out of the sorted lanes."""
+    n = mask.shape[0]
+    slabs = -(-n // rows)
+    m = jnp.pad(mask, (0, slabs * rows - n)).reshape(slabs, rows)
+    lane = jnp.arange(rows, dtype=jnp.int32)
+    front = jnp.sort(jnp.where(m, lane, rows), axis=-1)
+    counts = m.sum(-1, dtype=itype)
+    ends = jnp.cumsum(counts)
+    end_slot = jnp.minimum(ends, cap)   # slot cap is cut off: overflow
+
+    def over_slots(ended):
+        """Per slot, the sum of ``ended`` over the slabs that end at or
+        before it (a scatter of one update a slab, not a row)."""
+        at_end = jnp.zeros(cap + 1, itype).at[end_slot].add(
+            ended, indices_are_sorted=True)
+        return jnp.cumsum(at_end[:cap])
+
+    slot = jnp.arange(cap, dtype=itype)
+    # past the count every slab has ended: the last one's lanes, cut below
+    slab = jnp.minimum(over_slots(jnp.ones_like(counts)), slabs - 1)
+    rank = jnp.minimum(slot - over_slots(counts), rows - 1)
+    start = slab * rows
+    pos = start + front.reshape(-1)[start + rank]
+    return jnp.where(slot < ends[-1], pos, 0), ends[-1]
 
 
 def _u32_channels(key: jax.Array) -> List[jax.Array]:

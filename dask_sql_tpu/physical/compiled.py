@@ -65,8 +65,9 @@ from ..ops.hashing import (_U64_MAX, _combined_direct, _combined_int_key,
                            _ordered_check, _ordered_dense, _ordered_search,
                            _row_id_table, _slot_at_round, _try_static_codes)
 from ..ops.kernels import (_INT64_MIN, canon_f64, compact_indices,
-                           comparable_data, lexsort_by_passes,
-                           orderable_int64, unify_string_codes)
+                           compact_slab_rows, comparable_data,
+                           lexsort_by_passes, orderable_int64,
+                           unify_string_codes)
 from ..ops.pallas_kernels import _strategy_on_tpu
 from ..plan.nodes import (
     LogicalAggregate, LogicalFilter, LogicalJoin, LogicalProject, LogicalSort,
@@ -337,13 +338,19 @@ class _Tracer:
         masked rows into every join/sort above it — the single biggest
         steady-state tax vs the reference's dynamic partitions.  Compact to
         a power-of-2 capacity learned through the same flags/recompile
-        machinery as group caps: one sort of the set rows' positions
+        machinery as group caps: the set rows' positions
         (``compact_indices``) and a gather per column, where every
-        downstream sort then costs cap instead of n.  On a v5e at
-        n = 6.0 M (TPC-H Q12 / Q14, cap 65 536 / 262 144): 13.1 / 23.9 ms a
-        request under ``dsql.compact``; 495 / 506 ms while the positions
-        came from ``jnp.nonzero(size=cap)``, a scatter-add of all n rows
-        (PERF.md, PR 26).  A learned cap >= n/2 disables the site
+        downstream sort then costs cap instead of n.  On a v5e under
+        ``dsql.compact``, a request (TPC-H Q12 / Q14; PERF.md section 6):
+        at n = 6.0 M (SF1, cap 65 536 / 262 144) 495 / 506 ms while the
+        positions came from ``jnp.nonzero(size=cap)``, a scatter-add of
+        all n rows, 13.1 / 23.9 ms from one sort of all n positions
+        (PR 26), 3.4 / 16.5 ms since the sort runs inside slabs (PR 37);
+        at n = 60 M (SF10, cap 1 048 576 / 2 097 152) 249 / 423 ms with
+        the sort of all positions, 180 ms of each, and 83 / 261 ms with
+        the slabs': what is left is the gathers, a column each.  Which of
+        the two a site takes is ``kernels.compact_slab_rows``' to say,
+        from n and the cap.  A learned cap >= n/2 disables the site
         (unselective filter).
 
         ``after_join``: the site is the output of a join that another join
@@ -1457,13 +1464,18 @@ def _compact_eligible(plan: RelNode) -> set:
 def _compact_attrs(meta: dict) -> dict:
     """Whether a program compacts, and at what capacity: the ``cmp*`` sites
     live in it (a site whose learned cap says the filter is unselective
-    leaves none, and one that only counts compacts nothing) and the largest
-    of their caps; beside them the rows its joins take in, which is the
-    work the sites between two joins remove."""
-    caps = [cap for (n_rows, _, tag), cap in zip(meta["agg_sites"],
-                                                 meta["ngroup_caps"])
-            if tag.startswith("cmp") and cap < n_rows]
-    return {"compact_sites": len(caps), "compact_cap": max(caps, default=0),
+    leaves none, and one that only counts compacts nothing), how many of
+    them find their rows inside slabs and not by a sort of all their input
+    (``kernels.compact_slab_rows``: static, as everything here) and the
+    largest of their caps; beside them the rows its joins take in, which is
+    the work the sites between two joins remove."""
+    sites = [(n_rows, cap) for (n_rows, _, tag), cap in
+             zip(meta["agg_sites"], meta["ngroup_caps"])
+             if tag.startswith("cmp") and cap < n_rows]
+    return {"compact_sites": len(sites),
+            "compact_slab_sites": sum(
+                compact_slab_rows(n_rows, cap) > 0 for n_rows, cap in sites),
+            "compact_cap": max((cap for _, cap in sites), default=0),
             "join_rows": meta.get("join_rows", 0)}
 
 

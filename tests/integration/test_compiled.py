@@ -646,6 +646,16 @@ def test_filter_compaction_learned_caps(monkeypatch):
     attrs = dispatch_of(q.replace("sel < 3", "sel < 2")).attrs
     assert attrs["compact_sites"] >= 1
     assert 1024 <= attrs["compact_cap"] <= n // 8
+    # n is under the rows from which a site sorts inside slabs
+    assert attrs["compact_slab_sites"] == 0
+    from dask_sql_tpu.ops import kernels
+    monkeypatch.setattr(kernels, "COMPACT_SLAB_ROWS_MIN", n)
+    assert cm._compact_attrs(
+        {"agg_sites": [(n, False, "cmp0"), (n, False, "cmpj1"),
+                       (n, False, "agg0")],
+         "ngroup_caps": [n // 8, n // 4, 64]}) == {
+        "compact_sites": 2, "compact_slab_sites": 1,
+        "compact_cap": n // 4, "join_rows": 0}
     # a filter under a global aggregate never compacts
     ctx.sql("SELECT SUM(v) AS s FROM fact WHERE sel < 3", return_futures=False)
     attrs = dispatch_of("SELECT SUM(v) AS s FROM fact WHERE sel < 2").attrs

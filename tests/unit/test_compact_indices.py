@@ -1,14 +1,25 @@
 """``ops.kernels.compact_indices``: the row indices of the compaction below
-a join, built without a scatter.  They have to be ``jnp.nonzero(mask,
-size=cap, fill_value=0)``'s, element for element: every answer above a
-compaction rests on that."""
+a join, built without a scatter or a sort of all n rows.  They have to be
+``jnp.nonzero(mask, size=cap, fill_value=0)``'s, element for element, in
+both formulations (the whole-array sort and the slab form, at every slab
+width): every answer above a compaction rests on that."""
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pandas as pd
 import pytest
 
+from dask_sql_tpu.ops import kernels
 from dask_sql_tpu.ops.kernels import compact_indices
+
+#: slab widths: the one the cells meet, its neighbours (the constant may
+#: move with a later chip's readings), and one small enough that the old
+#: cases span thousands of slabs.  0: ``compact_indices`` itself, whatever
+#: it chooses at the case's n and cap.
+WIDTHS = sorted({0, 8, 128, 512, 1024, kernels.COMPACT_SLAB_ROWS})
 
 
 def _want(mask: np.ndarray, cap: int) -> np.ndarray:
@@ -48,14 +59,86 @@ CASES = {
 }
 
 
-@pytest.mark.parametrize("name", list(CASES))
-def test_indices_are_nonzeros(name):
-    mask, cap = CASES[name]
-    idx, count = jax.jit(compact_indices, static_argnums=1)(
-        jnp.asarray(mask), cap)
+def _edges(b: int) -> dict:
+    """Masks whose set rows sit on the edges of slabs of ``b`` rows."""
+    full, none = np.ones(b, dtype=bool), np.zeros(b, dtype=bool)
+    half = np.arange(b) % 2 == 0                           # b / 2 set
+    return {
+        "n_under_multiple": (_random(5 * b - 1, 0.3, seed=1), 2 * b),
+        "n_at_multiple": (_random(5 * b, 0.3, seed=2), 2 * b),
+        "n_over_multiple": (_random(5 * b + 1, 0.3, seed=3), 2 * b),
+        "empty_slab_between_full": (
+            np.concatenate([full, none, full, none, none, full]), 3 * b + 7),
+        "slab_all_set": (np.concatenate([half, full, half]), 2 * b + 1),
+        "all_in_last_padded_slab": (
+            np.concatenate([none, none, none, [False, True, True, False,
+                                               True]]), b),
+        "count_eq_cap_on_slab_edge": (
+            np.concatenate([half, full, none, half]), 2 * b),
+        "cap_on_slab_edge_more_set": (
+            np.concatenate([half, half, full, half]), b),
+        "overflow_ends_inside_slab": (
+            np.concatenate([half, full, full, half]), b + b // 4 + 3),
+        "one_dense_run": (                                 # a cmpj* site
+            np.concatenate([none, none[:b // 3], full, full, full[:b // 5],
+                            none, none]), 3 * b),
+    }
+
+
+def _compact(mask, cap, rows):
+    """``compact_indices`` (rows 0) or its slab form at ``rows`` a slab."""
+    if rows == 0:
+        fn = functools.partial(compact_indices, cap=cap)
+    else:
+        fn = functools.partial(kernels._compact_in_slabs, cap=cap, rows=rows,
+                               itype=jnp.int32)
+    return jax.jit(fn)(jnp.asarray(mask))
+
+
+def _check(mask, cap, rows):
+    idx, count = _compact(mask, cap, rows)
     assert idx.dtype == jnp.int32 and idx.shape == (cap,)
     assert int(count) == int(mask.sum())
     np.testing.assert_array_equal(np.asarray(idx), _want(mask, cap))
+    return idx
+
+
+@pytest.mark.parametrize("rows", [w for w in WIDTHS if w])
+@pytest.mark.parametrize("name", list(_edges(8)))
+def test_slab_edges(name, rows):
+    _check(*_edges(rows)[name], rows)
+
+
+@pytest.mark.parametrize("rows", [w for w in WIDTHS if w])
+@pytest.mark.parametrize("name", list(CASES))
+def test_slab_form_indices_are_nonzeros(name, rows):
+    _check(*CASES[name], rows)
+
+
+@pytest.mark.parametrize("n,cap,slabbed", [
+    (kernels.COMPACT_SLAB_ROWS_MIN, 1 << 16, True),        # Q12's at SF1
+    (kernels.COMPACT_SLAB_ROWS_MIN + 4097, 1 << 18, True),  # Q14's
+    (kernels.COMPACT_SLAB_ROWS_MIN - 1, 1 << 16, False),   # a join's output
+    (kernels.COMPACT_SLAB_ROWS_MIN + 4097, 1 << 20, False),  # cap n / 4
+])
+def test_the_choice_is_static_and_both_sides_agree(n, cap, slabbed):
+    """``compact_indices`` at sizes on both sides of ``compact_slab_rows``:
+    the formulation is the one the static rule names, and the positions are
+    ``np.nonzero``'s either way."""
+    assert bool(kernels.compact_slab_rows(n, cap)) == slabbed
+    mask = _random(n, cap / n / 2, seed=n % 7)
+    found = {e.primitive.name: e for e in _eqns_under(
+        jax.make_jaxpr(functools.partial(compact_indices, cap=cap))(
+            jnp.asarray(mask)).jaxpr, "")}
+    assert ("scatter-add" in found) == slabbed
+    assert (found["sort"].invars[0].aval.shape == (n,)) != slabbed
+    _check(mask, cap, 0)
+
+
+@pytest.mark.parametrize("name", list(CASES))
+def test_indices_are_nonzeros(name):
+    mask, cap = CASES[name]
+    idx = _check(mask, cap, 0)
     # the parent's own expression, not only numpy's
     np.testing.assert_array_equal(
         np.asarray(idx),
@@ -67,20 +150,44 @@ def test_a_cap_over_the_rows_is_refused():
         compact_indices(jnp.ones(8, dtype=bool), 9)
 
 
-# --- the program: no scatter under dsql.compact -----------------------------
+# --- the program: nothing under dsql.compact takes n rows one by one ---------
 
-def _primitives_under(jaxpr, scope: str, inside: bool = False):
-    """Names of the primitives whose name stack, or that of an equation
-    they are nested in, holds ``scope``."""
+def _eqns_under(jaxpr, scope: str, inside: bool = False):
+    """The equations whose name stack, or that of an equation they are
+    nested in, holds ``scope``."""
     for eqn in jaxpr.eqns:
         here = inside or scope in str(eqn.source_info.name_stack)
         if here:
-            yield eqn.primitive.name
+            yield eqn
         for value in eqn.params.values():
             for sub in value if isinstance(value, (list, tuple)) else [value]:
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    yield from _primitives_under(sub, scope, here)
+                    yield from _eqns_under(sub, scope, here)
+
+
+def _primitives_under(jaxpr, scope: str):
+    return (eqn.primitive.name for eqn in _eqns_under(jaxpr, scope))
+
+
+def _serial_over(eqn, n: int) -> bool:
+    """Whether ``eqn`` is one of the two ops a compaction must not apply to
+    all n rows: a scatter of n updates (a TPU serializes them) or a sort
+    whose sorted runs are n long (log2(n)^2 / 2 passes over HBM).  A scatter
+    of a mark a slab and a sort inside slabs are neither."""
+    name = eqn.primitive.name
+    if name.startswith("scatter"):
+        return math.prod(eqn.invars[2].aval.shape) >= n
+    if name == "sort":
+        return eqn.invars[0].aval.shape[eqn.params["dimension"]] >= n
+    return False
+
+
+def _offenders(fn, n: int):
+    mask = jax.ShapeDtypeStruct((n,), jnp.bool_)
+    return [eqn.primitive.name for eqn in _eqns_under(
+        jax.make_jaxpr(fn)(mask).jaxpr, "dsql.compact")
+        if _serial_over(eqn, n)]
 
 
 def test_walker_sees_the_scatter_of_nonzero():
@@ -95,13 +202,69 @@ def test_walker_sees_the_scatter_of_nonzero():
     assert any(p.startswith("scatter") for p in found)
 
 
-def test_join_over_filter_program_has_no_scatter_under_compact(monkeypatch):
+def test_the_guard_refuses_both_earlier_index_builds():
+    """``jnp.nonzero``'s ``bincount`` (before PR 26) and the sort of all n
+    positions (PR 26 to PR 37) both fail the guard below; the slab form
+    passes it with its scatters of a mark a slab and its sort along slabs."""
+    n, cap = 1 << 17, 1 << 12
+
+    def scoped(build):
+        def fn(mask):
+            with jax.named_scope("dsql.compact"):
+                return build(mask)
+        return fn
+
+    assert _offenders(scoped(
+        lambda m: jnp.nonzero(m, size=cap, fill_value=0)[0]), n) \
+        == ["scatter-add"]
+    assert _offenders(scoped(lambda m: jnp.sort(
+        jnp.where(m, jnp.arange(n, dtype=jnp.int32), n))[:cap]), n) \
+        == ["sort"]
+    slabbed = scoped(lambda m: kernels._compact_in_slabs(
+        m, cap, kernels.COMPACT_SLAB_ROWS, jnp.int32))
+    assert _offenders(slabbed, n) == []
+    found = set(_primitives_under(jax.make_jaxpr(slabbed)(
+        jax.ShapeDtypeStruct((n,), jnp.bool_)).jaxpr, "dsql.compact"))
+    assert {"sort", "scatter-add", "gather"} <= found
+
+
+@pytest.mark.parametrize("cap", [1 << 20, 1 << 21])
+def test_deployment_size_by_shapes_alone(cap):
+    """TPC-H SF10's lineitem (60 002 228 rows, not a multiple of any slab
+    width) at Q12's and Q14's caps, traced and never built: the slab form
+    is chosen, positions are ``int32[cap]``, and no intermediate is wider
+    than the (slabs, rows) int32 view of the mask."""
+    n = 60_002_228
+    rows = kernels.compact_slab_rows(n, cap)
+    assert rows and n % rows
+    mask = jax.ShapeDtypeStruct((n,), jnp.bool_)
+    fn = functools.partial(compact_indices, cap=cap)
+    idx, count = jax.eval_shape(fn, mask)
+    assert (idx.shape, idx.dtype) == ((cap,), jnp.int32)
+    assert count.shape == ()
+    widest = -(-n // rows) * rows * 4
+    eqns = list(_eqns_under(jax.make_jaxpr(fn)(mask).jaxpr, ""))
+    assert max(math.prod(v.aval.shape) * v.aval.dtype.itemsize
+               for eqn in eqns for v in eqn.outvars) == widest
+    assert not [eqn for eqn in eqns if _serial_over(eqn, n)]
+
+
+@pytest.mark.parametrize("slabs", [True, False])
+def test_join_over_filter_program_has_no_n_row_scatter_or_sort_under_compact(
+        monkeypatch, slabs):
     """A Q12-like program (a filtered fact table below a join below a
     grouped aggregate) traced under the TPU strategy: the compaction is
-    there, and nothing in it is a scatter."""
+    there, and under ``dsql.compact`` no scatter takes n updates and, where
+    the slab form is chosen (here at the sizes of a test: the rule's
+    constants are lowered), no sort runs n rows long either."""
     from dask_sql_tpu import Context
-    from dask_sql_tpu.physical import compiled as cm
+    from dask_sql_tpu.physical import caps, compiled as cm, programs
 
+    programs._cache.clear()      # the other case's programs and caps
+    caps._learned_caps.clear()
+    if slabs:
+        monkeypatch.setattr(kernels, "COMPACT_SLAB_ROWS_MIN", 1 << 16)
+        monkeypatch.setattr(kernels, "COMPACT_SLAB_CAP_SHARE", 2)
     monkeypatch.setenv("DSQL_STRATEGY", "tpu")
     monkeypatch.delenv("DSQL_CAPS_FILE", raising=False)
     jaxprs = []
@@ -130,8 +293,13 @@ def test_join_over_filter_program_has_no_scatter_under_compact(monkeypatch):
     ctx.sql("SELECT prio, COUNT(*) AS c FROM items JOIN orders "
             "ON items.okey = orders.okey WHERE mode < 2 GROUP BY prio",
             return_futures=False)
-    under = [set(_primitives_under(j, "dsql.compact")) for j in jaxprs]
-    assert any("gather" in found for found in under), \
+    under = [list(_eqns_under(j, "dsql.compact")) for j in jaxprs]
+    assert any(eqn.primitive.name == "gather" for eqns in under
+               for eqn in eqns), \
         "no program compacted: the guard guards nothing"
-    for found in under:
-        assert not [p for p in found if p.startswith("scatter")], found
+    for eqns in under:
+        serial = [eqn.primitive.name for eqn in eqns if _serial_over(eqn, n)]
+        assert serial == ([] if slabs or not eqns else ["sort"]), serial
+    # a mark a slab: the slab form's scatters, and only there
+    assert slabs == any(eqn.primitive.name == "scatter-add"
+                        for eqns in under for eqn in eqns)
