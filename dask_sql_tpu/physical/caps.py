@@ -126,8 +126,28 @@ def split_hint(base_key) -> Optional[int]:
 
 
 class _NeedsRecompile(Exception):
-    def __init__(self, caps):
+    """``caps``: what the next round compiles for.  ``reason``: what asked
+    for it, the next ``compile`` span's ``cause`` and the key of
+    ``telemetry.RECOMPILE_COUNTERS``: ``cap_overflow`` (a group cap or a
+    compaction site dropped rows), ``cap_tighten`` (a compaction site far
+    above its count, or one that only counted and goes live),
+    ``hint_refuted`` (an ``ord*`` hint the column did not keep)."""
+
+    def __init__(self, caps, reason):
         self.caps = caps
+        self.reason = reason
+
+
+def changed(entry, new_caps: Dict[str, int]) -> str:
+    """What a ``_NeedsRecompile`` changed, for the next ``compile`` span:
+    only the tags that differ, each as ``agg0:256>4096``, from what the
+    program ran with (a site's own default where nothing was learned)."""
+    had = {**entry.caps,
+           **{tag: cap for (_, _, tag), cap in zip(entry.meta["agg_sites"],
+                                                   entry.meta["ngroup_caps"])}}
+    return ",".join(f"{tag}:{had.get(tag)}>{cap}"
+                    for tag, cap in sorted(new_caps.items())
+                    if had.get(tag) != cap)
 
 
 def _check_ordered(entry, flags) -> None:
@@ -145,7 +165,8 @@ def _check_ordered(entry, flags) -> None:
     refuted = flags[2 + len(entry.meta["agg_sites"]):][:len(tags)]
     if refuted.any():
         raise _NeedsRecompile({**entry.caps, **{
-            tag: 0 for tag, bad in zip(tags, refuted) if bad}})
+            tag: 0 for tag, bad in zip(tags, refuted) if bad}},
+            "hint_refuted")
 
 
 def _check_flags(entry, flags) -> None:
@@ -201,4 +222,6 @@ def _check_flags(entry, flags) -> None:
                 # the steady-state program shrinks by >= 8x
                 recompile = True
     if recompile:
-        raise _NeedsRecompile(new_caps)
+        # an overflow and a tighten in one round is an overflow
+        raise _NeedsRecompile(new_caps,
+                              "cap_tighten" if exact else "cap_overflow")

@@ -1665,7 +1665,10 @@ def _execute_single(plan: RelNode, context, query_fp: str,
             return None
         caps = _caps.starting_caps(pk, context)
     try_store = True  # one persistent-store attempt per call, tops
-    for _ in range(8):  # capacity-escalation bound
+    # why this round's program is obtained, should it not be cached, and
+    # the caps that changed for it (``programs.obtain``'s ``compile`` span)
+    cause, changed = "first" if split_limit is None else "split", ""
+    for round_ in range(8):  # capacity-escalation bound
         _res.check("execute")
         key = (pk.key, tuple(sorted(caps.items())))
         with _tel.span("lookup", params=len(pk.params)):
@@ -1685,13 +1688,15 @@ def _execute_single(plan: RelNode, context, query_fp: str,
                 h2d = sum(int(a.nbytes) for a in bound)
                 flat = flat + bound
             _tel.annotate(args=len(flat), h2d_bytes=h2d)
+        compiled = None  # the ``compile`` span's record, if this round had one
         if entry is None:
             got = _programs.obtain(
                 pk, key, caps, claim, flat,
                 lambda: _build(pk.plan, context, pk.scans, caps, key,
                                origin=query_fp, params=pk.params),
                 query_fp=query_fp, in_stage=in_stage,
-                split_limit=split_limit, try_store=try_store)
+                split_limit=split_limit, try_store=try_store,
+                why={"round": round_, "cause": cause, "caps": changed})
             try_store = False
             if got is _programs.EAGER:
                 return None
@@ -1699,7 +1704,7 @@ def _execute_single(plan: RelNode, context, query_fp: str,
                 # ``plan``, not ``pk.plan``: the ORDER BY a host sort was
                 # to apply goes with it
                 return try_execute_compiled(plan, context, _split_limit=1)
-            entry, outs, caps = got
+            entry, outs, caps, compiled = got
         else:
             _programs.note_hit(entry, key, pk, query_fp, in_stage)
             # asynchronous: the span is the host's cost of launching the
@@ -1708,13 +1713,16 @@ def _execute_single(plan: RelNode, context, query_fp: str,
                            **_compact_attrs(entry.meta)):
                 outs = entry.fn(*flat)
         try:
-            with _tel.span("materialize"):
+            with (_tel.span("materialize") if compiled is None
+                  else _tel.first_run_span(compiled)):
                 result = _res.retry_transient(
                     lambda: _materialize(entry, outs),
                     site="materialize",
                     passthrough=(_NeedsRecompile,))
         except _NeedsRecompile as r:
             _tel.inc("recompiles")
+            _tel.inc(_tel.RECOMPILE_COUNTERS[r.reason])
+            cause, changed = r.reason, _caps.changed(entry, r.caps)
             caps = r.caps
             _caps._learned_caps_put(pk.key, caps)
             continue

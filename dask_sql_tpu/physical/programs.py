@@ -341,16 +341,26 @@ def _capture_cost(entry: _Compiled, query_fp: str, base_key
 
 def obtain(pk, key, caps: Dict[str, int], claim, flat, build, *,
            query_fp: str, in_stage: bool, split_limit: Optional[int],
-           try_store: bool):
-    """What happens once a program: load it from the persistent store, or
-    compile it (``build()`` traces; the first call compiles and runs).
+           try_store: bool, why: dict):
+    """What happens once a program: load it from the persistent store
+    (span ``program_store_load``), or compile it (span ``compile``, with
+    ``why``: the round, its ``cause`` and the caps that changed).
+    ``build()`` makes the jitted closure and traces nothing; the first call
+    traces, lowers, compiles (or reads XLA's persistent cache) and
+    dispatches.  JAX's own clocks give the first three as ``compile``'s
+    children ``compile_trace``, ``compile_lower`` and ``compile_xla``
+    (``telemetry.compile_span``); ``compile``'s self time is the
+    quarantine check, ``build()`` and the dispatch.  The wait for that first
+    run on the device is the caller's ``materialize`` (``first_run``).
 
     ``pk`` is the program's ``identity.ProgramKey``, ``key`` its cache key
     under ``caps``, ``claim`` what ``lookup`` handed this caller, ``flat``
-    the bound arguments.  Returns ``(entry, outs, caps)``: the program, the
-    outputs of its first run, and the capacities it was built for (a stored
-    program's supersede the caller's guess: they were learned by running
-    it).  Or a verdict: ``EAGER`` (quarantined, not traceable, or degraded
+    the bound arguments.  Returns ``(entry, outs, caps, compiled)``: the
+    program, the outputs of its first run, the capacities it was built for
+    (a stored program's supersede the caller's guess: they were learned by
+    running it), and the ``compile`` span's record in
+    ``telemetry.compile_log()`` (None for a stored program, and outside a
+    trace).  Or a verdict: ``EAGER`` (quarantined, not traceable, or degraded
     past the last rung), ``STAGES`` (the caller re-enters with a budget of
     one; the claim is released by then, so it cannot wait on its own
     verdict).  Deadlines, cancellations and, under
@@ -376,10 +386,12 @@ def obtain(pk, key, caps: Dict[str, int], claim, flat, build, *,
                 claim = None
                 with _state_lock:
                     _bounded_put(_cache, got[0].key, got[0], _CACHE_LIMIT)
-                return got
+                return (*got, None)
         qstore = _quar.get_store()
         qkey = _quar.program_key(base_key)
-        with _tel.span("compile"):
+        compiling = _tel.compile_span(
+            program=_program_name(pk.plan, base_key), **why)
+        with compiling:
             verdict = qstore.check(qkey) if qstore.enabled() else None
             if verdict == "quarantined":
                 # cross-process exile: some process crashed or hung on this
@@ -482,7 +494,7 @@ def obtain(pk, key, caps: Dict[str, int], claim, flat, build, *,
         # compile (best-effort; outside the watchdog — serialization cannot
         # wedge XLA)
         _pstore_put(entry, base_key, len(flat), len(outs), cost)
-        return entry, outs, caps
+        return entry, outs, caps, compiling.record
     finally:
         _release(key, claim)
 

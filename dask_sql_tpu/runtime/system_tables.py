@@ -20,6 +20,9 @@ occupy result-cache budget or interact with catalog epochs):
                          is armed, each row stamped with its replica)
 - ``system.slo``         per-class latency objectives + burn rates
 - ``system.replicas``    fleet heartbeat registry (DSQL_FLEET_DIR armed)
+- ``system.compiles``    the programs this process obtained by compiling
+                         (``telemetry.compile_log()``): cause, XLA-cache
+                         verdict, phases, first run
 
 Every table has a FIXED column schema with explicit dtypes so an empty
 engine still binds and executes ``SELECT * FROM system.queries`` — object
@@ -36,7 +39,7 @@ from ..table import Table
 TABLE_NAMES = ("queries", "active", "metrics", "cache", "quarantine",
                "programs", "table_stats", "mesh", "spill", "devices",
                "matviews", "view_candidates", "events", "slo", "prepared",
-               "tenants", "replicas", "autopilot")
+               "tenants", "replicas", "autopilot", "compiles")
 
 
 def _fleet_on() -> bool:
@@ -550,6 +553,32 @@ def _autopilot() -> Table:
     })
 
 
+def _compiles() -> Table:
+    """The last 256 ``compile`` spans of the process, oldest first
+    (``telemetry.compile_log()``; query and background traces alike): why
+    was this restart slow, which program was built twice.  ``xla_ms`` is
+    the read of XLA's persistent cache where ``xla_cache`` is ``hit``;
+    ``first_run_ms`` is -1 until that run's ``materialize`` closed."""
+    from . import telemetry as _tel
+
+    rows = _tel.compile_log()
+    return Table.from_pydict({
+        "t0_ns": _col(rows, "t0_ns", np.int64, 0),
+        "program": _col(rows, "program", object, ""),
+        "cause": _col(rows, "cause", object, ""),
+        "round": _col(rows, "round", np.int64, 0),
+        "caps": _col(rows, "caps", object, ""),
+        "xla_cache": _col(rows, "xla_cache", object, ""),
+        "background": _col(rows, "background", np.bool_, False),
+        "trace_ms": _col(rows, "trace_ms", np.float64, 0.0),
+        "lower_ms": _col(rows, "lower_ms", np.float64, 0.0),
+        "xla_ms": _col(rows, "xla_ms", np.float64, 0.0),
+        "first_run_ms": _col(rows, "first_run_ms", np.float64, -1.0),
+        "wall_ms": _col(rows, "wall_ms", np.float64, 0.0),
+        "error": _col(rows, "error", object, ""),
+    })
+
+
 _BUILDERS: Dict[str, object] = {
     "queries": _queries,
     "active": _active,
@@ -569,6 +598,7 @@ _BUILDERS: Dict[str, object] = {
     "tenants": _tenants,
     "replicas": _replicas,
     "autopilot": _autopilot,
+    "compiles": _compiles,
 }
 
 #: builders that need the resolving context (catalog / mesh live there)

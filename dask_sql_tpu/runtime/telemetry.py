@@ -55,9 +55,11 @@ import logging
 import os
 import threading
 import time
+from collections import deque
 from contextlib import contextmanager
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+import jax
 from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 logger = logging.getLogger(__name__)
@@ -230,6 +232,20 @@ STABLE_COUNTERS: Tuple[str, ...] = (
     # (PR 34, _count_probes): a dense column's probe counts as direct too,
     # a searched one's as neither direct nor looped
     "join_probes_ordered",
+    # the once-a-program path itemized (PR 38; ``compile_span`` /
+    # ``first_run_span``, whole milliseconds as ``load_*_ms`` are, added
+    # where a ``compile`` span or its first run closes, foreground or
+    # background): JAX's own clocks for trace, lowering and XLA by whether
+    # its persistent cache missed (``compile_xla_ms``) or hit
+    # (``compile_cache_load_ms``: the read and the deserialization), the
+    # ``materialize`` of a program's first run, and ``compile`` + first run
+    # of every round whose cause is not ``first`` (the price of the caps'
+    # ladder; overlaps the five before it)
+    "compile_trace_ms", "compile_lower_ms", "compile_xla_ms",
+    "compile_cache_load_ms", "compile_first_run_ms", "compile_recompile_ms",
+    # ``recompiles`` by what asked for the round (``caps._NeedsRecompile``'s
+    # reason); ``recompiles`` stays their sum
+    "recompiles_overflow", "recompiles_tighten", "recompiles_hint",
 )
 
 STABLE_HISTOGRAMS: Tuple[str, ...] = (
@@ -515,6 +531,7 @@ class _Tls(threading.local):
     node_recorder = None
     last_report: Optional["QueryReport"] = None
     last_load: Optional[Span] = None
+    xla_cache_hit = False  # ``_on_jax_duration``: a retrieval was recorded
 
 
 _tls = _Tls()
@@ -546,6 +563,7 @@ class _NoSpan:
     """What ``span()`` hands out outside a trace: enters to None."""
 
     __slots__ = ()
+    record = None  # as ``_CompileSpan``: nothing was logged
 
     def __enter__(self):
         return None
@@ -615,6 +633,188 @@ def annotation(name: str, **args):
     query's trace has closed); ``args`` (the query's ``seq``, counts) ride
     as the event's arguments."""
     return _TraceAnnotation("dsql:" + name, **args)
+
+
+# ---------------------------------------------------------------------------
+# the once-a-program path: ``compile`` itemized (physical/programs.py
+# ``obtain`` opens it, physical/compiled.py ``_execute_single`` its first run)
+# ---------------------------------------------------------------------------
+
+#: JAX's own clocks (``jax.monitoring`` duration events) -> the child of an
+#: open ``compile`` span each becomes
+_COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile_lower",
+    "/jax/core/compile/backend_compile_duration": "compile_xla",
+}
+#: recorded on a hit of XLA's persistent cache only, inside the
+#: ``backend_compile_duration`` that then closes round it
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+#: why a round after a program's first was asked for
+#: (``caps._NeedsRecompile.reason``, a ``compile`` span's ``cause``) -> the
+#: counter beside ``recompiles``, which stays their sum
+RECOMPILE_COUNTERS = {"cap_overflow": "recompiles_overflow",
+                      "cap_tighten": "recompiles_tighten",
+                      "hint_refuted": "recompiles_hint"}
+
+_compile_log: "deque[dict]" = deque(maxlen=256)
+_compile_log_lock = threading.Lock()  # the ring, and the one registration
+_listening = False
+
+
+def _on_jax_duration(event: str, duration_secs: float, **_) -> None:
+    """The engine's one ``jax.monitoring`` listener.  JAX calls it on the
+    thread that compiles; where that thread's innermost open span is a
+    ``compile``, the event becomes a closed child of it (its end is now,
+    its start ``duration_secs`` before, on the spans' clock).  Anywhere
+    else it returns at once: a warm request compiles nothing."""
+    parent = _tls.span
+    if parent is None or parent.name != "compile":
+        return
+    if event == _CACHE_RETRIEVAL:
+        _tls.xla_cache_hit = True
+        return
+    name = _COMPILE_PHASES.get(event)
+    trace = _tls.trace
+    if name is None or trace is None:
+        return
+    child = Span(name)
+    child.t1 = child.t0
+    child.t0 = max(child.t1 - int(duration_secs * 1e9), parent.t0)
+    if name == "compile_xla":
+        # a hit's duration is the read and the deserialization
+        hit, _tls.xla_cache_hit = _tls.xla_cache_hit, False
+        cache_on = (jax.config.jax_enable_compilation_cache
+                    and jax.config.jax_compilation_cache_dir is not None)
+        child.attrs["xla_cache"] = ("hit" if hit else
+                                    "miss" if cache_on else "off")
+    with trace.lock:
+        kids = parent.children
+        # a jit traced inside another's trace reports first and lies inside
+        # it (every ``jnp`` function of a program's body does): the outer
+        # one's duration holds it, so it goes.  Children stand in the order
+        # they ended, so what lies inside the new one is a tail of them.
+        i = len(kids)
+        while i and kids[i - 1].t1 > child.t0:
+            i -= 1
+        kids[i:] = [k for k in kids[i:] if k.name != name] + [child]
+
+
+def _listen() -> None:
+    global _listening
+    with _compile_log_lock:
+        if not _listening:
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_jax_duration)
+            _listening = True
+
+
+class _CompileSpan(_OpenSpan):
+    """The ``compile`` span.  Closing it adds its phases to the counters
+    and its record to the ring (``compile_log()``), foreground or
+    background; ``record`` is that entry, for ``first_run_span``."""
+
+    __slots__ = ("record",)
+
+    def __exit__(self, exc_type, exc, tb):
+        super().__exit__(exc_type, exc, tb)
+        s = self._span
+        ms = dict.fromkeys(("compile_trace_ms", "compile_lower_ms",
+                            "compile_xla_ms", "compile_cache_load_ms"), 0.0)
+        verdicts = set()
+        for c in s.children:
+            if c.name == "compile_xla":
+                verdicts.add(c.attrs["xla_cache"])
+                hit = c.attrs["xla_cache"] == "hit"
+                ms["compile_cache_load_ms" if hit
+                   else "compile_xla_ms"] += c.wall_ms
+            elif c.name + "_ms" in ms:
+                ms[c.name + "_ms"] += c.wall_ms
+        for counter, spent in ms.items():
+            inc(counter, round(spent))
+        wall, attrs = s.wall_ms, s.attrs
+        if attrs["cause"] != "first":
+            inc("compile_recompile_ms", round(wall))
+        self.record = {
+            "t0_ns": s.t0,
+            "program": attrs["program"],
+            "cause": attrs["cause"],
+            "round": attrs["round"],
+            "caps": attrs["caps"],
+            # of the program's XLA compiles (its own, and a helper's built
+            # while it traced): one that missed tells
+            "xla_cache": next((v for v in ("miss", "hit", "off")
+                               if v in verdicts), ""),
+            "background": attrs["background"],
+            "trace_ms": round(ms["compile_trace_ms"], 3),
+            "lower_ms": round(ms["compile_lower_ms"], 3),
+            "xla_ms": round(ms["compile_xla_ms"]
+                            + ms["compile_cache_load_ms"], 3),
+            "first_run_ms": None,
+            "wall_ms": round(wall, 3),
+            "error": attrs.get("error", ""),
+        }
+        with _compile_log_lock:
+            _compile_log.append(self.record)
+        return False
+
+
+def compile_span(program: str, round: int, cause: str, caps: str):
+    """``programs.obtain``'s ``compile`` span: which ``program``, in which
+    ``round`` of ``_execute_single``'s loop, its ``cause`` (``first``,
+    ``split``, or a ``caps._NeedsRecompile``'s reason) and the ``caps``
+    that changed for it; ``background`` is read off the trace.  ``span``'s
+    rules, and JAX's trace, lowering and XLA compile (or the read of its
+    persistent cache) inside it become its children ``compile_trace``,
+    ``compile_lower`` and ``compile_xla`` (``xla_cache=hit|miss|off``).
+    Its self time is ``build()``, the quarantine check and the first
+    dispatch."""
+    trace, parent = _tls.trace, _tls.span
+    if trace is None or parent is None:
+        return _NO_SPAN
+    if not _listening:
+        _listen()
+    return _CompileSpan(trace, parent, "compile", {
+        "program": program, "round": round, "cause": cause, "caps": caps,
+        "background": trace.root.name == "background_compile"})
+
+
+class _FirstRunSpan(_OpenSpan):
+    __slots__ = ("_record",)
+
+    def __init__(self, trace: QueryTrace, parent: Span, record: dict):
+        super().__init__(trace, parent, "materialize", {"first_run": True})
+        self._record = record
+
+    def __exit__(self, exc_type, exc, tb):
+        super().__exit__(exc_type, exc, tb)
+        ms = self._span.wall_ms
+        self._record["first_run_ms"] = round(ms, 3)
+        inc("compile_first_run_ms", round(ms))
+        if self._record["cause"] != "first":
+            inc("compile_recompile_ms", round(ms))
+        return False
+
+
+def first_run_span(record: dict):
+    """The ``materialize`` span of the round that compiled its program
+    (``record``: that ``compile`` span's): ``first_run=true``, and the wait
+    for the program's first run on the device goes to the counters and the
+    record, whether the run answers or asks for another round.  A record
+    was made inside a trace, on this thread: the trace is still open."""
+    return _FirstRunSpan(_tls.trace, _tls.span, record)
+
+
+def compile_log() -> List[dict]:
+    """The last 256 closed ``compile`` spans of the process, oldest first,
+    from query and background traces alike: ``t0_ns``, ``program``,
+    ``cause``, ``round``, ``caps``, ``xla_cache``, ``background``,
+    ``trace_ms``, ``lower_ms``, ``xla_ms`` (a hit's: the load),
+    ``first_run_ms`` (None until that run's ``materialize`` closed),
+    ``wall_ms``, ``error``.  ``system.compiles`` serves it."""
+    with _compile_log_lock:
+        return [dict(r) for r in _compile_log]
 
 
 @contextmanager
@@ -708,6 +908,8 @@ def _fleet_replica() -> Optional[str]:
 _PHASE_SPANS = frozenset((
     "parse", "plan", "execute", "fetch", "compile", "materialize", "stage",
     "stage_graph", "stream_batch", "queued", "retry_backoff", "drain",
+    # compile's children, from JAX's own clocks (``_on_jax_duration``)
+    "compile_trace", "compile_lower", "compile_xla",
     # the executor's host side, under execute (or a stage)
     "result_cache", "lookup", "bind", "dispatch"))
 # span attribute -> kind of collective, for QueryReport.collective_bytes
@@ -995,6 +1197,7 @@ def _close_trace(trace: QueryTrace, error: Optional[BaseException]) -> None:
     _tls.last_report = report
     REGISTRY.inc("queries")
     REGISTRY.observe("query_wall_ms", report.wall_ms)
+    # ``compile`` is the whole span, its ``compile_*`` children included
     for name in ("parse", "plan", "execute", "compile", "materialize"):
         v = report.phases.get(name)
         if v is not None:
