@@ -311,7 +311,8 @@ def _segmented_sums_limbs(vals: Optional[jax.Array], codes: jax.Array,
         out = slab_partials(vals, codes, mask)
     else:
         # one traced slab body, looped and not unrolled: the limb
-        # arithmetic above is ~50 f64 ops per value row, and a copy of it
+        # arithmetic above is five f64 ops a limb (some 80 a float row,
+        # 60 an int row, 9 a unit row), and a copy of it
         # per slab makes program size, compile time and the compiler's
         # host memory grow with the row count (CHANGES.md, PR 23).  The
         # last slab is anchored at n - SLAB_EXACT so every slice is full
@@ -353,24 +354,114 @@ def _segmented_sums_limbs(vals: Optional[jax.Array], codes: jax.Array,
     return jnp.stack([s + c for s, c in zip(sums, comp)])
 
 
-def segmented_sums_fixedpoint(vals: jax.Array, codes: jax.Array,
+def _row_plan(rows, row_classes):
+    """What the limb kernel sums of the rows it is handed, decided from the
+    rows themselves: ``first``, the places of the distinct ones (a row
+    handed in twice is the same OBJECT twice: SUM(x) and AVG(x) name one
+    value row, every COUNT of a column without NULLs the row mask);
+    ``same_as[i]``, which of them row ``i`` is; ``nonfinite``, whether a
+    distinct row can hold NaN or an infinity and is summed beside three
+    0/1 indicator rows: a float row or a scaled decimal read from floats,
+    never a ``unit`` row or a row of an integer dtype."""
+    first, same_as, seen = [], [], {}
+    for i, (row, c) in enumerate(zip(rows, row_classes)):
+        j = seen.setdefault(id(row), len(first))
+        if j == len(first):
+            first.append(i)
+        assert row_classes[first[j]] == c, (i, c, row_classes[first[j]])
+        same_as.append(j)
+    nonfinite = [row_classes[i] != "unit"
+                 and bool(jnp.issubdtype(rows[i].dtype, jnp.floating))
+                 for i in first]
+    return first, same_as, nonfinite
+
+
+def limb_row_counts(rows, row_classes) -> dict:
+    """The rows the caller named, the distinct rows the limb kernel sums
+    for them and the indicator rows it sums beside those: static."""
+    first, _, nonfinite = _row_plan(rows, row_classes)
+    return {"limb_rows_named": len(rows), "limb_rows_summed": len(first),
+            "limb_indicator_rows": 3 * sum(nonfinite)}
+
+
+def _limb_sums_of_named_rows(rows, codes, mask, num_groups, row_classes,
+                             interpret: bool, rows_of=None,
+                             counts=None) -> jax.Array:
+    """``_segmented_sums_limbs`` of the distinct rows among ``rows``
+    (``_row_plan``), non-finite safe, fanned back out to every place a row
+    was named: values are sanitized, NaN/+Inf/-Inf indicator rows (class
+    'unit': 0/1 by construction) are summed alongside the rows that can
+    hold one, and IEEE semantics reassembled.  With ``rows_of`` the rows
+    are too long to exist beside each other (``segmented_sums_slabwise``):
+    ``rows`` are then read by one max-reduction a float row, and the limb
+    kernel's loop builds each slab's from ``rows_of(take)``.  ``counts``,
+    a caller's dict, is added what was named and what is summed
+    (``limb_row_counts``'s names), for the span of its program."""
+    from .kernels import ieee_reassemble
+    first, same_as, nonfinite = _row_plan(rows, row_classes)
+    d = len(first)
+    flagged = [j for j in range(d) if nonfinite[j]]
+    if counts is not None:
+        for name, count in (("limb_rows_named", len(rows)),
+                            ("limb_rows_summed", d),
+                            ("limb_indicator_rows", 3 * len(flagged))):
+            counts[name] = counts.get(name, 0) + count
+    classes = [row_classes[i] for i in first] + ["unit"] * (3 * len(flagged))
+
+    def finite_matrix(named):
+        """(d + 3 * len(flagged), n) f64: the distinct rows with NaN and
+        +-Inf zeroed, then a 0/1 row of each kind for each row that can
+        hold one."""
+        out = [named[i] for i in first]
+        indicators = []
+        for j in flagged:
+            kinds = (jnp.isnan(out[j]), jnp.isposinf(out[j]),
+                     jnp.isneginf(out[j]))
+            indicators.extend(kinds)
+            out[j] = jnp.where(kinds[0] | kinds[1] | kinds[2], 0.0, out[j])
+        return jnp.stack([row.astype(jnp.float64)
+                          for row in out + indicators])
+
+    if rows_of is None:
+        sums = _segmented_sums_limbs(finite_matrix(rows), codes, mask,
+                                     num_groups, classes, interpret)
+    else:
+        contributes = mask.astype(bool)
+
+        def top(row):
+            row = row.astype(jnp.float64)
+            return jnp.max(jnp.where(contributes & jnp.isfinite(row),
+                                     jnp.abs(row), 0.0))
+
+        zero = jnp.float64(0.0)
+        absmax = jnp.stack(
+            [top(rows[i]) if row_classes[i] == "float" else zero
+             for i in first] + [zero] * (3 * len(flagged)))
+        sums = _segmented_sums_limbs(
+            None, codes, mask, num_groups, classes, interpret, absmax=absmax,
+            slab_of=lambda take: finite_matrix(rows_of(take)))
+    out = [sums[j] for j in range(d)]
+    for t, j in enumerate(flagged):
+        nan_c, pos_c, neg_c = (sums[d + 3 * t + kind] for kind in range(3))
+        out[j] = ieee_reassemble(sums[j], nan_c, pos_c, neg_c)
+    return jnp.stack([out[j] for j in same_as])
+
+
+def segmented_sums_fixedpoint(vals, codes: jax.Array,
                               mask: jax.Array, num_groups: int, *,
                               row_classes=None,
-                              interpret: bool | None = None) -> jax.Array:
-    """Limb-decomposed MXU segmented sums (see _segmented_sums_limbs) with
-    non-finite safety: values are sanitized and NaN/Inf indicator rows
-    (class 'unit' — 0/1 by construction) are summed alongside, then IEEE
-    semantics reassembled."""
+                              interpret: bool | None = None,
+                              counts=None) -> jax.Array:
+    """Limb-decomposed MXU segmented sums (``_segmented_sums_limbs``) of
+    the rows of a matrix, or of a sequence of rows, each in its own dtype,
+    among which a row named twice is summed once
+    (``_limb_sums_of_named_rows``)."""
     if interpret is None:
         interpret = not _backend_is_tpu()
-    a = vals.shape[0]
-    cls = ["float"] * a if row_classes is None else list(row_classes)
-
-    def backend(v, c, m, g):
-        flags = cls + ["unit"] * (v.shape[0] - a)
-        return _segmented_sums_limbs(v, c, m, g, flags, interpret)
-
-    return _nonfinite_safe(backend)(vals, codes, mask, num_groups)
+    rows = list(vals)  # a sequence of rows as it is, a matrix as its rows
+    cls = ["float"] * len(rows) if row_classes is None else list(row_classes)
+    return _limb_sums_of_named_rows(rows, codes, mask, num_groups, cls,
+                                    interpret, counts=counts)
 
 
 def segmented_sums_exact(vals: jax.Array, codes: jax.Array, mask: jax.Array,
@@ -482,36 +573,54 @@ def segmented_sums_xla_blocked(vals: jax.Array, codes: jax.Array,
     return out
 
 
-def segmented_sums_dispatch(vals: jax.Array, codes: jax.Array,
+def _limb_kernel_engaged() -> bool:
+    """Whether the static-domain reduction takes the Pallas kernels: on a
+    TPU, or under DSQL_PALLAS=force (interpreted off-TPU: a test hook)."""
+    return os.environ.get("DSQL_PALLAS") == "force" or _on_tpu()
+
+
+def _count_kernel_trace(interpret: bool) -> None:
+    """Trace-time proof that a program carries the real kernel, not the
+    interpreter or the scatter oracle (chip_smoke.py reads it)."""
+    if not interpret:
+        from ..runtime import telemetry as _tel
+        _tel.inc("pallas_kernel_traces")
+
+
+def segmented_sums_dispatch(vals, codes: jax.Array,
                             mask: jax.Array, num_groups: int,
-                            row_classes=None) -> jax.Array:
-    """Backend policy for the static-domain groupby reduction.
+                            row_classes=None, counts=None) -> jax.Array:
+    """Backend policy for the static-domain groupby reduction.  ``vals``
+    is an (A, n) matrix, or the A rows as a sequence (each in its own
+    dtype: a mask as the bool it is, an integer column as integers).
+    ``counts``: a dict the limb kernel adds its row counts to, where it
+    is the backend (``_limb_sums_of_named_rows``).
 
     - DSQL_PALLAS=force: pallas kernels (interpreted off-TPU) — test hook.
-    - TPU + 32-bit floats: the accumulate-in-place pallas MXU kernel.
-    - TPU + 64-bit: the fixed-point limb kernel (_segmented_sums_limbs) —
+    - TPU + a matrix of 32-bit floats: the accumulate-in-place pallas MXU
+      kernel.
+    - TPU otherwise: the fixed-point limb kernel (_segmented_sums_limbs) —
       bit-exact on unit/int rows, sub-ulp on float rows, and ~40x cheaper
       than the sequential f64 scan it replaced (the scan was the top device
       op in the TPC-H Q1/Q5 profiles, and its 64-bit-emulated matmul loop
-      also dominated query compile time).
+      also dominated query compile time).  A row named twice is summed
+      once, and only a row that can hold a NaN or an infinity is summed
+      beside indicator rows (``_row_plan``).
     - otherwise (CPU/GPU): XLA scatter segment-sum, which is fine there.
     Non-finite safety is applied once for every backend.
     """
-    import os
-
-    forced = os.environ.get("DSQL_PALLAS") == "force"
-    if not (forced or _on_tpu()):
+    named = isinstance(vals, (list, tuple))
+    if not _limb_kernel_engaged():
+        if named:
+            vals = jnp.stack([row.astype(jnp.float64) for row in vals])
         return reference_segmented_sums(vals, codes, mask, num_groups)
     interpret = not _backend_is_tpu()
-    if not interpret:
-        # trace-time proof that a program carries the real kernel, not
-        # the interpreter or the scatter oracle (chip_smoke.py reads it)
-        from ..runtime import telemetry as _tel
-        _tel.inc("pallas_kernel_traces")
-    if forced or vals.dtype != jnp.float32:
+    _count_kernel_trace(interpret)
+    if named or os.environ.get("DSQL_PALLAS") == "force" \
+            or vals.dtype != jnp.float32:
         return segmented_sums_fixedpoint(
             vals, codes, mask, num_groups, row_classes=row_classes,
-            interpret=interpret)
+            interpret=interpret, counts=counts)
     return segmented_sums(vals, codes, mask, num_groups, interpret=interpret)
 
 
@@ -540,53 +649,47 @@ def _nonfinite_safe(backend):
     return wrapped
 
 
-#: The most the limb kernel's input may take as ONE matrix: every value row
-#: with its three non-finite indicator rows, all n rows wide, as the TPU
-#: holds it (32-bit planes).  TPC-H Q1 at SF1 is 68 rows by 6 M, 1.6 GB, and
-#: stays one matrix; at SF10 it would be 16.3 GB beside 7.8 GB of resident
-#: columns, and its rows are built a slab at a time inside the kernel's loop
+#: The most the limb kernel's input may take as ONE matrix: the distinct
+#: rows among those a caller names and the indicator rows of those that can
+#: be non-finite (``_row_plan``), all n wide, in f64.  TPC-H Q1 names 17
+#: rows and stacks 21 (6 distinct, 15 indicator rows): 1.0 GB at SF1 (6 M
+#: rows), which stays one matrix (it was 68 rows and 3.3 GB before rows
+#: were merged); 10.1 GB at SF10 beside 7.8 GB of resident columns, and
+#: 2.8 GB behind SF10's first compaction (16.8 M rows): there the rows are
+#: built a slab at a time inside the kernel's loop
 #: (``segmented_sums_slabwise``).
 STACK_BYTES_MAX = 1 << 31
 
 
-def stack_fits(n_rows: int, n: int) -> bool:
-    return 4 * n_rows * n * 4 <= STACK_BYTES_MAX
+def stack_fits(rows, row_classes, n: int) -> bool:
+    counts = limb_row_counts(rows, row_classes)
+    stacked = counts["limb_rows_summed"] + counts["limb_indicator_rows"]
+    return 8 * stacked * n <= STACK_BYTES_MAX
 
 
 def segmented_sums_slabwise(rows_of, full_rows, codes: jax.Array,
                             mask: jax.Array, num_groups: int,
-                            row_classes) -> jax.Array:
-    """``segmented_sums_dispatch`` of f64 rows too many and too long to
-    stack (``stack_fits``): ``rows_of(take)`` builds the rows of one slab
-    from ``take``, the slab's slice of any full-length array, inside the
-    limb kernel's loop, so nothing as long as the input but the input
-    exists.  ``full_rows`` are the same rows at full length, as
-    expressions: read here by one max-reduction each (the float rows' grid)
+                            row_classes, counts=None) -> jax.Array:
+    """``segmented_sums_dispatch`` of rows too many and too long to exist
+    beside each other (``stack_fits``): ``rows_of(take)`` builds the rows
+    of one slab from ``take``, the slab's slice of any full-length array,
+    inside the limb kernel's loop, so nothing as long as the input but the
+    input exists.  ``full_rows`` are the same rows at full length, as
+    expressions: they say which rows are the same row (``_row_plan``: the
+    slab's rows are taken at the distinct places only), and the float
+    rows among them are read here by one max-reduction each (their grid)
     and never stored.  The same sums, bit for bit, as the stacked path
     gives: the grid, the limbs and the order of accumulation are its own."""
-    a = len(full_rows)
     n = codes.shape[0]
-    if not (os.environ.get("DSQL_PALLAS") == "force" or _on_tpu()) \
-            or n <= SLAB_EXACT:
-        return segmented_sums_dispatch(jnp.stack(full_rows), codes, mask,
-                                       num_groups, row_classes=row_classes)
+    if not _limb_kernel_engaged() or n <= SLAB_EXACT:
+        return segmented_sums_dispatch(list(full_rows), codes, mask,
+                                       num_groups, row_classes=row_classes,
+                                       counts=counts)
     interpret = not _backend_is_tpu()
-    if not interpret:
-        from ..runtime import telemetry as _tel
-        _tel.inc("pallas_kernel_traces")
-    from .kernels import ieee_reassemble
-    contributes = mask.astype(bool)
-    absmax = jnp.stack(
-        [jnp.max(jnp.where(contributes & jnp.isfinite(row), jnp.abs(row),
-                           0.0)) if c == "float" else jnp.float64(0.0)
-         for row, c in zip(full_rows, row_classes)]
-        + [jnp.float64(0.0)] * (3 * a))
-    sums = _segmented_sums_limbs(
-        None, codes, mask, num_groups, list(row_classes) + ["unit"] * (3 * a),
-        interpret, absmax=absmax,
-        slab_of=lambda take: _with_nonfinite_rows(jnp.stack(rows_of(take))))
-    return ieee_reassemble(sums[:a], sums[a:2 * a], sums[2 * a:3 * a],
-                           sums[3 * a:])
+    _count_kernel_trace(interpret)
+    return _limb_sums_of_named_rows(list(full_rows), codes, mask, num_groups,
+                                    list(row_classes), interpret,
+                                    rows_of=rows_of, counts=counts)
 
 
 def reference_segmented_sums(vals, codes, mask, num_groups):

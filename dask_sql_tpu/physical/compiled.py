@@ -249,6 +249,10 @@ class _Tracer:
         self._join_site_counter = 0
         # rows the program's joins take in, probe + build of each: static
         self.join_rows = 0
+        # rows its static-domain aggregates named to the limb kernel, and
+        # the distinct and the indicator rows the kernel sums for them:
+        # static, added by the kernel where it is the backend
+        self.limb_rows: Dict[str, int] = {}
         # one device bool a hash-table join, in trace order: whether its
         # table was direct-addressed, so its probe was ``_direct_probe``
         self.direct_probes: List[jax.Array] = []
@@ -495,9 +499,13 @@ class _Tracer:
         strings / booleans): codes come straight from dictionary ranks — no
         sort, no scatter, no capacity escalation — and all reductions ride
         the MXU one-hot kernel (ops/pallas_kernels.py) on TPU. Key output
-        columns are decoded from the slot index, so the data stream is
-        touched exactly once. Returns None when the shape doesn't fit
-        (non-MXU aggregates, non-enumerable keys, huge domains).
+        columns are decoded from the slot index, never gathered from the
+        data. The kernel is named a value row and a count row an aggregate
+        and sums each distinct one once (``rows_of``); what it reads of
+        the data: a column once where the rows exist whole, and once more
+        for a float row's largest magnitude where they are built a slab
+        at a time. Returns None when the shape doesn't fit (non-MXU
+        aggregates, non-enumerable keys, huge domains).
 
         This is the TPC-H Q1 shape: GROUP BY returnflag, linestatus.
         """
@@ -527,30 +535,47 @@ class _Tracer:
 
         from ..types import exact_decimal_scale
 
-        masks = {}  # an aggregate's full-length mask, made once
+        masks = {}  # full-length masks, one a (FILTER, column's NULLs)
+
+        def mask_of(agg, col):
+            """The rows an aggregate counts, the same object for the same
+            rows: the row mask itself where the column has no NULLs and
+            the aggregate no FILTER."""
+            nulls = None if col is None else col.mask
+            key = (agg.filter_arg, None if nulls is None else id(nulls))
+            if key not in masks:
+                rows = kmask if agg.filter_arg is None \
+                    else self._agg_filter(agg, src)
+                masks[key] = rows if nulls is None else (nulls & rows)
+            return masks[key]
 
         def rows_of(take, flags: bool):
             """The kernel's value rows, their classes and the aggregates'
             slots, built from ``take`` of every full-length input: the
             identity for the rows whole, a slab's slice inside the limb
-            kernel's loop.  ``flags``: append the int rows' magnitude
-            checks, which read whole rows."""
-            km = take(kmask)
-            mxu_rows = [km.astype(jnp.float64)]  # row 0: occupancy counts
+            kernel's loop.  A row is made once and named wherever an
+            aggregate reads it (one value row a (column, factor, mask),
+            one count row a mask), each in the dtype it has: the kernel
+            sums a row once however often it is named, and decides from
+            class and dtype what a row can hold (``pk._row_plan``).
+            ``flags``: append the int rows' magnitude checks, which read
+            whole rows."""
+            taken = {}  # id(full-length input) -> (it, its slice)
+            values = {}  # (id(column data), factor, id(mask)) -> value row
+
+            def part(whole):
+                if id(whole) not in taken:
+                    taken[id(whole)] = (whole, take(whole))
+                return taken[id(whole)][1]
+
+            mxu_rows = [part(kmask)]  # row 0: occupancy counts
             row_classes = ["unit"]  # per-row grid for the limb MXU kernel
             slots = []
             for j, agg in enumerate(rel.aggs):
                 f = rel.schema[len(rel.group_keys) + j]
                 col = src.table.columns[agg.args[0]] if agg.args else None
-                if j not in masks:
-                    fmask = self._agg_filter(agg, src)
-                    if col is None:
-                        masks[j] = jnp.ones(n, bool) if fmask is None \
-                            else fmask
-                    else:
-                        masks[j] = col.valid_mask() if fmask is None \
-                            else (col.valid_mask() & fmask)
-                vmask = take(masks[j])
+                full_mask = mask_of(agg, col)
+                vmask = part(full_mask)
                 # exact decimal money math rides the MXU too: integer-valued
                 # f64 matmuls are exact below 2^53 (SF100 cents sums ~6e15)
                 factor = 1.0
@@ -563,45 +588,56 @@ class _Tracer:
                     # it in the value slot too; no 2^53 magnitude guard (sums
                     # are never used, so a huge BIGINT column must not fall
                     # back)
-                    vrow = vmask.astype(jnp.float64)
-                    crow = vrow
+                    vrow = vmask
                     rc = "unit"
                 else:
-                    data = take(col.data).astype(jnp.float64)
-                    if factor != 1.0:
-                        data = jnp.round(data * factor)
-                    vrow = jnp.where(vmask, data, 0.0)
-                    crow = vmask.astype(jnp.float64)
                     is_int = factor != 1.0 or jnp.issubdtype(col.data.dtype,
                                                              jnp.integer)
-                    if is_int and flags:
-                        # the int grid is bit-exact only below 2^53; decimal
-                        # scales are pre-gated (p<=15) but a raw BIGINT
-                        # column's magnitude is data-dependent (initial=
-                        # keeps the trace alive on 0-row inputs)
-                        self.fallback.append(
-                            jnp.max(jnp.abs(vrow), initial=0.0) >= 2.0 ** 53)
                     rc = "int" if is_int else "float"
+                    key = (id(col.data), factor, id(full_mask))
+                    if key not in values:
+                        data = part(col.data)
+                        if factor != 1.0:
+                            data = jnp.round(
+                                data.astype(jnp.float64) * factor)
+                        elif not is_int:
+                            data = data.astype(jnp.float64)
+                        # an integer column stays one: the kernel widens
+                        # it, and knows by its dtype that it holds no NaN
+                        values[key] = jnp.where(vmask, data,
+                                                jnp.zeros((), data.dtype))
+                        if is_int and flags:
+                            # the int grid is bit-exact only below 2^53;
+                            # decimal scales are pre-gated (p<=15) but a
+                            # raw BIGINT column's magnitude is
+                            # data-dependent (initial= keeps the trace
+                            # alive on 0-row inputs)
+                            self.fallback.append(jnp.max(
+                                jnp.abs(values[key].astype(jnp.float64)),
+                                initial=0.0) >= 2.0 ** 53)
+                    vrow = values[key]
                 slots.append((j, agg, f, len(mxu_rows), factor))
                 mxu_rows.append(vrow)
                 row_classes.append(rc)
-                mxu_rows.append(crow)
+                mxu_rows.append(vmask)
                 row_classes.append("unit")
             return mxu_rows, row_classes, slots
 
         mxu_rows, row_classes, slots = rows_of(lambda whole: whole, True)
         with jax.named_scope("dsql.groupby_limbs"):
-            if pk.stack_fits(len(mxu_rows), n):
-                stack = jnp.stack(mxu_rows)
-                red = pk.segmented_sums_dispatch(stack, codes, kmask, domain,
-                                                 row_classes=row_classes)
+            if pk.stack_fits(mxu_rows, row_classes, n):
+                red = pk.segmented_sums_dispatch(mxu_rows, codes, kmask,
+                                                 domain,
+                                                 row_classes=row_classes,
+                                                 counts=self.limb_rows)
             else:
                 # rows too many and too long to exist at once (TPC-H Q1 at
-                # SF10: 17 rows of 60 M, 68 with their indicator rows): the
-                # kernel's loop builds each slab's
+                # SF10: 17 named rows of 60 M, 5 float rows with their 15
+                # indicator rows among the distinct ones): the kernel's
+                # loop builds each slab's
                 red = pk.segmented_sums_slabwise(
                     lambda take: rows_of(take, False)[0], mxu_rows, codes,
-                    kmask, domain, row_classes)
+                    kmask, domain, row_classes, self.limb_rows)
         occupancy = red[0] > 0
 
         from ..types import physical_dtype
@@ -1395,6 +1431,7 @@ def _build(plan: RelNode, context, scans, caps: Dict[str, int], key,
         meta["ngroup_caps"] = list(tr.ngroup_caps)
         meta["agg_sites"] = list(tr.agg_sites)
         meta["join_rows"] = tr.join_rows
+        meta["limb_rows"] = dict(tr.limb_rows)
         meta["hash_table_joins"] = len(tr.direct_probes)
         meta["ordered"] = [tag for tag, _, _ in tr.ordered]
         meta["ordered_dense"] = sum(dense for _, _, dense in tr.ordered)
@@ -1710,7 +1747,8 @@ def _execute_single(plan: RelNode, context, query_fp: str,
             # asynchronous: the span is the host's cost of launching the
             # program; the wait for the device is inside materialize
             with _tel.span("dispatch", program=entry.name,
-                           **_compact_attrs(entry.meta)):
+                           **_compact_attrs(entry.meta),
+                           **entry.meta.get("limb_rows", {})):
                 outs = entry.fn(*flat)
         try:
             with (_tel.span("materialize") if compiled is None

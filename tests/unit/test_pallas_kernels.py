@@ -273,3 +273,108 @@ def test_fixedpoint_masked_outlier_does_not_coarsen_grid():
     got = np.asarray(pk.segmented_sums_fixedpoint(
         vals, codes, mask, 2, row_classes=["float"], interpret=True))
     np.testing.assert_allclose(got, [[3.0, 3.0]], rtol=1e-12)
+
+
+# ---------------------------------------------------------------------------
+# What reaches the limb kernel through Context.sql (PR 39): a row named by
+# several aggregates is one row, a row mask is handed in once and as the
+# bool it is, and the program's ``dispatch`` span says what was named and
+# what was summed.  The TPU strategy forced on the CPU, kernels interpreted.
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def tpu_strategy(monkeypatch):
+    from dask_sql_tpu.physical import caps, compiled, programs
+
+    monkeypatch.delenv("DSQL_STRATEGY", raising=False)
+    monkeypatch.setattr(pk, "_on_tpu", lambda: True)
+    # the first arrival waits for its program: no eager answer
+    monkeypatch.setattr(compiled, "EAGER_SCAN_ROWS_MAX", 1 << 10)
+    programs._cache.clear()
+    caps._learned_caps.clear()
+
+
+def _dispatch_attrs(context, text):
+    """The ``dispatch`` span of a text whose program is there already (a
+    first arrival runs its program inside ``compile``)."""
+    from dask_sql_tpu.runtime import telemetry
+
+    frame = context.sql(text, return_futures=False)
+    span, = [s for s in telemetry.last_report().root.walk()
+             if s.name == "dispatch"]
+    return frame, span.attrs
+
+
+LIMB_ROWS = ("limb_rows_named", "limb_rows_summed", "limb_indicator_rows")
+
+
+@pytest.mark.parametrize("name, counts", [
+    # 17 named: 5 value rows and the row mask; 3 indicator rows a value row
+    ("q1", (17, 6, 15)),
+    # SUM(CASE ...) twice over a join's output: integer rows, no indicators
+    ("q12", (5, 5, 0))])
+def test_the_dispatch_span_says_what_the_limb_kernel_summed(
+        name, counts, tpu_strategy):
+    import importlib
+
+    from chipbench.data.tpch_gen import generate
+    from dask_sql_tpu import Context
+
+    shape = importlib.import_module(f"chipbench.shapes.{name}")
+    frames = generate(0.01, 2147483659)
+    context = Context()
+    for table in ("lineitem", "orders"):
+        context.create_table(table, frames[table])
+    context.sql(shape.sql(shape.params_at(shape.FIRST)),
+                return_futures=False)
+    params = shape.params_at(3)
+    got, attrs = _dispatch_attrs(context, shape.sql(params))
+    assert tuple(attrs[key] for key in LIMB_ROWS) == counts
+    want = shape.reference(frames, **params)
+    assert list(got.columns) == list(want.columns) and len(got) == len(want)
+    for column in want.columns:
+        if want[column].dtype.kind == "f":
+            np.testing.assert_allclose(got[column], want[column], rtol=1e-12)
+        else:
+            assert list(got[column]) == list(want[column])
+
+
+def test_a_nullable_column_and_a_filter_keep_their_own_rows(tpu_strategy):
+    """COUNT(x) of a column with NULLs is not COUNT(*): its count row is
+    the column's, not the row mask; an aggregate with a FILTER counts its
+    own rows; SUM(x) and AVG(x) still share one value row."""
+    import pandas as pd
+
+    from dask_sql_tpu import Context
+
+    rng = np.random.RandomState(17)
+    n = 3000
+    frame = pd.DataFrame({
+        "k": rng.choice(["a", "b", "c"], n),
+        "x": np.where(rng.rand(n) < 0.25, np.nan, rng.normal(0, 100, n)),
+        "y": rng.uniform(0, 10, n),
+        "z": rng.randint(-5, 6, n)})
+    context = Context()
+    context.create_table("t", frame)
+    text = ("SELECT k, COUNT(x) AS cx, COUNT(*) AS c, SUM(x) AS sx, "
+            "AVG(x) AS ax, SUM(y) FILTER (WHERE z > {z}) AS sy, "
+            "SUM(z) AS sz FROM t GROUP BY k ORDER BY k")
+    context.sql(text.format(z=1), return_futures=False)
+    got, attrs = _dispatch_attrs(context, text.format(z=0))
+    # named: occupancy and (value, count) of six aggregates.  Summed: the
+    # row mask (occupancy, COUNT(*) twice, SUM(z)'s count), x's own mask
+    # (COUNT(x) twice, SUM(x)'s and AVG(x)'s counts), the FILTER's mask,
+    # x (SUM and AVG), y under the FILTER, z.  x and y can hold a NaN.
+    assert tuple(attrs[key] for key in LIMB_ROWS) == (13, 6, 6)
+    by_k = frame.groupby("k")
+    want = pd.DataFrame({
+        "cx": by_k["x"].count(), "c": by_k.size(), "sx": by_k["x"].sum(),
+        "ax": by_k["x"].mean(),
+        "sy": frame[frame["z"] > 0].groupby("k")["y"].sum(),
+        "sz": by_k["z"].sum()}).reset_index()
+    assert list(got["k"]) == list(want["k"])
+    assert (got["cx"] < got["c"]).all()
+    for column in ("cx", "c", "sz"):
+        assert list(got[column]) == list(want[column])
+    for column in ("sx", "ax", "sy"):
+        np.testing.assert_allclose(got[column], want[column], rtol=1e-12)

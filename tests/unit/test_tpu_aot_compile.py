@@ -75,9 +75,8 @@ def test_segmented_sums_f32_sf1_width(one_chip):
 def test_segmented_sums_fixedpoint(one_chip, row_classes, n):
     """The f64 limb kernel per row class at one SLAB_EXACT slab, and at
     SF1 width, where the one traced slab body is looped (a dynamic slice
-    feeding the kernel inside a scan).  The full Q1 shape (six float + two
-    unit rows) takes ~34 s here at either width, so it is compiled by hand
-    (CHANGES.md, PR 23), not in tier-1."""
+    feeding the kernel inside a scan).  Q1's own rows are the case below
+    (``test_segmented_sums_q1_named_rows``)."""
     compiled = _compile(
         lambda v, c, m: pk.segmented_sums_fixedpoint(
             v, c, m, 8, row_classes=list(row_classes), interpret=False),
@@ -91,3 +90,31 @@ def test_exact_pow2_f64(one_chip):
     survives the TPU's X64 rewrite (ldexp/frexp do not)."""
     compiled = _compile(pk._exact_pow2, one_chip, ((4096,), jnp.int32))
     assert "tpu_custom_call" not in compiled.as_text()
+
+
+def test_segmented_sums_q1_named_rows(one_chip):
+    """TPC-H Q1's 17 named rows as the compiled tier hands them over (PR
+    39): five f64 value rows and the row mask, a bool, named ten times.
+    The limb matrix is 5 x 14 limb rows, the mask's own row and 15
+    indicator rows, bools until they are stacked: 86 rows, padded to 88
+    for Mosaic's (8, 128) tiles, at SF1 width, looped.  (The parent's 17
+    + 51 rows made 160 and compiled here for 70-80 s; these take 35.)"""
+    q1_classes = ["unit"] + ["float", "unit"] * 7 + ["unit", "unit"]
+
+    def q1(qty, price, disc_price, charge, disc, codes, keep):
+        rows = [keep]
+        for value in (qty, price, disc_price, charge, qty, price, disc):
+            rows += [value, keep]
+        rows += [keep, keep]
+        assert pk.limb_row_counts(rows, q1_classes) == {
+            "limb_rows_named": 17, "limb_rows_summed": 6,
+            "limb_indicator_rows": 15}
+        return pk.segmented_sums_fixedpoint(
+            rows, codes, keep, 6, row_classes=q1_classes, interpret=False)
+
+    compiled = _compile(
+        q1, one_chip, *[((N_SF1,), jnp.float64)] * 5,
+        ((N_SF1,), jnp.int32), ((N_SF1,), jnp.bool_))
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "f32[88," in text
