@@ -204,23 +204,57 @@ _SLOT_BYTES = 12
 _TABLE_BYTES_MAX = 1 << 30
 
 
-def _hash_table_size(n_keys: int) -> int:
-    """Power-of-2 table size at load factor <= 1/16, while that costs at
-    most ``_TABLE_BYTES_MAX``; above it the largest power of two that does,
-    and never a load factor over a half.  Decided from the static row count
-    alone, so a program's table is part of its text.
+#: The most slots a table sized from its key's span may have for each row
+#: of the larger of the probe side and the table it replaces: what a fill of
+#: the larger table may cost beside the probe it saves.  Priced on a TPU v5e
+#: (PERF.md section 6, PR 42; ``_join_hash_table`` alone, medians of seven):
+#: a slot costs 0.045 ns (131 072 x 2 097 152 rows: 65.3 ms at 2^24 slots,
+#: 67.6 at 2^26), a probe row that loops 46 to 110 ns more than one that is
+#: direct-addressed, so the fill pays up to 1 000 slots a row: 1 024 x
+#: 65 536 rows over a span of 2^26 take 4.50 ms at 2^14 slots and 4.55 at
+#: 2^26, a tie in time for 805 MB of temporaries.  64 is a sixteenth of
+#: that: a table is never taken for the time it ties at.
+_SPAN_SLOTS_A_ROW = 64
 
-    Generous sizing buys two things off-TPU: fewer claim rounds when
-    hashing, and — the big one — direct addressing for sparse integer
-    keys: TPC-H orderkeys span ~16x the row count, so a 16x table lets
-    `key - lo` resolve in ONE round where a 4x table would fall back to
-    multi-round hashing.  The cost is one table-sized fill (~2 ms at 32 MB
-    on this machine), well under the rounds it saves.
+
+def _hash_table_size(n_keys: int, span: int = 0, probe_rows: int = 0) -> int:
+    """How many slots a table of ``n_keys`` build rows gets: a power of two,
+    decided from static row counts and a hint that is part of the program's
+    key, so a program's table is part of its text.
+
+    16 slots a row, while that costs at most ``_TABLE_BYTES_MAX``; above it
+    the largest power of two that does, and never a load factor over a
+    half.  The generous table is what lets sparse integer keys be
+    direct-addressed (``_direct_info``): TPC-H's order keys span four times
+    their rows, a filtered side's more, and where ``key - lo`` fits the
+    table the insert is one scatter round and the probe ONE 32-bit gather
+    and a range test (``_direct_probe``; on a TPU v5e 39.8 ms at 6 M probe
+    rows, 15.0 at 2 M, 10.7-11.1 at 0.5-1.5 M: PERF.md, PR 30), where a
+    table the keys do not fit hashes: several scatter-min rounds to insert
+    and, a probe round, three gathers and a ``_mix64`` in emulated u64 (TPC-H
+    Q10's 2 M lineitem rows under 131 072 filtered orders: four rounds,
+    290-380 ms).
+
+    ``span`` (``statistics.key_span_hints``: the power of two at or above
+    the width of the key's base column at ingest; 0 for none) says when 16
+    slots a row cannot hold the keys whatever rows the join meets.  The
+    span's own table is taken where it is larger than the table above,
+    costs at most ``_TABLE_BYTES_MAX``, and has at most
+    ``_SPAN_SLOTS_A_ROW`` slots for each of ``probe_rows`` or of the slots
+    above, whichever is more: the fill and the row-id pass over its slots
+    (``_hash_table_insert``, ``_row_id_table``) are paid every request.
+    The kernel alone on the chip: TPC-H Q10's 131 072 x 2 097 152 rows over
+    6 M keys take 275.2 ms at 2^21 slots and 47.0 at 2^23; 16 384 x 262 144
+    rows over 2^25 keys 27.2 ms at 2^18 slots and 10.2 at 2^25.
     """
     n_keys = max(n_keys, 1)
     size = max(16, 1 << int(16 * n_keys - 1).bit_length())
     while size * _SLOT_BYTES > _TABLE_BYTES_MAX and size >= 4 * n_keys:
         size >>= 1
+    wide = 1 << max(int(span) - 1, 0).bit_length()
+    if (size < wide <= _SPAN_SLOTS_A_ROW * max(probe_rows, size)
+            and wide * _SLOT_BYTES <= _TABLE_BYTES_MAX):
+        return wide
     return size
 
 

@@ -113,6 +113,10 @@ def starting_caps(pk, context, count: bool = True) -> Dict[str, int]:
     # a hint one run's check refuted stays learned as 0
     for tag, level in _stats.ordered_probe_hints(pk.plan, context).items():
         caps.setdefault(tag, level)
+    # how wide a join key's base column is: what lets the tracer size a
+    # table that can be direct-addressed (``hashing._hash_table_size``)
+    for tag, span in _stats.key_span_hints(pk.plan, context).items():
+        caps.setdefault(tag, span)
     return caps
 
 

@@ -256,6 +256,7 @@ class _Tracer:
         # one device bool a hash-table join, in trace order: whether its
         # table was direct-addressed, so its probe was ``_direct_probe``
         self.direct_probes: List[jax.Array] = []
+        self.span_tables = 0    # those of them a ``span*`` hint sized: static
         # id(join) -> "ord<j>" (``statistics.join_tags``, set by _build),
         # and per join that built no table and probed its build side's key
         # column on a hint: (the hint's tag, the program's check of it,
@@ -861,7 +862,9 @@ class _Tracer:
             match, gathered = self._join_hash_table(
                 jt, probe, build, pparts, bparts, pvalid, ph, bh, exist_test,
                 self._ordered_hint(rel, probe_is_left, probe, build, bk_cols,
-                                   bparts, exist_test))
+                                   bparts, exist_test),
+                self.caps.get(_stats.span_tag(
+                    self._build_tag(rel, probe_is_left)), 0))
 
         def _out(table: Table, valid) -> _VT:
             return _VT(table, valid, weight=probe.weight,
@@ -1160,10 +1163,7 @@ class _Tracer:
                 or key.stype.is_string
                 or not jnp.issubdtype(bparts[0][1].dtype, jnp.integer)):
             return None
-        tag = self.join_tags.get(id(rel))
-        if tag is None:
-            return None
-        tag += "r" if probe_is_left else "l"
+        tag = self._build_tag(rel, probe_is_left)
         level = self.caps.get(tag, 0)
         if not level:
             return None
@@ -1173,6 +1173,11 @@ class _Tracer:
             if gathers >= ORDERED_GATHERS_A_BUILD_ROW * build.n:
                 return None
         return tag, level
+
+    def _build_tag(self, rel, probe_is_left: bool) -> str:
+        """The tag of ``rel``'s build side (``ord<j>l`` / ``r``), or ""."""
+        tag = self.join_tags.get(id(rel))
+        return tag + ("r" if probe_is_left else "l") if tag else ""
 
     def _join_ordered(self, jt, probe: _VT, build: _VT, praw: jax.Array,
                       braw: jax.Array, pvalid: jax.Array, tag: str,
@@ -1206,24 +1211,26 @@ class _Tracer:
 
     def _join_hash_table(self, jt, probe: _VT, build: _VT, pparts, bparts,
                          pvalid: jax.Array, ph: jax.Array, bh: jax.Array,
-                         exist_test=None, ordered=None):
-        """Open-addressing hash join, the CPU/GPU strategy: insert build
-        row ids into a power-of-2 table (empty-slot claim rounds, see
-        _hash_table_insert), probe with one gather chain per round actually
-        used; where the data lets the table be direct-addressed, round 0 is
-        one 32-bit gather and the only round (``_direct_probe``).
-        Verification always compares raw key parts, so lossy hashes
-        only add collisions — caught by the flags and rerun eager.  SEMI/
-        ANTI residual exist-tests aggregate (count, min, max) per slot with
-        cheap scatters, which the sorted-gather strategy could not express.
+                         exist_test=None, ordered=None, span: int = 0):
+        """Open-addressing hash join: insert build row ids into a power-of-2
+        table (empty-slot claim rounds, see _hash_table_insert), probe with
+        one gather chain per round actually used; where the data lets the
+        table be direct-addressed, round 0 is one 32-bit gather and the only
+        round (``_direct_probe``).  Verification always compares raw key
+        parts, so lossy hashes only add collisions — caught by the flags and
+        rerun eager.  SEMI/ANTI residual exist-tests aggregate (count, min,
+        max) per slot with cheap scatters, which the sorted-gather strategy
+        could not express.
         ``ordered`` (``_ordered_hint``): the build side's key column is its
         own index, and the join inserts nothing (``_join_ordered``).
+        ``span``: the class of the build key's ingest span (a ``span*`` hint:
+        ``statistics.key_span_hints``), by which ``_hash_table_size`` may give
+        one integer key a table that holds it; ``_direct_info`` checks the fit.
         """
         if ordered is not None:
             return self._join_ordered(jt, probe, build, pparts[0][1],
                                       bparts[0][1], pvalid, *ordered)
         nb, npr = build.n, probe.n
-        size = _hash_table_size(nb)
         bvalid = bh != _U64_MAX          # _hash_parts marks invalid keys
         # single integer-raw key (ints, dates, unified string codes): the
         # _mix64 rehash is a BIJECTION, so hash equality IS key equality —
@@ -1231,6 +1238,8 @@ class _Tracer:
         # enable the direct-address round-0 fast path
         bij = (len(bparts) == 1
                and jnp.issubdtype(bparts[0][1].dtype, jnp.integer))
+        size = _hash_table_size(nb, span if bij else 0, npr)
+        self.span_tables += size != _hash_table_size(nb)
         direct_b = direct_p = None
         combo_ok = None
         if bij:
@@ -1433,6 +1442,7 @@ def _build(plan: RelNode, context, scans, caps: Dict[str, int], key,
         meta["join_rows"] = tr.join_rows
         meta["limb_rows"] = dict(tr.limb_rows)
         meta["hash_table_joins"] = len(tr.direct_probes)
+        meta["span_tables"] = tr.span_tables
         meta["ordered"] = [tag for tag, _, _ in tr.ordered]
         meta["ordered_dense"] = sum(dense for _, _, dense in tr.ordered)
         meta["n_out"] = n
@@ -1505,7 +1515,8 @@ def _compact_attrs(meta: dict) -> dict:
     them find their rows inside slabs and not by a sort of all their input
     (``kernels.compact_slab_rows``: static, as everything here) and the
     largest of their caps; beside them the rows its joins take in, which is
-    the work the sites between two joins remove."""
+    the work the sites between two joins remove, and how many of their hash
+    tables a ``span*`` hint sized (``_join_hash_table``)."""
     sites = [(n_rows, cap) for (n_rows, _, tag), cap in
              zip(meta["agg_sites"], meta["ngroup_caps"])
              if tag.startswith("cmp") and cap < n_rows]
@@ -1513,7 +1524,8 @@ def _compact_attrs(meta: dict) -> dict:
             "compact_slab_sites": sum(
                 compact_slab_rows(n_rows, cap) > 0 for n_rows, cap in sites),
             "compact_cap": max((cap for _, cap in sites), default=0),
-            "join_rows": meta.get("join_rows", 0)}
+            "join_rows": meta.get("join_rows", 0),
+            "span_tables": meta.get("span_tables", 0)}
 
 
 def _count_probes(meta: dict, flags) -> None:

@@ -713,6 +713,17 @@ def _load_order_level(rel, ordinal: int, context) -> int:
     return ORDERED_NARROW if cs.domain <= 1 << 31 else ORDERED_WIDE
 
 
+def _single_key_sides(plan):
+    """``(tag, side, input, key ordinal)`` for both sides of every join of
+    ``_tagged_joins`` on ONE key pair: what a hint about a join's key can
+    speak of."""
+    for tag, rel in _tagged_joins(plan):
+        pairs = _equi_pairs(rel)
+        if len(pairs) == 1:
+            yield tag, "l", rel.left, pairs[0][0]
+            yield tag, "r", rel.right, pairs[0][1]
+
+
 def ordered_probe_hints(plan, context) -> Dict[str, int]:
     """Starting hints ``ord<j>l`` / ``ord<j>r`` for the joins on ONE key
     whose left / right input is a base table in the order of that key: the
@@ -724,19 +735,50 @@ def ordered_probe_hints(plan, context) -> Dict[str, int]:
         return {}
     hints: Dict[str, int] = {}
     try:
-        for tag, rel in _tagged_joins(plan):
-            pairs = _equi_pairs(rel)
-            if len(pairs) != 1:
-                continue
-            for side, key, input_ in (("l", pairs[0][0], rel.left),
-                                      ("r", pairs[0][1], rel.right)):
-                level = _load_order_level(input_, key, context)
-                if level:
-                    hints[tag + side] = level
+        for tag, side, input_, key in _single_key_sides(plan):
+            level = _load_order_level(input_, key, context)
+            if level:
+                hints[tag + side] = level
     except (KeyboardInterrupt, SystemExit):
         raise
     except Exception:
         logger.debug("ordered-probe hints failed", exc_info=True)
+        return {}
+    return hints
+
+
+def span_tag(side_tag: str) -> str:
+    """``span<j>l`` / ``span<j>r`` of the join side an ordered-probe hint
+    would call ``ord<j>l`` / ``ord<j>r`` (``join_tags`` and a side)."""
+    return "span" + side_tag[3:]
+
+
+def key_span_hints(plan, context) -> Dict[str, int]:
+    """Starting hints ``span<j>l`` / ``span<j>r`` for the joins on ONE
+    integer key that ``column_stats_for`` follows back to a base column:
+    the class of that column's ingest span, the power of two at or above
+    ``domain`` (max - min + 1).  A filter, a compaction or a join below
+    the key keeps its values inside the span, so a hash table of that many
+    slots can be direct-addressed whatever rows reach it; whether such a
+    table pays at the rows the join meets is the tracer's to say
+    (``hashing._hash_table_size``), and whether the keys lie inside it the
+    program's own (``hashing._direct_info``).  A statistic that is data:
+    it rides with the capacities into the program's key, as ``ord*``
+    does.  A string key (its codes are unified a join), a computed key and
+    a key of several parts get none."""
+    if not adaptive_enabled():
+        return {}
+    hints: Dict[str, int] = {}
+    try:
+        for tag, side, input_, key in _single_key_sides(plan):
+            cs = column_stats_for(input_, key, context)
+            if cs is not None and cs.is_int and cs.domain:
+                hints[span_tag(tag + side)] = _pad_pow2(cs.domain, 1,
+                                                        1 << 62)
+    except (KeyboardInterrupt, SystemExit):
+        raise
+    except Exception:
+        logger.debug("key-span hints failed", exc_info=True)
         return {}
     return hints
 
