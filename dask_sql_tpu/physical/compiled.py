@@ -43,6 +43,8 @@ imported by none of them:
 - ``stage_exec``: a plan above the heavy-node budget (physical/stages.py)
   runs as a DAG of bounded programs;
 - ``tiering``: a cold plan is answered eagerly while its programs compile;
+- ``semijoin``: what only a SEMI / ANTI join needs (its scopes' names,
+  ``NOT IN``'s three-valued logic, the residual exist-test);
 - ``ops/hashing.py``: the hash kernels joins and group-bys lower to.
 """
 from __future__ import annotations
@@ -66,13 +68,11 @@ from ..ops.hashing import (_U64_MAX, _combined_direct, _combined_int_key,
                            _row_id_table, _slot_at_round, _try_static_codes)
 from ..ops.kernels import (_INT64_MIN, canon_f64, compact_indices,
                            compact_slab_rows, comparable_data,
-                           lexsort_by_passes, orderable_int64,
-                           unify_string_codes)
+                           lexsort_by_passes, orderable_int64)
 from ..ops.pallas_kernels import _strategy_on_tpu
 from ..plan.nodes import (
     LogicalAggregate, LogicalFilter, LogicalJoin, LogicalProject, LogicalSort,
     LogicalTableScan, LogicalUnion, LogicalValues, LogicalWindow, RelNode,
-    RexCall, RexInputRef,
 )
 from ..runtime import (faults as _faults, resilience as _res,
                        statistics as _stats, telemetry as _tel)
@@ -83,6 +83,8 @@ from .identity import (Unsupported, _flatten_tables, _maybe_parameterize,
                        _program_name, program_key)
 from .programs import _Compiled, _cache  # noqa: F401
 from .rex.evaluate import evaluate_predicate, evaluate_rex
+from .semijoin import (_anti_keep, _exist_operands, _exists, _join_scope,
+                       _residual_exist_test)
 from .stage_exec import _execute_stage_graph, _partition_plan
 from .stages import heavy_count as _heavy_count, stage_budget
 from .tiering import inflight_background_compiles  # noqa: F401
@@ -206,28 +208,6 @@ def _sort_formulation(rows: int) -> bool:
     return _strategy_on_tpu() and rows <= SORT_ROWS_MAX
 
 
-def _exist_operands(x_col: Column, y_col: Column):
-    """The two sides of a SEMI/ANTI residual ``build.x OP probe.y`` on one
-    int64 domain (``_residual_exist_test`` admits nothing else)."""
-    if x_col.stype.is_string:
-        xd, yd = unify_string_codes([x_col, y_col])
-    else:
-        dt = jnp.promote_types(x_col.data.dtype, y_col.data.dtype)
-        xd, yd = x_col.data.astype(dt), y_col.data.astype(dt)
-    return xd.astype(jnp.int64), yd.astype(jnp.int64)
-
-
-def _exists(op: str, mn, mx, y) -> jax.Array:
-    """"Some build x with x OP y", from the least and greatest x of y's key."""
-    if op == "<>":
-        return (mn != y) | (mx != y)
-    if op == "<":
-        return mn < y
-    if op == "<=":
-        return mn <= y
-    return mx > y if op == ">" else mx >= y
-
-
 # ---------------------------------------------------------------------------
 # the tracer
 # ---------------------------------------------------------------------------
@@ -257,6 +237,8 @@ class _Tracer:
         # table was direct-addressed, so its probe was ``_direct_probe``
         self.direct_probes: List[jax.Array] = []
         self.span_tables = 0    # those of them a ``span*`` hint sized: static
+        # the program's SEMI / ANTI joins and inlined scalar subqueries
+        self.semi_joins = self.scalar_subqueries = 0
         # id(join) -> "ord<j>" (``statistics.join_tags``, set by _build),
         # and per join that built no table and probed its build side's key
         # column on a hint: (the hint's tag, the program's check of it,
@@ -283,6 +265,7 @@ class _Tracer:
         vt = self.run(rex.plan)
         if vt.valid is not None or vt.n != 1:
             raise Unsupported("scalar subquery with runtime row count")
+        self.scalar_subqueries += 1
         col = vt.table.columns[0]
         n = outer_table.num_rows
         d0 = col.data[0]
@@ -445,7 +428,10 @@ class _Tracer:
 
         tag = f"agg{self._agg_counter}"
         self._agg_counter += 1
-        cap = min(self.caps.get(tag, _caps.DEFAULT_GROUP_CAP), n)
+        # learned or hinted; else counted at ingest (a key column of its
+        # table, every row: ``statistics.counted_groups``); else the default
+        cap = min(self.caps.get(tag) or _stats.counted_groups(
+            rel, self.context) or _caps.DEFAULT_GROUP_CAP, n)
 
         # the dynamic-domain group-by: one scope on the device trace (beside
         # the static domain's dsql.groupby_limbs)
@@ -826,8 +812,8 @@ class _Tracer:
             # NOT EXISTS .. l3.l_suppkey <> l1.l_suppkey). Anything else —
             # or float operands, whose NaN comparison semantics the
             # min/max reduction can't reproduce — stays eager.
-            exist_test = self._residual_exist_test(rel, residual, probe,
-                                                   build)
+            exist_test = _residual_exist_test(rel, residual, probe.table,
+                                              build.table)
             if exist_test is None:
                 raise Unsupported("semi/anti join with general residual")
 
@@ -836,6 +822,7 @@ class _Tracer:
         ph = _hash_parts(pparts, pvalid)
         bh = _hash_parts(bparts, bvalid)
         self.join_rows += probe.n + build.n
+        self.semi_joins += jt in ("SEMI", "ANTI")
 
         # a side compacted at a join's output is small because the plan
         # chains joins under a hash-table join: were each join above to
@@ -881,17 +868,8 @@ class _Tracer:
             return _handed_on(probe.table.with_names(out_names),
                               probe.vmask() & match)
         if jt == "ANTI":
-            keep = ~match
-            if getattr(rel, "null_aware", False):
-                # NOT IN: any NULL key on the build side empties the
-                # result; NULL probe keys qualify only when the build is
-                # EMPTY (x NOT IN (empty) is TRUE for every x — matches
-                # ops/join.py:78-88 and PostgreSQL/SQLite)
-                build_rows = build.vmask()
-                build_has_null = (build_rows & ~bvalid).any()
-                build_nonempty = build_rows.any()
-                keep = (keep & ~build_has_null
-                        & (pvalid | ~build_nonempty))
+            keep = _anti_keep(match, pvalid, bvalid, build.vmask(),
+                              getattr(rel, "null_aware", False))
             return _out(probe.table.with_names(out_names),
                         probe.vmask() & keep)
 
@@ -934,47 +912,6 @@ class _Tracer:
                 coll = coll | (adj & d).any()
             self.fallback.append(coll)
 
-    def _residual_exist_test(self, rel, residual, probe: _VT, build: _VT):
-        """(op, x build Column, y probe Column) for a residual of the form
-        ``build.x OP probe.y`` with OP a comparison; None otherwise.
-        ``op`` is normalized so the test reads "exists build x with x OP y".
-        Floats are excluded (NaN comparison semantics don't survive the
-        min/max reduction)."""
-        if len(residual) != 1:
-            return None
-        r = residual[0]
-        if not (isinstance(r, RexCall) and r.op in ("<>", "<", "<=", ">", ">=")
-                and len(r.operands) == 2
-                and all(isinstance(o, RexInputRef) for o in r.operands)):
-            return None
-        nl = len(rel.left.schema)  # probe IS the left side for SEMI/ANTI
-        a, b = r.operands
-        if a.index < nl <= b.index:      # pred = y OP x -> exists x SWAP(OP) y
-            y_col = probe.table.columns[a.index]
-            x_col = build.table.columns[b.index - nl]
-            op = {"<": ">", ">": "<", "<=": ">=", ">=": "<=", "<>": "<>"}[r.op]
-        elif b.index < nl <= a.index:    # pred = x OP y
-            x_col = build.table.columns[a.index - nl]
-            y_col = probe.table.columns[b.index]
-            op = r.op
-        else:
-            return None
-        if x_col.stype.is_string != y_col.stype.is_string:
-            return None
-        for c in (x_col, y_col):
-            if not c.stype.is_string and jnp.issubdtype(c.data.dtype,
-                                                        jnp.floating):
-                return None
-        if not x_col.stype.is_string:
-            # the min/max reduction runs in int64: uint64 values >= 2^63
-            # would wrap on the cast and invert the ordering, and a MIXED
-            # uint64/signed pair promotes to float64 (lossy above 2^53) —
-            # only pairs whose promotion stays a signed integer are safe
-            dt = jnp.promote_types(x_col.data.dtype, y_col.data.dtype)
-            if dt == jnp.uint64 or jnp.issubdtype(dt, jnp.floating):
-                return None
-        return op, x_col, y_col
-
     def _join_merge(self, jt, probe: _VT, build: _VT, pparts, bparts,
                     pvalid: jax.Array, ph: jax.Array, bh: jax.Array,
                     exist_test=None):
@@ -1014,7 +951,7 @@ class _Tracer:
                        None if c0.mask is None else jnp.zeros(npr, bool),
                        c0.dictionary)
                 for c0 in build.table.columns]
-        with jax.named_scope("dsql.join_build"):
+        with jax.named_scope(_join_scope(jt, "build")):
             order = jnp.argsort(bh)
             bh_sorted = bh[order]
             # duplicate build keys / hash collisions appear as adjacent
@@ -1026,7 +963,7 @@ class _Tracer:
             self._append_join_flags(
                 jt, adj, [rs[1:] != rs[:-1] for rs in raws_sorted])
 
-        with jax.named_scope("dsql.join_probe"):
+        with jax.named_scope(_join_scope(jt, "probe")):
             pos = jnp.searchsorted(bh_sorted, ph, side="left", method="sort")
             in_range = pos < nb
             pos_c = jnp.minimum(pos, nb - 1)
@@ -1194,10 +1131,10 @@ class _Tracer:
         narrow = level == _stats.ORDERED_NARROW
         k = braw.astype(jnp.int64)
         raw = praw.astype(jnp.int64)
-        with jax.named_scope("dsql.join_build"):
+        with jax.named_scope(_join_scope(jt, "build")):
             lo, hi, ok = _ordered_check(k, dense, narrow)
         self.ordered.append((tag, ok, dense))
-        with jax.named_scope("dsql.join_probe"):
+        with jax.named_scope(_join_scope(jt, "probe")):
             if dense:
                 cand, found = _ordered_dense(lo, hi, raw)
             else:
@@ -1265,7 +1202,7 @@ class _Tracer:
                                _mix64(pkey.astype(jnp.uint64)), ph)
                 direct_b = _combined_direct(bkey, combo_ok, span_prod, size)
                 direct_p = direct_b._replace(raw=pkey)
-        with jax.named_scope("dsql.join_build"):
+        with jax.named_scope(_join_scope(jt, "build")):
             slot, resident, resolved, table, rounds = _hash_table_insert(
                 bh, bvalid, size, direct_b)
             rowtab = _row_id_table(table, nb)
@@ -1308,7 +1245,7 @@ class _Tracer:
             k, _ = st
             return k < rounds
 
-        with jax.named_scope("dsql.join_probe"):
+        with jax.named_scope(_join_scope(jt, "probe")):
             # a direct-addressed insert ends after round 0, which is peeled
             # here, so the loop below runs no round at all; any other table
             # discards the peeled candidates and loops from round 0
@@ -1443,6 +1380,8 @@ def _build(plan: RelNode, context, scans, caps: Dict[str, int], key,
         meta["limb_rows"] = dict(tr.limb_rows)
         meta["hash_table_joins"] = len(tr.direct_probes)
         meta["span_tables"] = tr.span_tables
+        meta["semi_joins"] = tr.semi_joins
+        meta["scalar_subqueries"] = tr.scalar_subqueries
         meta["ordered"] = [tag for tag, _, _ in tr.ordered]
         meta["ordered_dense"] = sum(dense for _, _, dense in tr.ordered)
         meta["n_out"] = n
@@ -1515,8 +1454,9 @@ def _compact_attrs(meta: dict) -> dict:
     them find their rows inside slabs and not by a sort of all their input
     (``kernels.compact_slab_rows``: static, as everything here) and the
     largest of their caps; beside them the rows its joins take in, which is
-    the work the sites between two joins remove, and how many of their hash
-    tables a ``span*`` hint sized (``_join_hash_table``)."""
+    the work the sites between two joins remove, how many of their hash
+    tables a ``span*`` hint sized (``_join_hash_table``), and what the
+    program holds of subqueries: SEMI / ANTI joins, inlined scalar ones."""
     sites = [(n_rows, cap) for (n_rows, _, tag), cap in
              zip(meta["agg_sites"], meta["ngroup_caps"])
              if tag.startswith("cmp") and cap < n_rows]
@@ -1525,7 +1465,9 @@ def _compact_attrs(meta: dict) -> dict:
                 compact_slab_rows(n_rows, cap) > 0 for n_rows, cap in sites),
             "compact_cap": max((cap for _, cap in sites), default=0),
             "join_rows": meta.get("join_rows", 0),
-            "span_tables": meta.get("span_tables", 0)}
+            "span_tables": meta.get("span_tables", 0),
+            "semi_joins": meta.get("semi_joins", 0),
+            "scalar_subqueries": meta.get("scalar_subqueries", 0)}
 
 
 def _count_probes(meta: dict, flags) -> None:

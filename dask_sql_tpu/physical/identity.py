@@ -227,10 +227,12 @@ def _maybe_parameterize(plan: RelNode, count: bool = True):
     from ..plan.parameterize import param_plans_enabled, parameterize_plan
     if not param_plans_enabled():
         return plan
-    new, hoisted = parameterize_plan(plan)
+    new, hoisted, in_subqueries = parameterize_plan(plan)
     if hoisted and count:
         _tel.inc("param_plans")
         _tel.inc("param_literals_hoisted", hoisted)
+        if in_subqueries:
+            _tel.inc("param_plan_subquery_hoisted", in_subqueries)
     return new
 
 

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 from typing import Union
 
+import jax.numpy as jnp
 import numpy as np
 
 from ...plan.nodes import (
@@ -82,6 +83,15 @@ def _eval_scalar_subquery(rex: RexScalarSubquery, table: Table, executor):
     v = vals[0]
     if v is None or (isinstance(v, float) and np.isnan(v)):
         return Scalar(None, rex.stype)
+    if jnp.issubdtype(col.data.dtype, jnp.floating):
+        # a floating value stays on the device, broadcast to the outer
+        # table's length as the compiled tier's is: read back to the host
+        # and sent again as a constant it need not come back the same on a
+        # TPU, whose float64 is emulated, and ``x = (SELECT MAX(x) ..)``
+        # then finds no row (TPC-H Q15 at SF1, one data set in seventeen:
+        # PERF.md section 6, PR 43)
+        return Column(jnp.broadcast_to(col.data[0], (table.num_rows,)),
+                      rex.stype)
     from ...types import python_value_to_physical
     return Scalar(python_value_to_physical(v, rex.stype), rex.stype)
 

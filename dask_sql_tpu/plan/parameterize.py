@@ -30,12 +30,20 @@ Eligibility is deliberately narrow (v1):
   simplifications).
 
 Structure-changing literals are never touched: IN-list arity, LIMIT /
-OFFSET counts (plain ints on LogicalSort, not rex), VALUES rows, scalar
-subquery bodies, anything under a volatile call (RAND,
-CURRENT_TIMESTAMP, ...) or a UDF.  The pass is idempotent — ``RexParam``
-nodes pass through untouched — because the compiled path's degradation
-ladder re-enters ``try_execute_compiled`` with an already-parameterized
-plan.
+OFFSET counts (plain ints on LogicalSort, not rex), VALUES rows, anything
+under a volatile call (RAND, CURRENT_TIMESTAMP, ...) or a UDF.  The pass
+is idempotent — ``RexParam`` nodes pass through untouched — because the
+compiled path's degradation ladder re-enters ``try_execute_compiled`` with
+an already-parameterized plan.
+
+The body of an uncorrelated scalar subquery (``RexScalarSubquery.plan``)
+is a plan like any other and goes through the same walk under the same
+rule: the tracer inlines it into the outer program
+(``compiled._Tracer.traced_scalar_subquery``), ``identity._fp_plan``
+serializes it with the outer plan's ``params`` list, and its scalars ride
+among the same trailing arguments.  A report that reads a CTE twice, once
+below ``= (SELECT MAX(..))``, therefore keeps one program whatever its
+dates (the counter ``param_plan_subquery_hoisted`` counts them).
 
 ``DSQL_PARAM_PLANS=0`` is the kill switch: the pass becomes the identity
 and every fingerprint/cache key is bit-for-bit what it was before this
@@ -104,25 +112,36 @@ def _contains_volatile(rex: N.RexNode) -> bool:
 
 
 class _Hoist:
-    __slots__ = ("next_slot", "hoisted")
+    __slots__ = ("next_slot", "hoisted", "in_subqueries", "depth")
 
     def __init__(self):
         self.next_slot = 0
         self.hoisted = 0
+        self.in_subqueries = 0   # those of them inside a subquery's body
+        self.depth = 0           # scalar subqueries the walk is inside
 
     def param(self, lit: N.RexLiteral) -> N.RexParam:
         p = N.RexParam(self.next_slot, lit.value, lit.stype)
         self.next_slot += 1
         self.hoisted += 1
+        if self.depth:
+            self.in_subqueries += 1
         return p
 
 
 def _walk_rex(rex: N.RexNode, acc: _Hoist) -> N.RexNode:
     """Rewrite eligible literals under this expression; returns ``rex``
     itself when nothing below changed."""
+    if isinstance(rex, N.RexScalarSubquery):
+        # the body is a plan of its own: the same walk, the same slots
+        acc.depth += 1
+        body = _walk_rel(rex.plan, acc)
+        acc.depth -= 1
+        return rex if body is rex.plan else N.RexScalarSubquery(body,
+                                                                rex.stype)
     if not isinstance(rex, N.RexCall):
         # literals NOT in an eligible comparison position stay baked;
-        # scalar-subquery plans and UDFs stay specialized wholesale
+        # UDFs stay specialized wholesale
         return rex
     if rex.op in _VOLATILE_OPS:
         return rex
@@ -186,15 +205,16 @@ def _walk_rel(rel: N.RelNode, acc: _Hoist) -> N.RelNode:
     return rel
 
 
-def parameterize_plan(plan: N.RelNode) -> Tuple[N.RelNode, int]:
-    """(rewritten plan, number of literals hoisted THIS call).
+def parameterize_plan(plan: N.RelNode) -> Tuple[N.RelNode, int, int]:
+    """(rewritten plan, literals hoisted THIS call, those of them inside
+    a scalar subquery's body).
 
     Idempotent: a second pass over the result hoists nothing (RexParam is
     not RexLiteral), so re-entrant callers (the whole→stages degradation
     rung) never double-count or renumber."""
     acc = _Hoist()
     new = _walk_rel(plan, acc)
-    return new, acc.hoisted
+    return new, acc.hoisted, acc.in_subqueries
 
 
 def collect_params(plan: N.RelNode) -> List[N.RexParam]:

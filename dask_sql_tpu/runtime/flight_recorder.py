@@ -297,7 +297,7 @@ def plan_fingerprint(plan, context) -> Optional[str]:
     from ..plan.parameterize import param_plans_enabled, parameterize_plan
 
     if param_plans_enabled():
-        plan, _ = parameterize_plan(plan)
+        plan = parameterize_plan(plan)[0]
     text, volatile, _scans = _rc.canonical_plan(plan, context, shape=True)
     if volatile:
         return None

@@ -110,6 +110,13 @@ class ColumnStats:
     #: order: the column is its own index (the compiled tier's ordered
     #: probe, physical/compiled.py), and ``ndv`` is its exact row count
     increasing: bool = False
+    #: integer column without NULLs whose values never decrease in load
+    #: order but repeat (a child table stored beside its parent's key:
+    #: TPC-H's l_orderkey, ps_partkey): its runs of equal values, which IS
+    #: its distinct count, exact where the strided sample behind ``ndv``
+    #: sees nearly every value once and says "as many as rows".  ``ndv``
+    #: itself stays the estimate it was (the joins' row estimates read it)
+    runs: Optional[int] = None
 
     def to_row(self) -> dict:
         return {
@@ -203,7 +210,8 @@ def _collect_column(name, col, rows: int, valid_rows) -> Optional[ColumnStats]:
         is_int = bool(np.issubdtype(data.dtype, np.integer))
         domain = None
         ndv: Optional[int] = None
-        increasing = bool(is_int and mask is None and _increasing(vals))
+        increasing = bool(is_int and mask is None
+                          and _ordered(vals, np.greater))
         if increasing:
             ndv = int(vals.size)  # every value once
         if is_int:
@@ -215,6 +223,10 @@ def _collect_column(name, col, rows: int, valid_rows) -> Optional[ColumnStats]:
                 ndv = int(np.count_nonzero(counts))
         if ndv is None:
             ndv = _sampled_ndv(vals)
+        runs = None
+        if is_int and mask is None and not increasing \
+                and _ordered(vals, np.greater_equal):
+            runs = int(np.count_nonzero(vals[1:] != vals[:-1])) + 1
         dense = bool(is_int and domain is not None
                      and domain <= dense_domain_cap())
         mnf, mxf = float(mn), float(mx)
@@ -222,7 +234,7 @@ def _collect_column(name, col, rows: int, valid_rows) -> Optional[ColumnStats]:
             mnf = mxf = None  # type: ignore[assignment]
         return ColumnStats(name=name, ndv=ndv, min=mnf, max=mxf,
                            null_frac=null_frac, is_int=is_int, dense=dense,
-                           domain=domain, increasing=increasing)
+                           domain=domain, increasing=increasing, runs=runs)
     except (KeyboardInterrupt, SystemExit):
         raise
     except Exception:
@@ -230,13 +242,14 @@ def _collect_column(name, col, rows: int, valid_rows) -> Optional[ColumnStats]:
         return None
 
 
-def _increasing(vals: np.ndarray) -> bool:
-    """``vals[1:] > vals[:-1]`` everywhere: one pass beside the min / max
-    pass, after a look at the head, where an unsorted column gives itself
-    away before the whole of it is compared."""
+def _ordered(vals: np.ndarray, follows) -> bool:
+    """``follows(vals[1:], vals[:-1])`` everywhere (``np.greater``: the
+    column increases; ``np.greater_equal``: it never decreases): one pass
+    beside the min / max pass, after a look at the head, where an unsorted
+    column gives itself away before the whole of it is compared."""
     head = vals[:4096]
-    return bool((head[1:] > head[:-1]).all()
-                and (vals[1:] > vals[:-1]).all())
+    return bool(follows(head[1:], head[:-1]).all()
+                and follows(vals[1:], vals[:-1]).all())
 
 
 def _sampled_ndv(vals: np.ndarray) -> int:
@@ -658,6 +671,55 @@ def compiled_cap_hints(plan, context) -> Dict[str, int]:
     except Exception:
         logger.debug("cap hints failed", exc_info=True)
         return {}
+
+
+def counted_groups(rel, context) -> Optional[int]:
+    """The capacity class of a grouped aggregate whose group count the ingest
+    statistics HOLD, for the tracer to start it from where nothing was
+    learned or hinted (``compiled._LogicalAggregate``, which gives the
+    aggregate its tag: no numbering is repeated here): one key, a base
+    column, over every row of its table (projects over a scan, no filter,
+    no join), and a distinct count that was counted and not sampled
+    (``_counted_ndv``).  TPC-H Q18's inner ``GROUP BY l_orderkey`` is 6 M
+    rows into 1.5 M groups beside an outer aggregate, so
+    ``compiled_cap_hints`` says nothing of it: from the default it climbs
+    4 096 -> 65 536 -> 1 048 576 -> 8 388 608 (``caps._check_flags`` jumps
+    x16 a saturated overflow), four whole-plan compiles and a capacity four
+    times its class; its runs start it at 2 097 152.  An estimate (a sampled
+    ``ndv``: 6 M for that column; a filter's selectivity, a join's fan-out)
+    gives None: a group cap never shrinks."""
+    if not adaptive_enabled() or forced_groupby() is not None \
+            or len(rel.group_keys) != 1:
+        return None
+    from ..plan import nodes as N
+    below = rel.input
+    while isinstance(below, N.LogicalProject):
+        below = below.input
+    if not isinstance(below, N.LogicalTableScan):
+        return None
+    try:
+        groups = _counted_ndv(
+            column_stats_for(rel.input, rel.group_keys[0], context))
+    except (KeyboardInterrupt, SystemExit):
+        raise
+    except Exception:
+        logger.debug("counted group count failed", exc_info=True)
+        return None
+    # counted: its own class, no margin, no ceiling but the rows
+    return max(64, 1 << (int(groups) - 1).bit_length()) if groups else None
+
+
+def _counted_ndv(cs: Optional[ColumnStats]) -> Optional[int]:
+    """A column's distinct count where ``_collect_column`` counted it: a
+    column that increases (every value once), a domain narrow enough to
+    bincount, the runs of one that never decreases.  None where ``ndv`` is
+    a sample's estimate."""
+    if cs is None:
+        return None
+    if cs.increasing or (cs.is_int
+                         and 0 < (cs.domain or 0) <= _NDV_PROBE_DOMAIN):
+        return cs.ndv
+    return cs.runs
 
 
 #: What an ordered-probe hint says of a join's build key column (the

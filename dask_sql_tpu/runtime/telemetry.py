@@ -175,8 +175,9 @@ STABLE_COUNTERS: Tuple[str, ...] = (
     # compiled-path program lookups for parameterized plans that hit
     # (in-memory cache or program store) vs compiled fresh;
     # prepared_executes counts EXECUTE statements served from the
-    # per-context PREPARE registry
-    "param_plans", "param_literals_hoisted",
+    # per-context PREPARE registry; param_plan_subquery_hoisted counts
+    # those of the hoisted literals that sat in a scalar subquery's body
+    "param_plans", "param_literals_hoisted", "param_plan_subquery_hoisted",
     "param_plan_hits", "param_plan_misses",
     "prepared_executes",
     # result spooler (server/app.py, ISSUE 17): results larger than

@@ -6,8 +6,10 @@ variants of a shape; distinct identities with the switch off), and the
 result-cache canonicalization contract: RexParam is value-bearing by
 default (result keys must distinguish literals) and slot+type in shape
 mode (EWMA history must not).  Also audits _canon_rel literal coverage:
-VALUES rows and scalar-subquery bodies participate in canonicalization,
-and volatile expressions are never hoisted.
+VALUES rows and scalar-subquery bodies participate in canonicalization
+(a body's literals are hoisted like the outer plan's:
+tests/unit/test_param_subquery.py), and volatile expressions are never
+hoisted.
 """
 import os
 
@@ -68,7 +70,7 @@ def _rex_kinds(plan):
 
 def test_comparison_literals_hoist(ctx):
     plan = _plan(ctx, "SELECT a FROM t WHERE a > 5 AND b <= 7.5")
-    new, n = parameterize_plan(plan)
+    new, n, _ = parameterize_plan(plan)
     assert n == 2
     params = collect_params(new)
     assert [p.value for p in params] == [5, 7.5]
@@ -81,10 +83,10 @@ def test_string_bool_null_literals_stay_baked(ctx):
     # strings resolve to dictionary codes at trace time; bools/NULLs steer
     # trace-time simplification — none may become runtime arguments
     plan = _plan(ctx, "SELECT a FROM t WHERE s = 'v1'")
-    _, n = parameterize_plan(plan)
+    _, n, _ = parameterize_plan(plan)
     assert n == 0
     plan = _plan(ctx, "SELECT a FROM t WHERE (a > 3) = TRUE")
-    new, _ = parameterize_plan(plan)
+    new, _, _ = parameterize_plan(plan)
     assert all(not (isinstance(p, N.RexParam)
                     and isinstance(p.value, bool))
                for p in collect_params(new))
@@ -94,7 +96,7 @@ def test_both_scalar_comparison_not_hoisted(ctx):
     # 1 < 2 has no column ref on either side: hoisting would push a traced
     # scalar through the host `bool()` branch of ops.comparison
     plan = _plan(ctx, "SELECT a FROM t WHERE 1 < 2 AND a > 5")
-    new, n = parameterize_plan(plan)
+    new, n, _ = parameterize_plan(plan)
     assert n == 1
     assert [p.value for p in collect_params(new)] == [5]
 
@@ -106,8 +108,8 @@ def test_in_list_arity_stays_structural(ctx):
     # never share a fingerprint (checked below via canonical text).
     p2 = _plan(ctx, "SELECT a FROM t WHERE a IN (1, 2)")
     p3 = _plan(ctx, "SELECT a FROM t WHERE a IN (1, 2, 3)")
-    n2, _ = parameterize_plan(p2)
-    n3, _ = parameterize_plan(p3)
+    n2, _, _ = parameterize_plan(p2)
+    n3, _, _ = parameterize_plan(p3)
     t2 = rc.canonical_plan(n2, ctx, shape=True)[0]
     t3 = rc.canonical_plan(n3, ctx, shape=True)[0]
     assert t2 != t3
@@ -115,14 +117,14 @@ def test_in_list_arity_stays_structural(ctx):
 
 def test_volatile_expressions_never_hoisted(ctx):
     plan = _plan(ctx, "SELECT a FROM t WHERE b > RAND(1) AND RAND(2) < 0.5")
-    new, n = parameterize_plan(plan)
+    new, n, _ = parameterize_plan(plan)
     assert n == 0
     assert collect_params(new) == []
 
 
 def test_values_rows_stay_baked(ctx):
     plan = _plan(ctx, "SELECT * FROM (VALUES (1, 2.0), (3, 4.0)) AS v(x, y)")
-    new, n = parameterize_plan(plan)
+    new, n, _ = parameterize_plan(plan)
     assert n == 0
     # and VALUES literals participate in canonicalization: different rows,
     # different canonical text (the result cache must not cross-serve)
@@ -131,23 +133,25 @@ def test_values_rows_stay_baked(ctx):
             != rc.canonical_plan(other, ctx)[0])
 
 
-def test_scalar_subquery_body_stays_baked_but_canonicalized(ctx):
+def test_scalar_subquery_body_is_hoisted_and_canonicalized(ctx):
     q = "SELECT a FROM t WHERE b > (SELECT AVG(b) FROM t WHERE a > {k})"
     p5 = _plan(ctx, q.format(k=5))
     p9 = _plan(ctx, q.format(k=9))
-    n5, h5 = parameterize_plan(p5)
-    parameterize_plan(p9)
-    # the subquery body is specialized wholesale: no param inside it
+    n5, h5, _ = parameterize_plan(p5)
+    n9, _, _ = parameterize_plan(p9)
+    # the body is a plan like any other (PR 43): its literal is a param
     sub_lits = [k for k in _rex_kinds(n5) if k == "RexParam"]
-    assert len(sub_lits) == h5  # only the outer hoists (if any)
-    # ... and its literal is visible to the canonicalizer
+    assert len(sub_lits) == h5 == 1
+    assert [p.value for p in collect_params(n5)] == [5]
+    # ... and its value stays visible to the canonicalizer, hoisted or not
     assert rc.canonical_plan(p5, ctx)[0] != rc.canonical_plan(p9, ctx)[0]
+    assert rc.canonical_plan(n5, ctx)[0] != rc.canonical_plan(n9, ctx)[0]
 
 
 def test_idempotent(ctx):
     plan = _plan(ctx, "SELECT a FROM t WHERE a > 5")
-    once, n1 = parameterize_plan(plan)
-    twice, n2 = parameterize_plan(once)
+    once, n1, _ = parameterize_plan(plan)
+    twice, n2, _ = parameterize_plan(once)
     assert n1 == 1 and n2 == 0
     assert twice is once
 
