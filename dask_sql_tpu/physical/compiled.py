@@ -239,6 +239,11 @@ class _Tracer:
         self.span_tables = 0    # those of them a ``span*`` hint sized: static
         # the program's SEMI / ANTI joins and inlined scalar subqueries
         self.semi_joins = self.scalar_subqueries = 0
+        # id(node) -> its _VT (the plan outlives the trace, so an id stays
+        # its node's): ``run`` traces a node once, and counts the
+        # references it answered from here
+        self._ran: Dict[int, _VT] = {}
+        self.shared_subplans = 0
         # id(join) -> "ord<j>" (``statistics.join_tags``, set by _build),
         # and per join that built no table and probed its build side's key
         # column on a hint: (the hint's tag, the program's check of it,
@@ -281,13 +286,23 @@ class _Tracer:
 
     # -- dispatch ----------------------------------------------------------
     def run(self, rel: RelNode) -> _VT:
+        """Lower ``rel``, once: a node the plan holds twice (a CTE read
+        twice, ``shared.unify``) hands its second reference the first one's
+        ``_VT``, so it has one ``agg*`` / ``cmp*`` site, one entry in every
+        table keyed by ``id`` and one place in the program (both sides of
+        TPC-H Q15's ``=`` are parts of one device array)."""
+        vt = self._ran.get(id(rel))
+        if vt is not None:
+            self.shared_subplans += 1
+            return vt
         m = getattr(self, "_" + type(rel).__name__, None)
         if m is None:
             raise Unsupported(type(rel).__name__)
         # trace time only: every op this node lowers to carries the node's
         # type in its op_name, which is how a device trace names it
         with jax.named_scope("dsql." + type(rel).__name__):
-            return m(rel)
+            vt = self._ran[id(rel)] = m(rel)
+        return vt
 
     # -- nodes -------------------------------------------------------------
     def _LogicalTableScan(self, rel: LogicalTableScan) -> _VT:
@@ -1382,6 +1397,7 @@ def _build(plan: RelNode, context, scans, caps: Dict[str, int], key,
         meta["span_tables"] = tr.span_tables
         meta["semi_joins"] = tr.semi_joins
         meta["scalar_subqueries"] = tr.scalar_subqueries
+        meta["shared_subplans"] = tr.shared_subplans
         meta["ordered"] = [tag for tag, _, _ in tr.ordered]
         meta["ordered_dense"] = sum(dense for _, _, dense in tr.ordered)
         meta["n_out"] = n
@@ -1467,7 +1483,8 @@ def _compact_attrs(meta: dict) -> dict:
             "join_rows": meta.get("join_rows", 0),
             "span_tables": meta.get("span_tables", 0),
             "semi_joins": meta.get("semi_joins", 0),
-            "scalar_subqueries": meta.get("scalar_subqueries", 0)}
+            "scalar_subqueries": meta.get("scalar_subqueries", 0),
+            "shared_subplans": meta.get("shared_subplans", 0)}
 
 
 def _count_probes(meta: dict, flags) -> None:

@@ -34,7 +34,8 @@ class Unsupported(Exception):
 _DENY_OPS = {"RAND", "RAND_INTEGER"}
 
 
-def _fp_rex(rex: RexNode, context=None, scans=None, params=None) -> str:
+def _fp_rex(rex: RexNode, context=None, scans=None, params=None,
+            seen=None) -> str:
     if params is None:
         params = []
     if isinstance(rex, RexInputRef):
@@ -62,23 +63,39 @@ def _fp_rex(rex: RexNode, context=None, scans=None, params=None) -> str:
         if info is not None:
             extra = f"!{getattr(info, 'name', info)}"
         return (f"C{rex.op}{extra}["
-                + ",".join(_fp_rex(o, context, scans, params)
+                + ",".join(_fp_rex(o, context, scans, params, seen)
                            for o in rex.operands)
                 + f"]:{rex.stype.name}")
     if isinstance(rex, RexScalarSubquery) and context is not None:
         # uncorrelated scalar subquery: the subplan joins the cache key and
         # its scans join the input spec; the tracer inlines it as a
         # broadcast 1-row result
-        return ("S[" + _fp_plan(rex.plan, context, scans, params)
+        return ("S[" + _fp_plan(rex.plan, context, scans, params, seen)
                 + f"]:{rex.stype.name}")
     raise Unsupported(type(rex).__name__)
 
 
-def _fp_plan(rel: RelNode, context, scans: list, params=None) -> str:
+def _fp_plan(rel: RelNode, context, scans: list, params=None,
+             seen=None) -> str:
     """Serialize the plan for cache keying; collects scan tables (and the
-    plan's RexParam nodes, in serialization order, into ``params``)."""
+    plan's RexParam nodes, in serialization order, into ``params``).  A node
+    the plan holds twice (``shared.unify``) is written once: its second
+    reference reads ``^k``, the k-th node the walk finished, so its scans
+    are listed, and bound, once."""
     if params is None:
         params = []
+    if seen is None:
+        seen = {}
+    back = seen.get(id(rel))
+    if back is None:
+        text = _fp_node(rel, context, scans, params, seen)
+        seen[id(rel)] = len(seen)
+        return text
+    return f"^{back}"
+
+
+def _fp_node(rel: RelNode, context, scans: list, params: list,
+             seen: dict) -> str:
     t = type(rel).__name__
     schema = ";".join(f"{f.name}:{f.stype.name}" for f in rel.schema)
     if isinstance(rel, LogicalTableScan):
@@ -94,10 +111,10 @@ def _fp_plan(rel: RelNode, context, scans: list, params=None) -> str:
         rv = "+rv" if entry.row_valid is not None else ""
         return f"Scan({rel.schema_name}.{rel.table_name}{rv})[{schema}]"
     if isinstance(rel, LogicalProject):
-        body = ",".join(_fp_rex(e, context, scans, params)
+        body = ",".join(_fp_rex(e, context, scans, params, seen)
                         for e in rel.exprs)
     elif isinstance(rel, LogicalFilter):
-        body = _fp_rex(rel.condition, context, scans, params)
+        body = _fp_rex(rel.condition, context, scans, params, seen)
     elif isinstance(rel, LogicalAggregate):
         for agg in rel.aggs:
             if agg.udaf is not None:
@@ -122,7 +139,7 @@ def _fp_plan(rel: RelNode, context, scans: list, params=None) -> str:
         # fingerprint so it can't share a program with a plain anti join
         na = "N" if getattr(rel, "null_aware", False) else ""
         cond = ("T" if rel.condition is None
-                else _fp_rex(rel.condition, context, scans, params))
+                else _fp_rex(rel.condition, context, scans, params, seen))
         body = f"{rel.join_type}{na}|{cond}"
     elif isinstance(rel, LogicalSort):
         body = (",".join(f"{c.index}{'a' if c.ascending else 'd'}"
@@ -146,7 +163,8 @@ def _fp_plan(rel: RelNode, context, scans: list, params=None) -> str:
         body = repr([[lit.value for lit in row] for row in rel.rows])
     else:
         raise Unsupported(type(rel).__name__)
-    kids = ",".join(_fp_plan(i, context, scans, params) for i in rel.inputs)
+    kids = ",".join(_fp_plan(i, context, scans, params, seen)
+                    for i in rel.inputs)
     return f"{t}({body})[{schema}]<{kids}>"
 
 
@@ -220,13 +238,20 @@ def _flatten_tables(scans) -> List[jax.Array]:
 
 def _maybe_parameterize(plan: RelNode, count: bool = True):
     """Hoist literals into runtime arguments (plan/parameterize.py) unless
-    the DSQL_PARAM_PLANS kill switch is off.  Idempotent — re-entries from
-    the degradation ladder / background compiles hoist nothing and count
-    nothing; probes pass ``count=False`` so a tier prediction never
-    inflates the execution counters."""
+    the DSQL_PARAM_PLANS kill switch is off, after the subtrees the plan
+    holds more than once by value were made one node each
+    (``shared.unify``: a CTE read twice is then hoisted, keyed and traced
+    once; the identity on a plan without a repeat).  Idempotent — re-entries
+    from the degradation ladder / background compiles unify and hoist
+    nothing and count nothing; probes pass ``count=False`` so a tier
+    prediction never inflates the execution counters."""
     from ..plan.parameterize import param_plans_enabled, parameterize_plan
+    from .shared import unify
     if not param_plans_enabled():
         return plan
+    plan, replaced = unify(plan)
+    if replaced and count:
+        _tel.inc("param_plan_shared_subtrees", replaced)
     new, hoisted, in_subqueries = parameterize_plan(plan)
     if hoisted and count:
         _tel.inc("param_plans")

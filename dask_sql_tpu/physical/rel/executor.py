@@ -31,6 +31,7 @@ from ...table import Column, Scalar, Table
 from ...types import physical_dtype
 from ...utils import Pluggable
 from ..rex.evaluate import evaluate_predicate, evaluate_rex
+from ..shared import read_twice
 
 logger = logging.getLogger(__name__)
 
@@ -41,14 +42,18 @@ class RelExecutor(Pluggable):
     def __init__(self, context):
         self.context = context
         # id(node) -> canonical text of the aggregates and joins the plan
-        # holds more than once (``_read_twice``, at the first ``execute``),
-        # and the text -> the one result every copy hands on
+        # holds more than once (``shared.read_twice``, at the first
+        # ``execute``), and the text -> the one result every copy hands on:
+        # half the work, and the copies' floating sums are the SAME array,
+        # where two runs of one kernel need not round alike on a TPU, whose
+        # float64 is emulated (1 of 17 first arrivals of TPC-H Q15 at SF1
+        # came back empty: PERF.md section 6, PR 43)
         self._twice = None
         self._once: dict = {}
 
     def execute(self, rel: RelNode) -> Table:
         if self._twice is None:
-            self._twice = _read_twice(rel)
+            self._twice = read_twice(rel)
         text = self._twice.get(id(rel))
         if text is None:
             return self._execute(rel)
@@ -76,50 +81,6 @@ class RelExecutor(Pluggable):
             return result
         result = plugin(rel, self)
         return result
-
-
-def _read_twice(plan: RelNode) -> dict:
-    """id(node) -> canonical text (``result_cache.canonical_plan``: by value,
-    scalar subqueries' bodies included) of the aggregates and joins whose
-    subtree ``plan`` holds more than once: a CTE the text reads twice, one
-    copy perhaps inside a scalar subquery's body (TPC-H Q15's ``total_revenue
-    = (SELECT MAX(total_revenue) FROM revenue0)``).  ``execute`` runs such a
-    subtree once and hands every copy the one result: half the work, and the
-    copies' floating sums are the SAME array, where two runs of one kernel
-    need not round alike on a TPU, whose float64 is emulated (1 of 17 first
-    arrivals of Q15 at SF1 came back empty: PERF.md section 6, PR 43).
-    Nothing volatile is shared."""
-    from ...plan.nodes import RexScalarSubquery
-    from ...runtime.result_cache import canonical_plan
-
-    found: List[RelNode] = []
-
-    def of_rex(rex) -> None:
-        if isinstance(rex, RexScalarSubquery):
-            walk(rex.plan)
-        for o in getattr(rex, "operands", ()):
-            of_rex(o)
-
-    def walk(rel: RelNode) -> None:
-        if isinstance(rel, (LogicalAggregate, LogicalJoin)):
-            found.append(rel)
-        for rex in (*getattr(rel, "exprs", ()),
-                    getattr(rel, "condition", None)):
-            if rex is not None:
-                of_rex(rex)
-        for i in rel.inputs:
-            walk(i)
-
-    walk(plan)
-    if len(found) < 2:
-        return {}
-    texts = {}
-    for rel in found:
-        text, volatile, _ = canonical_plan(rel)
-        if not volatile:
-            texts.setdefault(text, []).append(rel)
-    return {id(rel): text for text, rels in texts.items() if len(rels) > 1
-            for rel in rels}
 
 
 # ---------------------------------------------------------------------------

@@ -43,7 +43,20 @@ rule: the tracer inlines it into the outer program
 serializes it with the outer plan's ``params`` list, and its scalars ride
 among the same trailing arguments.  A report that reads a CTE twice, once
 below ``= (SELECT MAX(..))``, therefore keeps one program whatever its
-dates (the counter ``param_plan_subquery_hoisted`` counts them).
+dates.
+
+Such a plan reaches this pass as a DAG: ``physical/shared.unify`` has made
+the copies of a subtree that are equal BY VALUE one node object
+(``identity._maybe_parameterize``; TPC-H Q15's CTE, whose two dates stood in
+both copies).  The walk rewrites a node it meets twice ONCE and hands every
+reference the same rewritten node, so the CTE has one set of slots (Q15
+hoists 2 literals, not 4), ``identity._fp_rex`` numbers each ``RexParam`` in
+one place, and the tracer, which keeps a node's result by identity, traces
+the CTE once.  Two copies that differ in a literal were not equal by value,
+are two nodes here and get slots of their own: no program rests on two
+slots happening to hold one value.  The counter
+``param_plan_subquery_hoisted`` counts the slots a scalar subquery's body
+reads, those it shares with the plan around it included.
 
 ``DSQL_PARAM_PLANS=0`` is the kill switch: the pass becomes the identity
 and every fingerprint/cache key is bit-for-bit what it was before this
@@ -112,20 +125,21 @@ def _contains_volatile(rex: N.RexNode) -> bool:
 
 
 class _Hoist:
-    __slots__ = ("next_slot", "hoisted", "in_subqueries", "depth")
+    __slots__ = ("next_slot", "in_bodies", "depth", "done")
 
     def __init__(self):
-        self.next_slot = 0
-        self.hoisted = 0
-        self.in_subqueries = 0   # those of them inside a subquery's body
+        self.next_slot = 0       # = the literals hoisted so far
+        self.in_bodies = set()   # the slots a scalar subquery's body reads
         self.depth = 0           # scalar subqueries the walk is inside
+        # id(node) -> (its rewrite, the slots hoisted beneath it as a
+        # range): a node the plan holds twice is rewritten once
+        self.done = {}
 
     def param(self, lit: N.RexLiteral) -> N.RexParam:
         p = N.RexParam(self.next_slot, lit.value, lit.stype)
-        self.next_slot += 1
-        self.hoisted += 1
         if self.depth:
-            self.in_subqueries += 1
+            self.in_bodies.add(p.slot)
+        self.next_slot += 1
         return p
 
 
@@ -167,6 +181,19 @@ def _walk_rex(rex: N.RexNode, acc: _Hoist) -> N.RexNode:
 
 
 def _walk_rel(rel: N.RelNode, acc: _Hoist) -> N.RelNode:
+    """Rewrite ``rel`` once, however many references the plan holds to it
+    (``copy.copy`` per reference would split a shared node again)."""
+    hit = acc.done.get(id(rel))
+    if hit is None:
+        lo = acc.next_slot
+        hit = acc.done[id(rel)] = (_rewrite_rel(rel, acc),
+                                   range(lo, acc.next_slot))
+    elif acc.depth:
+        acc.in_bodies.update(hit[1])
+    return hit[0]
+
+
+def _rewrite_rel(rel: N.RelNode, acc: _Hoist) -> N.RelNode:
     kids = rel.inputs
     new_kids = [_walk_rel(k, acc) for k in kids]
     changed = any(n is not o for n, o in zip(new_kids, kids))
@@ -206,15 +233,16 @@ def _walk_rel(rel: N.RelNode, acc: _Hoist) -> N.RelNode:
 
 
 def parameterize_plan(plan: N.RelNode) -> Tuple[N.RelNode, int, int]:
-    """(rewritten plan, literals hoisted THIS call, those of them inside
-    a scalar subquery's body).
+    """(rewritten plan, literals hoisted THIS call, those of them a scalar
+    subquery's body reads).
 
-    Idempotent: a second pass over the result hoists nothing (RexParam is
-    not RexLiteral), so re-entrant callers (the whole→stages degradation
-    rung) never double-count or renumber."""
+    Idempotent, for a DAG as for a tree: a second pass over the result
+    hoists nothing (RexParam is not RexLiteral) and returns the nodes it was
+    given, so re-entrant callers (the whole→stages degradation rung) never
+    double-count, renumber or split a shared node."""
     acc = _Hoist()
     new = _walk_rel(plan, acc)
-    return new, acc.hoisted, acc.in_subqueries
+    return new, acc.next_slot, len(acc.in_bodies)
 
 
 def collect_params(plan: N.RelNode) -> List[N.RexParam]:

@@ -652,7 +652,10 @@ def compiled_cap_hints(plan, context) -> Dict[str, int]:
     aggs: List[Any] = []
 
     def walk(rel) -> None:
-        if isinstance(rel, N.LogicalAggregate) and rel.group_keys:
+        # one node reached twice (a CTE read twice, ``physical/shared.py``)
+        # is one aggregate of the trace
+        if isinstance(rel, N.LogicalAggregate) and rel.group_keys \
+                and not any(rel is a for a in aggs):
             aggs.append(rel)
         for i in rel.inputs:
             walk(i)
@@ -733,7 +736,8 @@ ORDERED_WIDE, ORDERED_NARROW, ORDERED_DENSE = 1, 2, 3
 def _tagged_joins(plan) -> list:
     """``("ord<j>", join)`` for the plan's joins, each numbered after those
     of its left and of its right input.  A scalar subquery's are not among
-    them."""
+    them, and a join the plan holds twice as one node
+    (``physical/shared.py``) is numbered where it is met first."""
     from ..plan import nodes as N
 
     joins: list = []
@@ -741,7 +745,8 @@ def _tagged_joins(plan) -> list:
     def walk(rel) -> None:
         for i in rel.inputs:
             walk(i)
-        if isinstance(rel, N.LogicalJoin):
+        if isinstance(rel, N.LogicalJoin) \
+                and not any(rel is j for _, j in joins):
             joins.append((f"ord{len(joins)}", rel))
 
     walk(plan)

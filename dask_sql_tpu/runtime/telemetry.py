@@ -176,9 +176,11 @@ STABLE_COUNTERS: Tuple[str, ...] = (
     # (in-memory cache or program store) vs compiled fresh;
     # prepared_executes counts EXECUTE statements served from the
     # per-context PREPARE registry; param_plan_subquery_hoisted counts
-    # those of the hoisted literals that sat in a scalar subquery's body
+    # those of the hoisted literals that a scalar subquery's body reads;
+    # param_plan_shared_subtrees the references to a subtree the plan
+    # held twice that were pointed at its first copy (physical/shared.py)
     "param_plans", "param_literals_hoisted", "param_plan_subquery_hoisted",
-    "param_plan_hits", "param_plan_misses",
+    "param_plan_shared_subtrees", "param_plan_hits", "param_plan_misses",
     "prepared_executes",
     # result spooler (server/app.py, ISSUE 17): results larger than
     # DSQL_RESULT_PAGE_ROWS spool into the spill store and stream out

@@ -20,7 +20,7 @@ PACKAGE = os.path.dirname(os.path.abspath(dask_sql_tpu.__file__))
 #: the modules beneath ``physical/compiled.py``, and the three outside the
 #: executor that used to reach into it for a program's identity
 BENEATH = ["physical/identity.py", "ops/hashing.py", "physical/caps.py",
-           "physical/semijoin.py",
+           "physical/semijoin.py", "physical/shared.py",
            "physical/programs.py", "physical/stage_exec.py",
            "physical/tiering.py", "physical/stages.py",
            "runtime/profiler.py", "runtime/system_tables.py",

@@ -1,8 +1,9 @@
 """The eager executor and a CTE read twice, once below ``= (SELECT MAX(..))``
 (TPC-H Q15, PR 43).  Two things hold its float equality together:
 
-- a subtree the plan holds twice runs ONCE (``rel/executor.py::_read_twice``):
-  the copies have one canonical text (``result_cache.canonical_plan``, by
+- a subtree the plan holds twice runs ONCE (``RelExecutor.execute``'s memo
+  by ``physical/shared.py::read_twice``, the one finder of both tiers): the
+  copies have one canonical text (``result_cache.canonical_plan``, by
   value) and every copy is handed the one result, so both sides of the
   ``=`` are read from the same array;
 - a floating scalar subquery's value stays on the device
@@ -19,6 +20,7 @@ import pytest
 
 from chipbench.data import tpch_gen
 from dask_sql_tpu import Context
+from dask_sql_tpu.physical import shared
 from dask_sql_tpu.physical.rel import executor as ex
 from dask_sql_tpu.plan import nodes as N
 from dask_sql_tpu.sql.parser import parse_sql
@@ -89,7 +91,7 @@ SHARED = {
 
 @pytest.mark.parametrize("text", sorted(SHARED))
 def test_the_subtrees_a_plan_holds_twice(ctx, text):
-    twice = ex._read_twice(_plan(ctx, text))
+    twice = shared.read_twice(_plan(ctx, text))
     assert len(twice) == SHARED[text]
     assert len(set(twice.values())) == (1 if twice else 0)
 
@@ -126,7 +128,7 @@ def test_q15_on_the_eager_tier_makes_its_revenue_once(tpch, executed,
     shape = importlib.import_module("chipbench.shapes.q15")
     params = shape.params_at(shape.FIRST)
     plan = _plan(context, shape.SQL.format(**params))
-    twice = ex._read_twice(plan)
+    twice = shared.read_twice(plan)
     assert len(twice) == 2 and len(set(twice.values())) == 1
     got = context.sql(shape.SQL.format(**params), return_futures=False)
     assert context.last_report.tier == "eager"

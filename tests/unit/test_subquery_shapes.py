@@ -19,8 +19,9 @@ from dask_sql_tpu.physical.caps import _learned_caps
 #: lineitem has 360 000 rows here: joins and compaction sites engage
 SF = 0.06
 
-#: shape: (SEMI / ANTI joins, inlined scalar subqueries) of its program
-SHAPES = {"q4": (1, 0), "q15": (0, 1), "q18": (1, 0)}
+#: shape: (SEMI / ANTI joins, inlined scalar subqueries, references to a
+#: subtree the plan held twice that its one trace answered) of its program
+SHAPES = {"q4": (1, 0, 0), "q15": (0, 1, 1), "q18": (1, 0, 0)}
 
 
 def _shape(name):
@@ -85,10 +86,18 @@ def test_one_program_serves_new_parameters_and_says_what_it_holds(
         report = ctx.last_report
         assert report.tier == "compiled"
         span, = [s for s in report.root.walk() if s.name == "dispatch"]
-        assert (span.attrs["semi_joins"],
-                span.attrs["scalar_subqueries"]) == SHAPES[name]
+        assert (span.attrs["semi_joins"], span.attrs["scalar_subqueries"],
+                span.attrs["shared_subplans"]) == SHAPES[name]
     assert cm.stats["compiles"] + cm.stats["recompiles"] == compiles
-
+    if name == "q15":
+        # the CTE the text reads twice is in the program once: one group-by
+        # and, lineitem having 360 000 rows here, one compaction below it
+        tags = [sorted(tag for _, _, tag in e.meta["agg_sites"])
+                for e in programs._cache.values()
+                if getattr(e, "name", None) == span.attrs["program"]
+                and "agg_sites" in e.meta]
+        assert ["agg0", "cmp0"] in tags
+        assert all(t.count("agg0") == 1 and "agg1" not in t for t in tags)
 
 def _scopes(jaxpr, found=None, inside=()):
     """{``dsql.`` scope: the primitives of the equations under it}."""
