@@ -8,7 +8,7 @@ codes (NULLs form their own group), then every aggregate is a
 """
 from __future__ import annotations
 
-from typing import List, Optional
+from typing import List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -16,7 +16,8 @@ import numpy as np
 
 from ..table import dict_sort_order, Column, Scalar, Table
 from ..types import SqlType, exact_decimal_scale, physical_dtype
-from .kernels import comparable_data, decimal_unscale, factorize_columns
+from .kernels import (comparable_data, compact_indices, decimal_unscale,
+                      factorize_columns)
 
 
 def group_codes(key_cols: List[Column], variant: str = "hash",
@@ -320,6 +321,146 @@ def segment_aggregate(op: str, col: Optional[Column], codes: Optional[jax.Array]
         return Column._encode_strings(strs, None)
 
     raise NotImplementedError(f"Aggregate {op}")
+
+
+# ---------------------------------------------------------------------------
+# GROUP BY on a key column that never decreases in row order: the groups are
+# the column's runs of equal neighbours, so nothing is hashed, and nothing
+# scatters or gathers at the rows: elementwise passes over the n rows, one
+# sort of their positions and gathers at the group capacity.  The compiled
+# tier takes it on an ingest statistic's word (``statistics.grouped_by_runs``)
+# and the program checks that word (``KeyRuns.ok``).
+# ---------------------------------------------------------------------------
+
+#: what ``run_aggregate`` covers; a node with any other aggregate keeps
+#: ``segment_aggregate`` (``compiled._LogicalAggregate`` asks)
+RUN_AGGREGATE_OPS = frozenset({"COUNT", "SUM", "$SUM0", "AVG", "MIN", "MAX"})
+
+
+class KeyRuns(NamedTuple):
+    """The runs of a key column of n rows at a capacity of ``cap`` groups.
+    Group g is rows ``[starts[g], ends[g])``, in row order (the order of
+    first occurrence, as ``hashing._group_hashed_codes`` numbers groups);
+    slots past ``num_groups`` hold ``n`` twice, an empty run."""
+    boundary: jax.Array     # [n] bool: the row opens a run
+    starts: jax.Array       # [cap]
+    ends: jax.Array         # [cap]
+    num_groups: jax.Array   # the runs counted, over ``cap`` or not
+    steps: jax.Array        # ceil(log2(the longest run held))
+    ok: jax.Array           # the column never decreases
+
+
+def key_runs(k: jax.Array, cap: int) -> KeyRuns:
+    n = k.shape[0]
+    boundary = jnp.concatenate([jnp.ones(min(n, 1), bool), k[1:] != k[:-1]])
+    ok = (k[1:] >= k[:-1]).all()
+    starts, num_groups = compact_indices(boundary, cap)
+    starts = jnp.where(jnp.arange(cap) < num_groups, starts, n)
+    ends = jnp.concatenate([starts[1:], jnp.full(min(cap, 1), n,
+                                                 starts.dtype)])
+    longest = jnp.max(ends - starts, initial=1).astype(jnp.int32)
+    return KeyRuns(boundary, starts, ends, num_groups,
+                   32 - jax.lax.clz(longest - 1), ok)
+
+
+def _sum_over_runs(work: jax.Array, runs: KeyRuns) -> jax.Array:
+    """Exact sums of an integer row a run: the running sum read at the runs'
+    last rows, less the run's before.  int64 wraps, so the difference is
+    right wherever the run's own sum fits, whatever the running total."""
+    n = work.shape[0]
+    at_end = jnp.cumsum(work)[jnp.clip(runs.ends - 1, 0, max(n - 1, 0))]
+    return at_end - jnp.concatenate([jnp.zeros(min(len(at_end), 1),
+                                               at_end.dtype), at_end[:-1]])
+
+
+def _reduce_in_runs(work: jax.Array, runs: KeyRuns, combine) -> jax.Array:
+    """``combine`` over each run, formed INSIDE the run: in step s a row
+    takes in the row 2^s before it unless its own window already reaches
+    its run's first row (``reached``, which doubles the same way), for as
+    many steps as the longest run asks (3 for TPC-H's seven lines an order,
+    ``ceil(log2 n)`` at the worst), and a run's result stands at its last
+    row.  A floating sum is pairwise inside its run: never the difference
+    of a running total, whose rounding is the column's and not the run's."""
+    n = work.shape[0]
+    pad = 1 << max((n - 1).bit_length() - 1, 0)
+
+    def before(a, d):
+        return jax.lax.dynamic_slice(
+            jnp.concatenate([jnp.zeros(pad, a.dtype), a]), (pad - d,), (n,))
+
+    def step(carry):
+        s, x, reached = carry
+        d = jnp.int32(1) << s
+        return (s + 1, jnp.where(reached, x, combine(x, before(x, d))),
+                reached | before(reached, d))
+
+    _, x, _ = jax.lax.while_loop(lambda c: c[0] < runs.steps, step,
+                                 (jnp.int32(0), work, runs.boundary))
+    return x[jnp.clip(runs.ends - 1, 0, max(n - 1, 0))]
+
+
+def run_aggregate(op: str, col: Optional[Column], runs: KeyRuns,
+                  out_type: SqlType,
+                  filter_mask: Optional[jax.Array] = None) -> Column:
+    """``segment_aggregate`` for the ops of ``RUN_AGGREGATE_OPS`` over the
+    runs of a key column, by its rules: the exact decimals, the NULL rules,
+    string MIN / MAX by dictionary rank.  Invalid rows (NULLs, a FILTER's, a
+    DISTINCT's keep mask) contribute the operator's neutral element."""
+    sized = (runs.ends - runs.starts).astype(jnp.int64)
+    if col is None and filter_mask is None:
+        return Column(sized, out_type, None)
+    valid = filter_mask
+    if col is not None and col.mask is not None:
+        valid = col.mask if valid is None else (valid & col.mask)
+    # every row valid: a run's count is its length
+    count = sized if valid is None else _sum_over_runs(
+        valid.astype(runs.starts.dtype), runs).astype(jnp.int64)
+    if op == "COUNT":
+        return Column(count, out_type, None)
+    has_any = count > 0
+
+    def masked(work, neutral):
+        return work if valid is None else jnp.where(valid, work, neutral)
+
+    data = col.data
+    if op in ("SUM", "$SUM0", "AVG"):
+        dscale = exact_decimal_scale(col.stype)
+        if dscale is not None:
+            s_int = _sum_over_runs(
+                masked(_decimal_scaled_ints(data, dscale), 0), runs)
+            return _decimal_exact_result(op, s_int, count, dscale, out_type)
+        if jnp.issubdtype(data.dtype, jnp.integer):
+            s = _sum_over_runs(masked(data.astype(jnp.int64), 0), runs)
+        else:
+            s = _reduce_in_runs(masked(data.astype(jnp.float64), 0.0), runs,
+                                jnp.add)
+        if op == "AVG":
+            return Column(s.astype(jnp.float64) / jnp.maximum(count, 1),
+                          out_type, has_any)
+        return Column(s.astype(physical_dtype(out_type)), out_type,
+                      has_any if op == "SUM" else None)
+
+    if op not in ("MIN", "MAX"):
+        raise NotImplementedError(f"Aggregate {op} over runs")
+    combine = jnp.minimum if op == "MIN" else jnp.maximum
+    if col.stype.is_string:
+        info = jnp.iinfo(jnp.int32)
+        ranks = _reduce_in_runs(
+            masked(col.dict_ranks().data.astype(jnp.int32),
+                   info.max if op == "MIN" else info.min), runs, combine)
+        order = dict_sort_order(col.dictionary)
+        codes = jnp.take(jnp.asarray(order.astype(np.int32)),
+                         jnp.clip(ranks, 0, len(order) - 1))
+        return Column(codes, out_type, has_any, col.dictionary)
+    if jnp.issubdtype(data.dtype, jnp.floating):
+        neutral = jnp.inf if op == "MIN" else -jnp.inf
+    elif data.dtype == jnp.bool_:
+        data, neutral = data.astype(jnp.int64), int(op == "MIN")
+    else:
+        info = jnp.iinfo(data.dtype)
+        neutral = info.max if op == "MIN" else info.min
+    out = _reduce_in_runs(masked(data, neutral), runs, combine)
+    return Column(out.astype(physical_dtype(out_type)), out_type, has_any)
 
 
 def distinct_rows(cols: List[Column]) -> jax.Array:

@@ -117,6 +117,10 @@ def starting_caps(pk, context, count: bool = True) -> Dict[str, int]:
     # table that can be direct-addressed (``hashing._hash_table_size``)
     for tag, span in _stats.key_span_hints(pk.plan, context).items():
         caps.setdefault(tag, span)
+    # how many GROUP BYs may take their groups from the runs of a key
+    # column in load order: refuted once, it stays learned as 0
+    for tag, runs in _stats.run_group_hints(pk.plan, context).items():
+        caps.setdefault(tag, runs)
     return caps
 
 
@@ -135,7 +139,8 @@ class _NeedsRecompile(Exception):
     ``telemetry.RECOMPILE_COUNTERS``: ``cap_overflow`` (a group cap or a
     compaction site dropped rows), ``cap_tighten`` (a compaction site far
     above its count, or one that only counted and goes live),
-    ``hint_refuted`` (an ``ord*`` hint the column did not keep)."""
+    ``hint_refuted`` (an ``ord*`` or ``runs`` hint the column did not
+    keep)."""
 
     def __init__(self, caps, reason):
         self.caps = caps
@@ -155,15 +160,19 @@ def changed(entry, new_caps: Dict[str, int]) -> str:
 
 
 def _check_ordered(entry, flags) -> None:
-    """Raise _NeedsRecompile where a join probed its build side's key
-    column on a hint (``ord*``, runtime/statistics.py) the column did not
-    keep: after the sites' counts the flags hold one entry a hinted join
-    (``meta["ordered"]``, trace order), set where the program's check of
-    the physical column failed.  Such a run's answer is worth nothing and
-    neither are its other flags, the eager bit among them: the next round
-    clears the hint and builds the table, and the cleared hint is
-    learned."""
-    tags = entry.meta.get("ordered")
+    """Raise _NeedsRecompile where a program took a column's order on a
+    hint (runtime/statistics.py) the column did not keep: a join that
+    probed its build side's key column (``ord*``), a GROUP BY that took its
+    groups from the runs of its key (``runs``).  After the sites' counts
+    the flags hold one entry a hinted join (``meta["ordered"]``, trace
+    order) and one a GROUP BY by runs (``meta["run_groupbys"]`` of them),
+    set where the program's check of the physical column failed.  Such a
+    run's answer is worth nothing and neither are its other flags, the
+    eager bit among them: the next round clears the hint and builds the
+    table, and the cleared hint is learned."""
+    from ..runtime.statistics import RUN_GROUPS_TAG
+    tags = list(entry.meta.get("ordered") or ()) \
+        + [RUN_GROUPS_TAG] * entry.meta.get("run_groupbys", 0)
     if not tags:
         return
     refuted = flags[2 + len(entry.meta["agg_sites"]):][:len(tags)]

@@ -15,7 +15,8 @@ counts allow:
   the merge join (``_join_merge``), whose sorts compile inside a set-up up
   to there and run faster on the chip;
 - GROUP BY hashes its keys into group codes with a static capacity
-  (``_hashed_aggregate``) or, over a statically enumerable key domain,
+  (``_hashed_aggregate``), takes the runs of a key column in load order for
+  its groups (``_run_aggregate``) or, over a statically enumerable domain,
   reduces on the MXU with no capacity at all (``_static_domain_aggregate``);
 - ORDER BY is one multi-key sort up to ``LEXSORT_ROWS_MAX`` rows under the
   TPU strategy and a single-key sort a key channel above it
@@ -250,6 +251,8 @@ class _Tracer:
         # whether the column is dense, so that the probe is direct)
         self.join_tags: Dict[int, str] = {}
         self.ordered: List[Tuple[str, jax.Array, bool]] = []
+        # per GROUP BY by runs (``_run_aggregate``): the check of its hint
+        self.run_groups: List[jax.Array] = []
         # filter nodes (by id) eligible for learned-capacity compaction —
         # computed by _compact_eligible over the whole plan before tracing
         self.compact_ok: set = set()
@@ -399,8 +402,8 @@ class _Tracer:
 
     def _compact_site(self, count: jax.Array, cap: int, n: int,
                       tag: str) -> None:
-        """The flags' entry of a compaction site: what it counted, what it
-        holds, the rows it took in (``_check_flags`` reads all three)."""
+        """The flags' entry of a site that counts exactly (a compaction, a
+        GROUP BY by runs): count, capacity, input rows (``_check_flags``)."""
         self.ngroups.append(count)
         self.ngroup_caps.append(cap)
         self.agg_sites.append((n, False, tag))
@@ -416,13 +419,7 @@ class _Tracer:
         out_names = [f.name for f in rel.schema]
 
         if not rel.group_keys:
-            for j, agg in enumerate(rel.aggs):
-                f = rel.schema[j]
-                col = src.table.columns[agg.args[0]] if agg.args else None
-                fmask = self._agg_filter(agg, src)
-                if agg.distinct and agg.op not in ("MIN", "MAX"):
-                    keep = self._distinct_keep([], agg, src)
-                    fmask = keep if fmask is None else (fmask & keep)
+            for agg, f, col, fmask in self._agg_inputs(rel, src, []):
                 out_cols.append(G.whole_table_aggregate(
                     agg.op, col, fmask, f.stype, n))
             return _VT(Table(out_names, out_cols), None)
@@ -448,10 +445,51 @@ class _Tracer:
         cap = min(self.caps.get(tag) or _stats.counted_groups(
             rel, self.context) or _caps.DEFAULT_GROUP_CAP, n)
 
+        # every row of a scan as loaded, grouped by one integer column that
+        # the statistics say never decreases (the hint ``runs`` among the
+        # capacities, 0 once a program refuted it): its runs are the groups
+        key = key_cols[0]
+        by_runs = bool(
+            self.caps.get(_stats.RUN_GROUPS_TAG) and src.load_order
+            and src.valid is None and len(key_cols) == 1
+            and key.mask is None and not key.stype.is_string
+            and jnp.issubdtype(key.data.dtype, jnp.integer)
+            and all(a.op in G.RUN_AGGREGATE_OPS for a in rel.aggs)
+            and _stats.grouped_by_runs(rel, self.context))
         # the dynamic-domain group-by: one scope on the device trace (beside
         # the static domain's dsql.groupby_limbs)
         with jax.named_scope("dsql.groupby_sorted"):
+            if by_runs:
+                return self._run_aggregate(rel, src, key, cap, tag)
             return self._hashed_aggregate(rel, src, key_cols, cap, tag)
+
+    def _run_aggregate(self, rel, src: _VT, key: Column, cap: int,
+                       tag: str) -> _VT:
+        """GROUP BY a key column in load order (ops/groupby.py ``key_runs``):
+        no table, no scatter; the groups in ``_hashed_aggregate``'s order
+        (first occurrence), and the check of the hint among the flags
+        (``caps._check_ordered``: a refuted one never answers)."""
+        runs = G.key_runs(key.data, cap)
+        self.run_groups.append(runs.ok)
+        self._compact_site(runs.num_groups, cap, src.n, tag)
+        cols = [key.take(jnp.minimum(runs.starts, src.n - 1))]
+        for agg, f, col, fmask in self._agg_inputs(rel, src, [key]):
+            cols.append(G.run_aggregate(agg.op, col, runs, f.stype, fmask))
+        return _VT(Table([f.name for f in rel.schema], cols),
+                   jnp.arange(cap) < runs.num_groups)
+
+    def _agg_inputs(self, rel, src: _VT, key_cols: List[Column]):
+        """(aggregate, output field, argument column, row mask) of each
+        aggregate of ``rel``: the mask is its FILTER, the rows' validity
+        and, for a DISTINCT one, the first occurrences of its argument."""
+        for j, agg in enumerate(rel.aggs):
+            fmask = self._agg_filter(agg, src)
+            if agg.distinct and agg.op not in ("MIN", "MAX"):
+                keep = self._distinct_keep(key_cols, agg, src)
+                fmask = keep if fmask is None else (fmask & keep)
+            yield (agg, rel.schema[len(rel.group_keys) + j],
+                   src.table.columns[agg.args[0]] if agg.args else None,
+                   fmask)
 
     def _hashed_aggregate(self, rel, src: _VT, key_cols: List[Column],
                           cap: int, tag: str) -> _VT:
@@ -482,13 +520,7 @@ class _Tracer:
                           None if col.mask is None else col.mask[:cap],
                           col.dictionary)
 
-        for j, agg in enumerate(rel.aggs):
-            f = rel.schema[len(rel.group_keys) + j]
-            col = src.table.columns[agg.args[0]] if agg.args else None
-            fmask = self._agg_filter(agg, src)
-            if agg.distinct and agg.op not in ("MIN", "MAX"):
-                keep = self._distinct_keep(key_cols, agg, src)
-                fmask = keep if fmask is None else (fmask & keep)
+        for agg, f, col, fmask in self._agg_inputs(rel, src, key_cols):
             out_cols.append(_trim(G.segment_aggregate(
                 agg.op, col, codes, cap + 1, f.stype, filter_mask=fmask,
                 n_rows=n)))
@@ -1378,12 +1410,13 @@ def _build(plan: RelNode, context, scans, caps: Dict[str, int], key,
         fb = jnp.zeros((), dtype=bool)
         for f in tr.fallback:
             fb = fb | f
-        # after the sites, one entry an ordered probe: its hint refuted
-        # (``_check_ordered``).  The tail is read by count from the END
-        # (``_materialize``): what ``_check_flags`` reads keeps its positions
+        # after the sites, one entry an ordered probe and a GROUP BY by runs:
+        # its hint refuted (``_check_ordered``).  The tail is read by count
+        # from the END (``_materialize``): ``_check_flags``' part stays put
         flags = jnp.stack([fb.astype(jnp.int64), count]
                           + [g.astype(jnp.int64) for g in
                              tr.ngroups + [~ok for _, ok, _ in tr.ordered]
+                             + [~ok for ok in tr.run_groups]
                              + tr.direct_probes])
         meta["names"] = list(out.table.names)
         meta["cols"] = [(c.stype, c.mask is not None, c.dictionary)
@@ -1400,6 +1433,7 @@ def _build(plan: RelNode, context, scans, caps: Dict[str, int], key,
         meta["shared_subplans"] = tr.shared_subplans
         meta["ordered"] = [tag for tag, _, _ in tr.ordered]
         meta["ordered_dense"] = sum(dense for _, _, dense in tr.ordered)
+        meta["run_groupbys"] = len(tr.run_groups)
         meta["n_out"] = n
         outs: List[jax.Array] = [flags]
         for c in out.table.columns:
@@ -1484,7 +1518,8 @@ def _compact_attrs(meta: dict) -> dict:
             "span_tables": meta.get("span_tables", 0),
             "semi_joins": meta.get("semi_joins", 0),
             "scalar_subqueries": meta.get("scalar_subqueries", 0),
-            "shared_subplans": meta.get("shared_subplans", 0)}
+            "shared_subplans": meta.get("shared_subplans", 0),
+            "run_groupbys": meta.get("run_groupbys", 0)}
 
 
 def _count_probes(meta: dict, flags) -> None:
@@ -1527,6 +1562,8 @@ def _materialize(entry: _Compiled, outs) -> Table:
         return None
     _check_flags(entry, flags)
     _count_probes(meta, flags)
+    if meta.get("run_groupbys"):
+        _tel.inc("groupby_run_aggregates", meta["run_groupbys"])
     count = int(flags[1])
     cut = meta["has_valid"] and count < meta["n_out"]
     sel = np.nonzero(host[-1])[0] if small and cut else None

@@ -634,6 +634,24 @@ def _pad_pow2(n: int, lo: int = 64, hi: int = 1 << 20) -> int:
     return min(max(1 << (n - 1).bit_length(), lo), hi)
 
 
+def _grouped_aggregates(plan) -> List[Any]:
+    """The grouped aggregates among the plan's inputs; one node reached
+    twice (a CTE read twice, ``physical/shared.py``) is one aggregate of
+    the trace."""
+    from ..plan import nodes as N
+    aggs: List[Any] = []
+
+    def walk(rel) -> None:
+        if isinstance(rel, N.LogicalAggregate) and rel.group_keys \
+                and not any(rel is a for a in aggs):
+            aggs.append(rel)
+        for i in rel.inputs:
+            walk(i)
+
+    walk(plan)
+    return aggs
+
+
 def compiled_cap_hints(plan, context) -> Dict[str, int]:
     """Stats-derived starting caps for the compiled executor's padded
     group-capacity classes.
@@ -647,21 +665,8 @@ def compiled_cap_hints(plan, context) -> Dict[str, int]:
     capacity-escalation recompile, too large is just the old padding."""
     if not adaptive_enabled() or forced_groupby() is not None:
         return {}
-    from ..plan import nodes as N
-
-    aggs: List[Any] = []
-
-    def walk(rel) -> None:
-        # one node reached twice (a CTE read twice, ``physical/shared.py``)
-        # is one aggregate of the trace
-        if isinstance(rel, N.LogicalAggregate) and rel.group_keys \
-                and not any(rel is a for a in aggs):
-            aggs.append(rel)
-        for i in rel.inputs:
-            walk(i)
-
     try:
-        walk(plan)
+        aggs = _grouped_aggregates(plan)
         if len(aggs) != 1:
             return {}
         rel = aggs[0]
@@ -676,21 +681,10 @@ def compiled_cap_hints(plan, context) -> Dict[str, int]:
         return {}
 
 
-def counted_groups(rel, context) -> Optional[int]:
-    """The capacity class of a grouped aggregate whose group count the ingest
-    statistics HOLD, for the tracer to start it from where nothing was
-    learned or hinted (``compiled._LogicalAggregate``, which gives the
-    aggregate its tag: no numbering is repeated here): one key, a base
-    column, over every row of its table (projects over a scan, no filter,
-    no join), and a distinct count that was counted and not sampled
-    (``_counted_ndv``).  TPC-H Q18's inner ``GROUP BY l_orderkey`` is 6 M
-    rows into 1.5 M groups beside an outer aggregate, so
-    ``compiled_cap_hints`` says nothing of it: from the default it climbs
-    4 096 -> 65 536 -> 1 048 576 -> 8 388 608 (``caps._check_flags`` jumps
-    x16 a saturated overflow), four whole-plan compiles and a capacity four
-    times its class; its runs start it at 2 097 152.  An estimate (a sampled
-    ``ndv``: 6 M for that column; a filter's selectivity, a join's fan-out)
-    gives None: a group cap never shrinks."""
+def _whole_table_key(rel, context) -> Optional[ColumnStats]:
+    """The ingest statistics of a grouped aggregate's key where they speak
+    of its groups: one key, a base column, over every row of its table
+    (projects over a scan, no filter, no join).  None elsewhere."""
     if not adaptive_enabled() or forced_groupby() is not None \
             or len(rel.group_keys) != 1:
         return None
@@ -701,15 +695,63 @@ def counted_groups(rel, context) -> Optional[int]:
     if not isinstance(below, N.LogicalTableScan):
         return None
     try:
-        groups = _counted_ndv(
-            column_stats_for(rel.input, rel.group_keys[0], context))
+        return column_stats_for(rel.input, rel.group_keys[0], context)
     except (KeyboardInterrupt, SystemExit):
         raise
     except Exception:
-        logger.debug("counted group count failed", exc_info=True)
+        logger.debug("group key statistics failed", exc_info=True)
         return None
+
+
+def counted_groups(rel, context) -> Optional[int]:
+    """The capacity class of a grouped aggregate whose group count the ingest
+    statistics HOLD, for the tracer to start it from where nothing was
+    learned or hinted (``compiled._LogicalAggregate``, which gives the
+    aggregate its tag: no numbering is repeated here): one key, a base
+    column, over every row of its table (``_whole_table_key``), and a
+    distinct count that was counted and not sampled (``_counted_ndv``).
+    TPC-H Q18's inner ``GROUP BY l_orderkey`` is 6 M
+    rows into 1.5 M groups beside an outer aggregate, so
+    ``compiled_cap_hints`` says nothing of it: from the default it climbs
+    4 096 -> 65 536 -> 1 048 576 -> 8 388 608 (``caps._check_flags`` jumps
+    x16 a saturated overflow), four whole-plan compiles and a capacity four
+    times its class; its runs start it at 2 097 152.  An estimate (a sampled
+    ``ndv``: 6 M for that column; a filter's selectivity, a join's fan-out)
+    gives None: a group cap never shrinks."""
+    groups = _counted_ndv(_whole_table_key(rel, context))
     # counted: its own class, no margin, no ceiling but the rows
     return max(64, 1 << (int(groups) - 1).bit_length()) if groups else None
+
+
+def grouped_by_runs(rel, context) -> bool:
+    """Whether the groups of a grouped aggregate are the RUNS of its key
+    column: the key of ``_whole_table_key``, an integer column without NULLs
+    that never decreases in load order (``runs``, or ``increasing``: every
+    row a group).  The compiled tier then takes the groups from the column's
+    boundaries and hashes nothing (``ops/groupby.py::key_runs``): TPC-H
+    Q18's inner GROUP BY, per-session totals over events loaded in session
+    order, any roll-up of a child table stored beside its parent's key.  A
+    statistic that is data: the plan's count of such aggregates rides with
+    the capacities (``run_group_hints``), the tracer asks here for the
+    aggregate it stands at, and the program checks the column itself."""
+    cs = _whole_table_key(rel, context)
+    return cs is not None and (cs.increasing or cs.runs is not None)
+
+
+#: the tag of ``run_group_hints``' word among a request's capacities
+RUN_GROUPS_TAG = "runs"
+
+
+def run_group_hints(plan, context) -> Dict[str, int]:
+    """``{"runs": k}`` where ``k`` > 0 grouped aggregates among the plan's
+    inputs are ``grouped_by_runs``: part of the program's key as the
+    ``ord*`` hints are (other data of the same layout is another program),
+    and 0 once a program's own check refuted it
+    (``caps._check_ordered``).  A count, not a tag an aggregate: the
+    tracer numbers aggregates, nothing else does."""
+    runs = sum(grouped_by_runs(rel, context)
+               for rel in _grouped_aggregates(plan))
+    return {RUN_GROUPS_TAG: runs} if runs else {}
 
 
 def _counted_ndv(cs: Optional[ColumnStats]) -> Optional[int]:

@@ -656,7 +656,8 @@ def test_filter_compaction_learned_caps(monkeypatch):
          "ngroup_caps": [n // 8, n // 4, 64]}) == {
         "compact_sites": 2, "compact_slab_sites": 1,
         "compact_cap": n // 4, "join_rows": 0, "span_tables": 0,
-        "semi_joins": 0, "scalar_subqueries": 0, "shared_subplans": 0}
+        "semi_joins": 0, "scalar_subqueries": 0, "shared_subplans": 0,
+        "run_groupbys": 0}
     # a filter under a global aggregate never compacts
     ctx.sql("SELECT SUM(v) AS s FROM fact WHERE sel < 3", return_futures=False)
     attrs = dispatch_of("SELECT SUM(v) AS s FROM fact WHERE sel < 2").attrs

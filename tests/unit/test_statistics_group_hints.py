@@ -188,3 +188,67 @@ def test_the_switch_off_counts_nothing(ctx, monkeypatch):
     assert st.counted_groups(_grouped(plan, ctx)["k"], ctx) == 8192
     monkeypatch.setenv("DSQL_ADAPTIVE", "0")
     assert st.counted_groups(_grouped(plan, ctx)["k"], ctx) is None
+
+
+#: text: whether each of its plan's grouped aggregates takes its groups from
+#: the runs of its key (``statistics.grouped_by_runs``), by key; and the
+#: word the plan's capacities carry (``run_group_hints``)
+RUN_CASES = {
+    # a base column that never decreases, over every row of its table
+    "SELECT k, n FROM (SELECT k, COUNT(*) AS n FROM fact GROUP BY k) x "
+    "WHERE k IN (SELECT g FROM fact GROUP BY g HAVING SUM(v) > 1)":
+        ({"k": True, "g": False}, {"runs": 1}),
+    # a filter: the runs survive it, the count of groups does not
+    "SELECT k, n FROM (SELECT k, COUNT(*) AS n FROM fact WHERE v > 0.5 "
+    "GROUP BY k) x": ({"k": False}, {}),
+    # a join below the aggregate
+    "SELECT a.k, COUNT(*) AS n FROM fact a JOIN fact b ON a.k = b.w "
+    "GROUP BY a.k": ({"k": False}, {}),
+    # two keys, the first in order
+    "SELECT k, g, COUNT(*) AS n FROM fact GROUP BY k, g": ({"k": False}, {}),
+    # a key in no order, and a string
+    "SELECT w, n FROM (SELECT w, COUNT(*) AS n FROM fact GROUP BY w) x "
+    "WHERE w IN (SELECT s FROM fact GROUP BY s)":
+        ({"w": False, "s": False}, {}),
+    # a computed key is no base column
+    "SELECT k2, COUNT(*) AS n FROM (SELECT k + 1 AS k2 FROM fact) x "
+    "GROUP BY k2": ({"?": False}, {}),
+    # through a project that only renames, and strictly increasing: every
+    # row a group
+    "SELECT r, SUM(v) AS s FROM (SELECT u AS r, v FROM uniq) x GROUP BY r":
+        ({"u": True}, {"runs": 1}),
+    # a NULL in the key: no order was counted
+    "SELECT kn, COUNT(*) AS n FROM uniq GROUP BY kn": ({"kn": False}, {}),
+    # two such aggregates in one plan are two of the count
+    "SELECT x.k, x.n, y.s FROM (SELECT k, COUNT(*) AS n FROM fact "
+    "GROUP BY k) x JOIN (SELECT u, SUM(v) AS s FROM uniq GROUP BY u) y "
+    "ON x.k = y.u": ({"k": True, "u": True}, {"runs": 2}),
+}
+
+
+@pytest.fixture(scope="module")
+def run_ctx(ctx):
+    rng = np.random.RandomState(45)
+    uniq = pd.DataFrame({
+        "u": np.arange(3000) * 5 + 1, "v": rng.rand(3000),
+        "kn": pd.array(np.repeat(np.arange(1000), 3), dtype="Int64")})
+    uniq.loc[17, "kn"] = pd.NA
+    ctx.create_table("uniq", uniq)
+    return ctx
+
+
+@pytest.mark.parametrize("text", sorted(RUN_CASES))
+def test_the_groups_are_runs_only_where_the_statistics_hold_it(run_ctx, text):
+    by_key, word = RUN_CASES[text]
+    plan = _plan(run_ctx, text)
+    assert {key: st.grouped_by_runs(rel, run_ctx)
+            for key, rel in _grouped(plan, run_ctx).items()} == by_key
+    assert st.run_group_hints(plan, run_ctx) == word
+
+
+def test_the_switch_off_hints_no_runs(ctx, monkeypatch):
+    plan = _plan(ctx, "SELECT k, COUNT(*) AS n FROM fact GROUP BY k")
+    assert st.run_group_hints(plan, ctx) == {st.RUN_GROUPS_TAG: 1}
+    monkeypatch.setenv("DSQL_ADAPTIVE", "0")
+    assert st.run_group_hints(plan, ctx) == {}
+    assert not st.grouped_by_runs(_grouped(plan, ctx)["k"], ctx)
