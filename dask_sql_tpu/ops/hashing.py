@@ -207,7 +207,7 @@ _TABLE_BYTES_MAX = 1 << 30
 #: The most slots a table sized from its key's span may have for each row
 #: of the larger of the probe side and the table it replaces: what a fill of
 #: the larger table may cost beside the probe it saves.  Priced on a TPU v5e
-#: (PERF.md section 6, PR 42; ``_join_hash_table`` alone, medians of seven):
+#: (PERF.md section 6, PR 42; ``joins.hash_table`` alone, medians of seven):
 #: a slot costs 0.045 ns (131 072 x 2 097 152 rows: 65.3 ms at 2^24 slots,
 #: 67.6 at 2^26), a probe row that loops 46 to 110 ns more than one that is
 #: direct-addressed, so the fill pays up to 1 000 slots a row: 1 024 x
@@ -465,8 +465,8 @@ def _direct_probe(rowtab: jax.Array, direct: _Direct, n: int) -> jax.Array:
 # on a key column that strictly increases, is its own index.  Nothing is
 # inserted: the row of key ``k`` is ``k - lo`` where the keys are dense, and
 # found by a search of the column itself where they are not.  The tracer
-# (``compiled._join_hash_table``) takes it on an ingest statistic's word and
-# the program checks that word over the physical column.
+# takes it (``joins.ordered``) on an ingest statistic's word and the
+# program checks that word over the physical column.
 # ---------------------------------------------------------------------------
 
 def _ordered_check(k: jax.Array, dense: bool, narrow: bool):

@@ -46,7 +46,7 @@ def shard_table_with_validity(table, mesh: Mesh):
     marking the real rows. Column NULL masks are untouched — padding
     visibility is a TABLE property (COUNT(*) must not see pad rows), which
     the compiled executor's validity-mask pipeline consumes directly
-    (physical/compiled.py _VT)."""
+    (physical/traced.py _VT)."""
     import jax.numpy as jnp
 
     from ..table import Column, Table

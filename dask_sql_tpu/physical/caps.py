@@ -4,9 +4,10 @@ the next round's capacities.
 
 Static shapes need a capacity for every GROUP BY and every compaction site
 (``compiled._Tracer``); a program reports what each site counted through its
-flags, ``_check_flags`` asks for a recompile where a capacity proved too
-small (or far too large), and what was learned is kept per program key: in
-memory, and in ``DSQL_CAPS_FILE`` for the next process.
+flags (``traced.read`` names their parts), ``_check_flags`` asks for a
+recompile where a capacity proved too small (or far too large), and what was
+learned is kept per program key: in memory, and in ``DSQL_CAPS_FILE`` for
+the next process.
 """
 from __future__ import annotations
 
@@ -15,6 +16,7 @@ from collections import OrderedDict
 from typing import Dict, Optional
 
 from ..runtime import kvstore as _kv, telemetry as _tel
+from .traced import read as _read_flags
 
 DEFAULT_GROUP_CAP = 4096
 _LEARNED_LIMIT = 1024
@@ -30,7 +32,6 @@ _learned_caps: "OrderedDict[tuple, Dict[str, int]]" = OrderedDict()
 # fingerprint, input layout fingerprint, strategy — so a cap never applies
 # to a different query, data layout, or backend strategy.
 _caps_disk: Optional[Dict[str, Dict[str, int]]] = None
-_caps_seed: Optional[Dict[str, Dict[str, int]]] = None
 
 
 def _caps_disk_read(path: str) -> Dict[str, Dict[str, int]]:
@@ -45,27 +46,12 @@ def _learned_caps_get(base_key) -> Dict[str, int]:
     caps = _learned_caps.get(base_key)
     if caps is not None:
         return dict(caps)
-    key = None
     path = os.environ.get("DSQL_CAPS_FILE")
     if path:
         global _caps_disk
         if _caps_disk is None:
             _caps_disk = _caps_disk_read(path)
-        key = _kv.digest_key(base_key)
-        hit = _caps_disk.get(key)
-        if hit:
-            return dict(hit)
-    # read-only seed (``DSQL_CAPS_SEED=/path.json``): caps and split hints
-    # learned on one host, committed with the repo, consulted when neither
-    # memory nor the writable caps file knows this program.  Keys are
-    # content-based (plan + input-layout fingerprints), so a seed entry can
-    # only ever match the same query over same-layout data — on any host.
-    seed_path = os.environ.get("DSQL_CAPS_SEED")
-    if seed_path:
-        global _caps_seed
-        if _caps_seed is None:
-            _caps_seed = _caps_disk_read(seed_path)
-        return dict(_caps_seed.get(key or _kv.digest_key(base_key), {}))
+        return dict(_caps_disk.get(_kv.digest_key(base_key), {}))
     return {}
 
 
@@ -163,27 +149,20 @@ def _check_ordered(entry, flags) -> None:
     """Raise _NeedsRecompile where a program took a column's order on a
     hint (runtime/statistics.py) the column did not keep: a join that
     probed its build side's key column (``ord*``), a GROUP BY that took its
-    groups from the runs of its key (``runs``).  After the sites' counts
-    the flags hold one entry a hinted join (``meta["ordered"]``, trace
-    order) and one a GROUP BY by runs (``meta["run_groupbys"]`` of them),
-    set where the program's check of the physical column failed.  Such a
-    run's answer is worth nothing and neither are its other flags, the
-    eager bit among them: the next round clears the hint and builds the
-    table, and the cleared hint is learned."""
-    from ..runtime.statistics import RUN_GROUPS_TAG
-    tags = list(entry.meta.get("ordered") or ()) \
-        + [RUN_GROUPS_TAG] * entry.meta.get("run_groupbys", 0)
-    if not tags:
-        return
-    refuted = flags[2 + len(entry.meta["agg_sites"]):][:len(tags)]
-    if refuted.any():
+    groups from the runs of its key (``runs``).  The flags say of each
+    such hint whether the program's check of the physical column failed.
+    Such a run's answer is worth nothing and neither are its other flags,
+    the eager bit among them: the next round clears the hint and builds
+    the table, and the cleared hint is learned."""
+    refuted = _read_flags(entry.meta, flags).refuted
+    if any(bad for _, bad in refuted):
         raise _NeedsRecompile({**entry.caps, **{
-            tag: 0 for tag, bad in zip(tags, refuted) if bad}},
+            tag: 0 for tag, bad in refuted if bad}},
             "hint_refuted")
 
 
 def _check_flags(entry, flags) -> None:
-    """Raise _NeedsRecompile on group-cap overflow; flags[0] => eager.
+    """Raise _NeedsRecompile on group-cap overflow.
     ``entry`` is a ``programs._Compiled`` (its ``meta`` and ``caps``).
     Compaction sites (tag cmp*) additionally SHRINK: a cap far above the
     observed count recompiles once to a tight one (persisted, so future
@@ -201,9 +180,9 @@ def _check_flags(entry, flags) -> None:
     new_caps = dict(entry.caps)
     recompile = False
     exact = True
-    for (n_rows, hashed, tag), cap, ng in zip(meta["agg_sites"],
-                                              meta["ngroup_caps"],
-                                              flags[2:]):
+    for (n_rows, hashed, tag), cap, ng in zip(
+            meta["agg_sites"], meta["ngroup_caps"],
+            _read_flags(meta, flags).site_counts):
         ng = int(ng)
         if ng > cap:
             if hashed and ng > n_rows:

@@ -9,15 +9,18 @@ TPU-first answer (SURVEY §5, §7 "hard parts" item 2): a plan is traced
 a validity mask, and every operator takes the formulation its static row
 counts allow:
 
-- an equi-join builds a hash table and probes it (``_join_hash_table``;
-  kernels in ops/hashing.py) on every backend; under the TPU strategy only
-  where its probe side has more than ``SORT_ROWS_MAX`` rows, and below that
-  the merge join (``_join_merge``), whose sorts compile inside a set-up up
-  to there and run faster on the chip;
+- an equi-join builds a hash table and probes it (``joins.hash_table``;
+  kernels in ops/hashing.py) on every backend, or probes its build side's
+  key column where a hint says that is in order (``joins.ordered``); under
+  the TPU strategy only where its probe side has more than
+  ``SORT_ROWS_MAX`` rows, and below that the merge join (``joins.merge``),
+  whose sorts compile inside a set-up up to there and run faster on the
+  chip;
 - GROUP BY hashes its keys into group codes with a static capacity
-  (``_hashed_aggregate``), takes the runs of a key column in load order for
-  its groups (``_run_aggregate``) or, over a statically enumerable domain,
-  reduces on the MXU with no capacity at all (``_static_domain_aggregate``);
+  (``aggregates.hashed_aggregate``), takes the runs of a key column in load
+  order for its groups (``run_aggregate``) or, over a statically enumerable
+  domain, reduces on the MXU with no capacity at all
+  (``static_domain_aggregate``);
 - ORDER BY is one multi-key sort up to ``LEXSORT_ROWS_MAX`` rows under the
   TPU strategy and a single-key sort a key channel above it
   (``lexsort_by_passes``); off it a terminal ORDER BY runs on the host;
@@ -25,9 +28,12 @@ counts allow:
   a join's output that another join takes in are compacted to a learned
   capacity (``_maybe_compact``, ``_compact_eligible``).
 
-What XLA cannot express statically (group-count overflow, non-unique build
-side, 64-bit hash collision) surfaces through a flags vector; the host
-recompiles with another capacity or falls back to the eager executor.  Plan
+The tracer CHOOSES a formulation (``_LogicalJoin``, ``_LogicalAggregate``,
+``_maybe_compact``) and the modules beneath it lower one.  What XLA cannot
+express statically (group-count overflow, non-unique build side, 64-bit
+hash collision) surfaces through a flags vector, whose layout
+``traced.ProgramFlags`` alone knows; the host recompiles with another
+capacity or falls back to the eager executor.  Plan
 shapes outside the subset (UDFs, host-bound string ops) are found at trace
 time and cached as such.  Steady state is one dispatch and one fetch per
 program, and fresh data of the same layout never recompiles.
@@ -38,6 +44,13 @@ decision beneath it has one module; this one imports them all and is
 imported by none of them:
 
 - ``identity``: what a program is (``program_key``), its digest, its name;
+- ``traced``: the stream between two operators (``_VT``) and the ledger of
+  what a trace owes the host (``ProgramFlags``: the flags vector, packed
+  and read);
+- ``joins``: the four formulations of an equi-join, each a function of its
+  inputs and the ledger;
+- ``aggregates``: the three of a grouped aggregate, and the masks of FILTER
+  and DISTINCT;
 - ``caps``: learned capacities and how a run's flags change them;
 - ``programs``: a program's life (cache, in-flight claims, program store,
   quarantine and watchdog, compile retries, the degradation ladder);
@@ -51,7 +64,6 @@ imported by none of them:
 from __future__ import annotations
 
 import logging
-import math
 import os
 from typing import Dict, List, Optional, Tuple
 
@@ -60,13 +72,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops import groupby as G
-from ..ops.hashing import (_U64_MAX, _combined_direct, _combined_int_key,
-                           _decode_static_keys, _direct_info,
-                           _direct_probe, _group_hashed_codes, _hash_parts,
-                           _hash_table_insert, _hash_table_size,
-                           _join_key_parts, _keys_valid, _mix64,
-                           _ordered_check, _ordered_dense, _ordered_search,
-                           _row_id_table, _slot_at_round, _try_static_codes)
+from ..ops.hashing import _hash_parts, _join_key_parts, _keys_valid
 from ..ops.kernels import (_INT64_MIN, canon_f64, compact_indices,
                            compact_slab_rows, comparable_data,
                            lexsort_by_passes, orderable_int64)
@@ -78,17 +84,18 @@ from ..plan.nodes import (
 from ..runtime import (faults as _faults, resilience as _res,
                        statistics as _stats, telemetry as _tel)
 from ..table import Column, Scalar, Table
-from . import caps as _caps, programs as _programs, tiering as _tiering
+from . import (aggregates as _aggs, caps as _caps, joins as _joins,
+               programs as _programs, tiering as _tiering)
 from .caps import _NeedsRecompile, _check_flags, _learned_caps  # noqa: F401
 from .identity import (Unsupported, _flatten_tables, _maybe_parameterize,
                        _program_name, program_key)
 from .programs import _Compiled, _cache  # noqa: F401
 from .rex.evaluate import evaluate_predicate, evaluate_rex
-from .semijoin import (_anti_keep, _exist_operands, _exists, _join_scope,
-                       _residual_exist_test)
+from .semijoin import _anti_keep, _residual_exist_test
 from .stage_exec import _execute_stage_graph, _partition_plan
 from .stages import heavy_count as _heavy_count, stage_budget
 from .tiering import inflight_background_compiles  # noqa: F401
+from .traced import _VT, ProgramFlags, read as _read_flags
 
 logger = logging.getLogger(__name__)
 
@@ -98,51 +105,6 @@ logger = logging.getLogger(__name__)
 # increments go through ``telemetry.inc`` (atomic), never ``stats[k] += 1``
 # (an unlocked read-modify-write).
 stats = _tel.CounterAlias()
-
-
-class _VT:
-    """A padded device table + row-validity mask (None = all rows valid).
-
-    ``weight`` is the PRE-compaction row count (defaults to the physical
-    row count): heuristics that pick sides by size — the INNER-join
-    probe/build choice — must see the logical stream size, or a compacted
-    fact side masquerades as small, becomes the build, and its duplicate
-    keys trip the unique-build fallback.
-
-    ``hash_joins`` is set on a stream compacted at a join's output: the
-    joins above it keep the hash table though their probe side is small
-    now (``_LogicalJoin`` has the reason).
-
-    ``load_order`` is set while the rows are still a scan's rows in the
-    order they were loaded (a project, a filter that only masks): a join
-    may then probe such a build side's key column itself
-    (``_join_hash_table``).  Whatever moves rows clears it."""
-
-    __slots__ = ("table", "valid", "weight", "hash_joins", "load_order")
-
-    def __init__(self, table: Table, valid: Optional[jax.Array],
-                 weight: Optional[int] = None, hash_joins: bool = False,
-                 load_order: bool = False):
-        self.table = table
-        self.valid = valid
-        self.weight = weight if weight is not None else table.num_rows
-        self.hash_joins = hash_joins
-        self.load_order = load_order
-
-    def carry(self, table: Table, valid: Optional[jax.Array]) -> "_VT":
-        """This stream after an operator that hands its rows on: what the
-        joins above decide by rides along."""
-        return _VT(table, valid, self.weight, self.hash_joins,
-                   self.load_order)
-
-    @property
-    def n(self) -> int:
-        return self.table.num_rows
-
-    def vmask(self) -> jax.Array:
-        if self.valid is None:
-            return jnp.ones(self.n, dtype=bool)
-        return self.valid
 
 
 #: The most rows at which a join traced for a TPU keeps its SORT
@@ -155,11 +117,11 @@ class _VT:
 #: group sort this strategy had until PR 27, 134 / - / 737.  A join sorts
 #: three times and TPC-H Q3 / Q5 / Q10 join
 #: two to five times at 1.5-6 M rows: 1127 s for Q3's program (PR 23), past
-#: any set-up.  The scatter formulations (``_join_hash_table``,
-#: ``_hashed_aggregate``) hold no sort and compile in seconds at any size;
-#: on the chip they pay a serialized scatter per build row and a gather
-#: per probe row instead: 186 ns a build row (Q3's trace on a v5e, PR 27:
-#: 306.6 ms under ``dsql.join_build`` for 1.65 M rows) where the merge
+#: any set-up.  The scatter formulations (``joins.hash_table``,
+#: ``aggregates.hashed_aggregate``) hold no sort and compile in seconds at
+#: any size; on the chip they pay a serialized scatter per build row and a
+#: gather per probe row instead: 186 ns a build row (Q3's trace on a v5e,
+#: PR 27: 306.6 ms under ``dsql.join_build`` for 1.65 M rows) where the merge
 #: join's build sort takes 20 (Q12: 30.2 ms for the same 1.5 M orders), so
 #: Q12 under the hash table would cost some 320 ms for its 68 and the
 #: merge join stays wherever its sorts compile.  The choice is made per
@@ -221,38 +183,17 @@ class _Tracer:
         self.context = context
         self.scan_tables = scan_tables
         self.caps = caps
-        self.fallback: List[jax.Array] = []      # device bools -> eager rerun
-        self.ngroups: List[jax.Array] = []        # device ints, order = walk
-        self.ngroup_caps: List[int] = []          # matching static caps
-        self.agg_sites: List[Tuple[int, bool, str]] = []  # (rows, hashed, tag)
+        # what the program tells the host: its flags and static counters
+        self.flags = ProgramFlags()
         self._agg_counter = 0
         self._cmp_counter = 0
         self._join_site_counter = 0
-        # rows the program's joins take in, probe + build of each: static
-        self.join_rows = 0
-        # rows its static-domain aggregates named to the limb kernel, and
-        # the distinct and the indicator rows the kernel sums for them:
-        # static, added by the kernel where it is the backend
-        self.limb_rows: Dict[str, int] = {}
-        # one device bool a hash-table join, in trace order: whether its
-        # table was direct-addressed, so its probe was ``_direct_probe``
-        self.direct_probes: List[jax.Array] = []
-        self.span_tables = 0    # those of them a ``span*`` hint sized: static
-        # the program's SEMI / ANTI joins and inlined scalar subqueries
-        self.semi_joins = self.scalar_subqueries = 0
         # id(node) -> its _VT (the plan outlives the trace, so an id stays
         # its node's): ``run`` traces a node once, and counts the
         # references it answered from here
         self._ran: Dict[int, _VT] = {}
-        self.shared_subplans = 0
-        # id(join) -> "ord<j>" (``statistics.join_tags``, set by _build),
-        # and per join that built no table and probed its build side's key
-        # column on a hint: (the hint's tag, the program's check of it,
-        # whether the column is dense, so that the probe is direct)
+        # id(join) -> "ord<j>" (``statistics.join_tags``, set by _build)
         self.join_tags: Dict[int, str] = {}
-        self.ordered: List[Tuple[str, jax.Array, bool]] = []
-        # per GROUP BY by runs (``_run_aggregate``): the check of its hint
-        self.run_groups: List[jax.Array] = []
         # filter nodes (by id) eligible for learned-capacity compaction —
         # computed by _compact_eligible over the whole plan before tracing
         self.compact_ok: set = set()
@@ -273,7 +214,7 @@ class _Tracer:
         vt = self.run(rex.plan)
         if vt.valid is not None or vt.n != 1:
             raise Unsupported("scalar subquery with runtime row count")
-        self.scalar_subqueries += 1
+        self.flags.scalar_subqueries += 1
         col = vt.table.columns[0]
         n = outer_table.num_rows
         d0 = col.data[0]
@@ -296,7 +237,7 @@ class _Tracer:
         TPC-H Q15's ``=`` are parts of one device array)."""
         vt = self._ran.get(id(rel))
         if vt is not None:
-            self.shared_subplans += 1
+            self.flags.shared_subplans += 1
             return vt
         m = getattr(self, "_" + type(rel).__name__, None)
         if m is None:
@@ -381,8 +322,8 @@ class _Tracer:
             self._cmp_counter += 1
         cap = self.caps.get(tag)
         if cap is None and after_join:
-            self._compact_site(jnp.sum(vt.vmask(), dtype=jnp.int64), n, n,
-                               tag)
+            self.flags.site(tag, n, False, n,
+                            jnp.sum(vt.vmask(), dtype=jnp.int64))
             return vt
         if cap is None:
             cap = 1 << max(int((max(n // 4, 1) - 1)).bit_length(), 10)
@@ -396,17 +337,9 @@ class _Tracer:
             cols = [c.take(idx) for c in vt.table.columns]
         # count > cap rows were silently dropped: the flags check raises
         # _NeedsRecompile before any result materializes
-        self._compact_site(count, cap, n, tag)
+        self.flags.site(tag, n, False, cap, count)
         return _VT(Table(list(vt.table.names), cols), row_valid,
                    weight=vt.weight, hash_joins=vt.hash_joins or after_join)
-
-    def _compact_site(self, count: jax.Array, cap: int, n: int,
-                      tag: str) -> None:
-        """The flags' entry of a site that counts exactly (a compaction, a
-        GROUP BY by runs): count, capacity, input rows (``_check_flags``)."""
-        self.ngroups.append(count)
-        self.ngroup_caps.append(cap)
-        self.agg_sites.append((n, False, tag))
 
     def _LogicalValues(self, rel: LogicalValues) -> _VT:
         from .rel.executor import _values
@@ -419,13 +352,15 @@ class _Tracer:
         out_names = [f.name for f in rel.schema]
 
         if not rel.group_keys:
-            for agg, f, col, fmask in self._agg_inputs(rel, src, []):
+            for agg, f, col, fmask in _aggs.agg_inputs(rel, src, [],
+                                                        self.flags):
                 out_cols.append(G.whole_table_aggregate(
                     agg.op, col, fmask, f.stype, n))
             return _VT(Table(out_names, out_cols), None)
 
         key_cols = [src.table.columns[i] for i in rel.group_keys]
-        static = self._static_domain_aggregate(rel, src, key_cols)
+        static = _aggs.static_domain_aggregate(rel, src, key_cols,
+                                               self.flags)
         if static is not None:
             return static
 
@@ -460,273 +395,10 @@ class _Tracer:
         # the static domain's dsql.groupby_limbs)
         with jax.named_scope("dsql.groupby_sorted"):
             if by_runs:
-                return self._run_aggregate(rel, src, key, cap, tag)
-            return self._hashed_aggregate(rel, src, key_cols, cap, tag)
-
-    def _run_aggregate(self, rel, src: _VT, key: Column, cap: int,
-                       tag: str) -> _VT:
-        """GROUP BY a key column in load order (ops/groupby.py ``key_runs``):
-        no table, no scatter; the groups in ``_hashed_aggregate``'s order
-        (first occurrence), and the check of the hint among the flags
-        (``caps._check_ordered``: a refuted one never answers)."""
-        runs = G.key_runs(key.data, cap)
-        self.run_groups.append(runs.ok)
-        self._compact_site(runs.num_groups, cap, src.n, tag)
-        cols = [key.take(jnp.minimum(runs.starts, src.n - 1))]
-        for agg, f, col, fmask in self._agg_inputs(rel, src, [key]):
-            cols.append(G.run_aggregate(agg.op, col, runs, f.stype, fmask))
-        return _VT(Table([f.name for f in rel.schema], cols),
-                   jnp.arange(cap) < runs.num_groups)
-
-    def _agg_inputs(self, rel, src: _VT, key_cols: List[Column]):
-        """(aggregate, output field, argument column, row mask) of each
-        aggregate of ``rel``: the mask is its FILTER, the rows' validity
-        and, for a DISTINCT one, the first occurrences of its argument."""
-        for j, agg in enumerate(rel.aggs):
-            fmask = self._agg_filter(agg, src)
-            if agg.distinct and agg.op not in ("MIN", "MAX"):
-                keep = self._distinct_keep(key_cols, agg, src)
-                fmask = keep if fmask is None else (fmask & keep)
-            yield (agg, rel.schema[len(rel.group_keys) + j],
-                   src.table.columns[agg.args[0]] if agg.args else None,
-                   fmask)
-
-    def _hashed_aggregate(self, rel, src: _VT, key_cols: List[Column],
-                          cap: int, tag: str) -> _VT:
-        """General GROUP BY, on every backend (the group sort the TPU strategy
-        had compiled for minutes above some tens of thousands of rows,
-        ``SORT_ROWS_MAX``, and went in PR 27): hash-table group codes in
-        original row order (no sort), then each aggregate is a segment_* scatter keyed on
-        the dense codes — the same kernels the eager path uses
-        (ops/groupby.py segment_aggregate), so semantics (exact decimals,
-        NULL rules, string MIN/MAX ranks) are shared by construction.
-        Invalid rows ride the trash segment ``cap``, sliced off afterwards.
-        """
-        n = src.n
-        out_names = [f.name for f in rel.schema]
-        codes, first_rows, num_groups, coll = _group_hashed_codes(
-            key_cols, src.valid, cap)
-        self.fallback.append(coll)
-        self.ngroups.append(num_groups)
-        self.ngroup_caps.append(cap)
-        self.agg_sites.append((n, True, tag))
-
-        out_cols: List[Column] = []
-        for ki in rel.group_keys:
-            out_cols.append(src.table.columns[ki].take(first_rows))
-
-        def _trim(col: Column) -> Column:
-            return Column(col.data[:cap], col.stype,
-                          None if col.mask is None else col.mask[:cap],
-                          col.dictionary)
-
-        for agg, f, col, fmask in self._agg_inputs(rel, src, key_cols):
-            out_cols.append(_trim(G.segment_aggregate(
-                agg.op, col, codes, cap + 1, f.stype, filter_mask=fmask,
-                n_rows=n)))
-        row_valid = jnp.arange(cap) < num_groups
-        return _VT(Table(out_names, out_cols), row_valid)
-
-    def _static_domain_aggregate(self, rel, src: _VT, key_cols
-                                 ) -> Optional[_VT]:
-        """GROUP BY over a statically-enumerable key domain (dict-encoded
-        strings / booleans): codes come straight from dictionary ranks — no
-        sort, no scatter, no capacity escalation — and all reductions ride
-        the MXU one-hot kernel (ops/pallas_kernels.py) on TPU. Key output
-        columns are decoded from the slot index, never gathered from the
-        data. The kernel is named a value row and a count row an aggregate
-        and sums each distinct one once (``rows_of``); what it reads of
-        the data: a column once where the rows exist whole, and once more
-        for a float row's largest magnitude where they are built a slab
-        at a time. Returns None when the shape doesn't fit (non-MXU
-        aggregates, non-enumerable keys, huge domains).
-
-        This is the TPC-H Q1 shape: GROUP BY returnflag, linestatus.
-        """
-        from ..ops import pallas_kernels as pk
-        static = _try_static_codes(key_cols)
-        if static is None:
-            return None
-        codes, domain, key_meta = static
-        if domain > 256:
-            return None
-        for agg in rel.aggs:
-            col = src.table.columns[agg.args[0]] if agg.args else None
-            if agg.op not in ("SUM", "$SUM0", "AVG", "COUNT") or agg.distinct:
-                return None
-            if col is not None and col.stype.is_string:
-                return None
-            if col is not None and col.data.dtype == jnp.bool_:
-                return None
-
-        n = src.n
-        rv = src.valid
-        kmask = jnp.ones(n, bool) if rv is None else rv
-
-        out_names = [f.name for f in rel.schema]
-        out_cols: List[Column] = _decode_static_keys(key_cols, key_meta,
-                                                     domain)
-
-        from ..types import exact_decimal_scale
-
-        masks = {}  # full-length masks, one a (FILTER, column's NULLs)
-
-        def mask_of(agg, col):
-            """The rows an aggregate counts, the same object for the same
-            rows: the row mask itself where the column has no NULLs and
-            the aggregate no FILTER."""
-            nulls = None if col is None else col.mask
-            key = (agg.filter_arg, None if nulls is None else id(nulls))
-            if key not in masks:
-                rows = kmask if agg.filter_arg is None \
-                    else self._agg_filter(agg, src)
-                masks[key] = rows if nulls is None else (nulls & rows)
-            return masks[key]
-
-        def rows_of(take, flags: bool):
-            """The kernel's value rows, their classes and the aggregates'
-            slots, built from ``take`` of every full-length input: the
-            identity for the rows whole, a slab's slice inside the limb
-            kernel's loop.  A row is made once and named wherever an
-            aggregate reads it (one value row a (column, factor, mask),
-            one count row a mask), each in the dtype it has: the kernel
-            sums a row once however often it is named, and decides from
-            class and dtype what a row can hold (``pk._row_plan``).
-            ``flags``: append the int rows' magnitude checks, which read
-            whole rows."""
-            taken = {}  # id(full-length input) -> (it, its slice)
-            values = {}  # (id(column data), factor, id(mask)) -> value row
-
-            def part(whole):
-                if id(whole) not in taken:
-                    taken[id(whole)] = (whole, take(whole))
-                return taken[id(whole)][1]
-
-            mxu_rows = [part(kmask)]  # row 0: occupancy counts
-            row_classes = ["unit"]  # per-row grid for the limb MXU kernel
-            slots = []
-            for j, agg in enumerate(rel.aggs):
-                f = rel.schema[len(rel.group_keys) + j]
-                col = src.table.columns[agg.args[0]] if agg.args else None
-                full_mask = mask_of(agg, col)
-                vmask = part(full_mask)
-                # exact decimal money math rides the MXU too: integer-valued
-                # f64 matmuls are exact below 2^53 (SF100 cents sums ~6e15)
-                factor = 1.0
-                if col is not None and agg.op in ("SUM", "$SUM0", "AVG"):
-                    ds = exact_decimal_scale(col.stype)
-                    if ds is not None:
-                        factor = 10.0 ** ds
-                if col is None or agg.op == "COUNT":
-                    # COUNT(col): only the 0/1 count row is ever read — ship
-                    # it in the value slot too; no 2^53 magnitude guard (sums
-                    # are never used, so a huge BIGINT column must not fall
-                    # back)
-                    vrow = vmask
-                    rc = "unit"
-                else:
-                    is_int = factor != 1.0 or jnp.issubdtype(col.data.dtype,
-                                                             jnp.integer)
-                    rc = "int" if is_int else "float"
-                    key = (id(col.data), factor, id(full_mask))
-                    if key not in values:
-                        data = part(col.data)
-                        if factor != 1.0:
-                            data = jnp.round(
-                                data.astype(jnp.float64) * factor)
-                        elif not is_int:
-                            data = data.astype(jnp.float64)
-                        # an integer column stays one: the kernel widens
-                        # it, and knows by its dtype that it holds no NaN
-                        values[key] = jnp.where(vmask, data,
-                                                jnp.zeros((), data.dtype))
-                        if is_int and flags:
-                            # the int grid is bit-exact only below 2^53;
-                            # decimal scales are pre-gated (p<=15) but a
-                            # raw BIGINT column's magnitude is
-                            # data-dependent (initial= keeps the trace
-                            # alive on 0-row inputs)
-                            self.fallback.append(jnp.max(
-                                jnp.abs(values[key].astype(jnp.float64)),
-                                initial=0.0) >= 2.0 ** 53)
-                    vrow = values[key]
-                slots.append((j, agg, f, len(mxu_rows), factor))
-                mxu_rows.append(vrow)
-                row_classes.append(rc)
-                mxu_rows.append(vmask)
-                row_classes.append("unit")
-            return mxu_rows, row_classes, slots
-
-        mxu_rows, row_classes, slots = rows_of(lambda whole: whole, True)
-        with jax.named_scope("dsql.groupby_limbs"):
-            if pk.stack_fits(mxu_rows, row_classes, n):
-                red = pk.segmented_sums_dispatch(mxu_rows, codes, kmask,
-                                                 domain,
-                                                 row_classes=row_classes,
-                                                 counts=self.limb_rows)
-            else:
-                # rows too many and too long to exist at once (TPC-H Q1 at
-                # SF10: 17 named rows of 60 M, 5 float rows with their 15
-                # indicator rows among the distinct ones): the kernel's
-                # loop builds each slab's
-                red = pk.segmented_sums_slabwise(
-                    lambda take: rows_of(take, False)[0], mxu_rows, codes,
-                    kmask, domain, row_classes, self.limb_rows)
-        occupancy = red[0] > 0
-
-        from ..types import physical_dtype
-        results: List[Optional[Column]] = [None] * len(rel.aggs)
-        for j, agg, f, row0, factor in slots:
-            sums, counts = red[row0], red[row0 + 1]
-            has = counts > 0
-            if agg.op == "COUNT":
-                results[j] = Column(counts.astype(jnp.int64), f.stype, None)
-            elif agg.op in ("$SUM0", "SUM"):
-                out = sums
-                if factor != 1.0:
-                    # MXU sums of scaled decimals are integer-valued f64
-                    # (exact below 2^53): unscale via the exact-quotient
-                    # path, not a reciprocal-rewritten division
-                    from ..ops.kernels import decimal_unscale
-                    out = decimal_unscale(
-                        sums.astype(jnp.int64),
-                        int(round(math.log10(factor))))
-                results[j] = Column(
-                    out.astype(physical_dtype(f.stype)), f.stype,
-                    None if agg.op == "$SUM0" else has)
-            else:  # AVG
-                results[j] = Column(sums / (jnp.maximum(counts, 1.0) * factor),
-                                    f.stype, has)
-        out_cols.extend(results)
-        return _VT(Table(out_names, out_cols), occupancy)
-
-    def _first_occurrence_keep(self, cols: List[Column],
-                               row_valid: Optional[jax.Array]) -> jax.Array:
-        """Row-space mask: True on the first valid row of each distinct
-        column-tuple (the shared dedup primitive for UNION DISTINCT and
-        DISTINCT aggregates). Appends the factorize collision flag."""
-        n = len(cols[0])
-        # codes per input row from the hash table, no sort.  No ngroups
-        # escalation here (a capacity of n is the worst case), so an
-        # unresolved table folds into the collision flag and reruns eager
-        codes, first, ng, coll = _group_hashed_codes(cols, row_valid, n)
-        self.fallback.append(coll | (ng > n))
-        return jnp.clip(first, 0, max(n - 1, 0))[codes] == jnp.arange(n)
-
-    def _distinct_keep(self, key_cols: List[Column], agg, src: _VT
-                       ) -> jax.Array:
-        """First occurrence of each (group keys, argument value) combo."""
-        return self._first_occurrence_keep(
-            list(key_cols) + [src.table.columns[agg.args[0]]], src.valid)
-
-    def _agg_filter(self, agg, src: _VT):
-        """Combined FILTER-clause + row-validity mask (None = all rows)."""
-        fmask = src.valid
-        if agg.filter_arg is not None:
-            fc = src.table.columns[agg.filter_arg]
-            fm = fc.data.astype(bool) & fc.valid_mask()
-            fmask = fm if fmask is None else (fmask & fm)
-        return fmask
+                return _aggs.run_aggregate(rel, src, key, cap, tag,
+                                           self.flags)
+            return _aggs.hashed_aggregate(rel, src, key_cols, cap, tag,
+                                          self.flags)
 
     def _LogicalSort(self, rel: LogicalSort) -> _VT:
         src = self.run(rel.input)
@@ -819,8 +491,8 @@ class _Tracer:
         if rel.all:
             return out
         # UNION DISTINCT: keep first occurrence of each distinct row
-        keep = self._first_occurrence_keep(list(out.table.columns),
-                                           out.valid)
+        keep = _aggs.first_occurrence_keep(list(out.table.columns),
+                                           out.valid, self.flags)
         return _VT(out.table, keep & out.vmask())
 
     def _LogicalJoin(self, rel: LogicalJoin) -> _VT:
@@ -868,8 +540,8 @@ class _Tracer:
         bvalid = _keys_valid(bk_cols, build.valid)
         ph = _hash_parts(pparts, pvalid)
         bh = _hash_parts(bparts, bvalid)
-        self.join_rows += probe.n + build.n
-        self.semi_joins += jt in ("SEMI", "ANTI")
+        self.flags.join_rows += probe.n + build.n
+        self.flags.semi_joins += jt in ("SEMI", "ANTI")
 
         # a side compacted at a join's output is small because the plan
         # chains joins under a hash-table join: were each join above to
@@ -880,25 +552,28 @@ class _Tracer:
         # PERF.md, PR 28), and on the device either is nearly free at
         # these sizes (build sides of 5 and 25 rows)
         hash_joins = probe.hash_joins or build.hash_joins
+        sides = (jt, probe, build, pparts, bparts, pvalid, ph, bh)
         if _sort_formulation(probe.n) and not hash_joins:
-            # sorted-probe join: one 2-channel build-side argsort + binary
-            # search + row-id gathers, regardless of build width.  Two of its
-            # three sorts see every probe row, so the probe's rows decide
-            # (SORT_ROWS_MAX)
-            match, gathered = self._join_merge(jt, probe, build, pparts,
-                                               bparts, pvalid, ph, bh,
-                                               exist_test)
+            # the merge join: two of its three sorts see every probe row,
+            # so the probe's rows decide (SORT_ROWS_MAX)
+            if exist_test is None:
+                match, gathered = _joins.merge(*sides, self.flags)
+            else:
+                match, gathered = _joins.merge_exists(*sides, exist_test,
+                                                      self.flags)
+        elif (ordered := self._ordered_hint(
+                rel, probe_is_left, probe, build, bk_cols, bparts,
+                exist_test)) is not None:
+            # the build side's key column is its own index
+            match, gathered = _joins.ordered(
+                jt, probe, build, pparts[0][1], bparts[0][1], pvalid,
+                *ordered, self.flags)
         else:
-            # CPU/GPU: scatters and gathers cost ~1 ms where any 600k-row
-            # sort costs 350-750 ms — hash-table join, no sort of either
-            # side.  On a TPU above SORT_ROWS_MAX probe rows too: there the
-            # sorts are what does not compile
-            match, gathered = self._join_hash_table(
-                jt, probe, build, pparts, bparts, pvalid, ph, bh, exist_test,
-                self._ordered_hint(rel, probe_is_left, probe, build, bk_cols,
-                                   bparts, exist_test),
-                self.caps.get(_stats.span_tag(
-                    self._build_tag(rel, probe_is_left)), 0))
+            # off the TPU strategy nothing sorts; under it above
+            # SORT_ROWS_MAX probe rows the sorts are what does not compile
+            match, gathered = _joins.hash_table(
+                *sides, exist_test, self.caps.get(_stats.span_tag(
+                    self._build_tag(rel, probe_is_left)), 0), self.flags)
 
         def _out(table: Table, valid) -> _VT:
             return _VT(table, valid, weight=probe.weight,
@@ -944,187 +619,6 @@ class _Tracer:
         gathered = [c.with_mask(c.valid_mask() & match) for c in gathered]
         return _out(_pairs(gathered), probe.valid)
 
-    def _append_join_flags(self, jt, adj: jax.Array, raw_diffs) -> None:
-        """Shared fallback policy for both join strategies. ``adj`` marks
-        adjacent equal-hash build pairs in build-hash-sorted order;
-        ``raw_diffs`` are the matching adjacent raw-key inequality masks.
-        INNER/LEFT/RIGHT require a unique build key (adjacency of any kind
-        covers hash collisions too); SEMI/ANTI tolerate duplicates, so only
-        a genuine collision (equal hash, different raw key) is fatal."""
-        if jt in ("INNER", "LEFT", "RIGHT"):
-            self.fallback.append(adj.any())
-        else:
-            coll = jnp.zeros((), dtype=bool)
-            for d in raw_diffs:
-                coll = coll | (adj & d).any()
-            self.fallback.append(coll)
-
-    def _join_merge(self, jt, probe: _VT, build: _VT, pparts, bparts,
-                    pvalid: jax.Array, ph: jax.Array, bh: jax.Array,
-                    exist_test=None):
-        """Sorted-probe join, the TPU strategy: sort ONLY the build side's
-        hashes (2-channel argsort at nb rows), locate each probe hash with
-        ``searchsorted(method='sort')`` — ONE (nb+npr)-row 2-channel sort.
-        The scan method looked cheaper on paper (log2(nb) HLO ops), but on
-        TPU each of its ~21 iterations is an npr-row gather: 2.66 s at
-        SF-1 Q12 shapes vs ~40 ms for the sort method (measured r4, this
-        chip) — the scan was the whole reason join-heavy queries lost to
-        pandas before it.  Raw keys verify via row-id gathers.
-
-        SEMI/ANTI residual exist-tests use the payload variant
-        (_join_merge_payload): per-run build aggregates need the sorted
-        x-value stream, and those plans carry no build columns, so their
-        channel count stays small (a payload channel a build column is what
-        XLA:TPU does not compile at SF-1 shapes: a 13-channel sort 153 s, a
-        2-channel associative_scan over 15 min).  Returns (match over probe
-        rows, fetched build columns or None for SEMI/ANTI)."""
-        if exist_test is not None:
-            return self._join_merge_payload(jt, probe, build, pparts,
-                                            bparts, pvalid, ph, bh,
-                                            exist_test)
-        nb, npr = build.n, probe.n
-        if nb == 0:
-            # a gather from a 0-row build would fail at trace time; an
-            # empty build matches nothing (x NOT IN (empty) handled by the
-            # caller's null-aware logic over this all-false match)
-            self.fallback.append(jnp.zeros((), bool))
-            match = jnp.zeros(npr, dtype=bool)
-            if jt in ("SEMI", "ANTI"):
-                return match, None
-            # zero-filled columns, masked by the all-false match downstream
-            # (same values the payload formulation's concat-of-zeros carried)
-            return match, [
-                Column(jnp.zeros(npr, dtype=c0.data.dtype), c0.stype,
-                       None if c0.mask is None else jnp.zeros(npr, bool),
-                       c0.dictionary)
-                for c0 in build.table.columns]
-        with jax.named_scope(_join_scope(jt, "build")):
-            order = jnp.argsort(bh)
-            bh_sorted = bh[order]
-            # duplicate build keys / hash collisions appear as adjacent
-            # equal hashes in sorted order (same flag policy as every
-            # strategy)
-            adj = ((bh_sorted[1:] == bh_sorted[:-1])
-                   & (bh_sorted[1:] != _U64_MAX))
-            raws_sorted = [braw[order] for _, braw in bparts]
-            self._append_join_flags(
-                jt, adj, [rs[1:] != rs[:-1] for rs in raws_sorted])
-
-        with jax.named_scope(_join_scope(jt, "probe")):
-            pos = jnp.searchsorted(bh_sorted, ph, side="left", method="sort")
-            in_range = pos < nb
-            pos_c = jnp.minimum(pos, nb - 1)
-            cand = order[pos_c]
-            match = in_range & pvalid & (bh_sorted[pos_c] == ph)
-            for (_, praw), (_, braw) in zip(pparts, bparts):
-                match = match & (praw == braw[cand])
-            if jt in ("SEMI", "ANTI"):
-                return match, None
-            return match, [c0.take(cand) for c0 in build.table.columns]
-
-    def _join_merge_payload(self, jt, probe: _VT, build: _VT, pparts,
-                            bparts, pvalid: jax.Array, ph: jax.Array,
-                            bh: jax.Array, exist_test=None):
-        """Payload-channel merge join (r1/r2 formulation), kept for the
-        SEMI/ANTI residual exist-test path: per-run build aggregates need
-        the sorted x-value stream and segmented scans. Returns (match over
-        probe rows, carried build columns or None for SEMI/ANTI)."""
-        nb, npr = build.n, probe.n
-        m = nb + npr
-        h_m = jnp.concatenate([bh, ph])
-        flag_b = jnp.concatenate([jnp.ones(nb, bool), jnp.zeros(npr, bool)])
-        idt = jnp.int32 if m < 2**31 else jnp.int64
-        iota_m = jnp.arange(m, dtype=idt)
-        raw_ch = [jnp.concatenate([braw, praw])
-                  for (_, braw), (_, praw) in zip(bparts, pparts)]
-        need_cols = jt in ("INNER", "LEFT", "RIGHT")
-        col_ch: List[jax.Array] = []
-        if need_cols:
-            for c0 in build.table.columns:
-                col_ch.append(jnp.concatenate(
-                    [c0.data, jnp.zeros(npr, dtype=c0.data.dtype)]))
-                if c0.mask is not None:
-                    col_ch.append(jnp.concatenate(
-                        [c0.mask, jnp.zeros(npr, dtype=bool)]))
-
-        res_ch: List[jax.Array] = []
-        if exist_test is not None:
-            _, x_col, y_col = exist_test
-            xd, yd = _exist_operands(x_col, y_col)
-            res_ch = [
-                jnp.concatenate([xd, jnp.zeros(npr, dtype=jnp.int64)]),
-                jnp.concatenate([x_col.valid_mask(),
-                                 jnp.zeros(npr, dtype=bool)]),
-                jnp.concatenate([jnp.zeros(nb, dtype=jnp.int64), yd]),
-                jnp.concatenate([jnp.zeros(nb, dtype=bool),
-                                 y_col.valid_mask()]),
-            ]
-
-        outs = jax.lax.sort((h_m, flag_b, iota_m, *raw_ch, *col_ch,
-                             *res_ch),
-                            num_keys=1, is_stable=True)
-        hs, fbs, iotas = outs[0], outs[1], outs[2]
-        raws = outs[3:3 + len(raw_ch)]
-        ncol = len(col_ch)
-        colss = outs[3 + len(raw_ch): 3 + len(raw_ch) + ncol]
-        ress = outs[3 + len(raw_ch) + ncol:]
-
-        # equal-hash build rows are contiguous (stable sort puts build rows
-        # before same-hash probe rows), so duplicates/collisions show up as
-        # adjacent build pairs — no scan needed for the flags
-        adj = fbs[1:] & fbs[:-1] & (hs[1:] == hs[:-1]) & (hs[1:] != _U64_MAX)
-        self._append_join_flags(jt, adj, [r[1:] != r[:-1] for r in raws])
-
-        def carry_op(a, b):
-            take = b[0]
-            return tuple([a[0] | b[0]]
-                         + [jnp.where(take, bv, av)
-                            for av, bv in zip(a[1:], b[1:])])
-
-        carried = jax.lax.associative_scan(
-            carry_op, (fbs, *raws, *colss))
-        has_b = carried[0]
-        c_raws = carried[1:1 + len(raws)]
-        c_cols = carried[1 + len(raws):]
-
-        # a probe row matches iff the last build row at-or-before it has the
-        # same raw key (equal raw => equal hash, and everything between them
-        # in hash order then shares that hash)
-        match_s = (~fbs) & has_b
-        for cr, r in zip(c_raws, raws):
-            match_s = match_s & (cr == r)
-
-        if exist_test is not None:
-            # per-hash-run build aggregates decide "exists build x OP y":
-            # all build rows of a run precede its probe rows (stable sort),
-            # so a probe's inclusive segmented scan covers the whole run
-            from ..ops.window import segmented_cumsum, segmented_scan
-            op_t = exist_test[0]
-            xs, xvs, ys, yvs = ress
-            run_start = jnp.concatenate(
-                [jnp.ones(1, dtype=bool), hs[1:] != hs[:-1]])
-            xv = xvs & fbs
-            cnt = segmented_cumsum(xv.astype(jnp.int64), run_start)
-            mn = segmented_scan(jnp.where(xv, xs, jnp.iinfo(jnp.int64).max),
-                                run_start, jnp.minimum)
-            mx = segmented_scan(jnp.where(xv, xs, jnp.iinfo(jnp.int64).min),
-                                run_start, jnp.maximum)
-            match_s = match_s & (cnt > 0) & _exists(op_t, mn, mx, ys) & yvs
-
-        un = jax.lax.sort((iotas, match_s, *c_cols), num_keys=1)
-        match = un[1][nb:] & pvalid
-        ub_cols = [o[nb:] for o in un[2:]]
-
-        if not need_cols:
-            return match, None
-        gathered: List[Column] = []
-        it = iter(ub_cols)
-        for c0 in build.table.columns:
-            data = next(it)
-            mask = next(it) if c0.mask is not None else None
-            gathered.append(Column(data, c0.stype, mask, c0.dictionary))
-        return match, gathered
-
     def _ordered_hint(self, rel, probe_is_left: bool, probe: _VT, build: _VT,
                       bk_cols: List[Column], bparts, exist_test):
         """(tag, level) where a hash-table join may probe its build side's
@@ -1162,183 +656,6 @@ class _Tracer:
         """The tag of ``rel``'s build side (``ord<j>l`` / ``r``), or ""."""
         tag = self.join_tags.get(id(rel))
         return tag + ("r" if probe_is_left else "l") if tag else ""
-
-    def _join_ordered(self, jt, probe: _VT, build: _VT, praw: jax.Array,
-                      braw: jax.Array, pvalid: jax.Array, tag: str,
-                      level: int):
-        """The ordered probe (kernels in ops/hashing.py): the build side's
-        key column is strictly increasing in row order, so the row of a
-        key is found in the column itself and nothing is built.  A strictly
-        increasing key is unique, so the table's ``dup`` / ``unresolved`` /
-        ``raw_mismatch`` flags have nothing to say; what there is to check
-        is the hint, one elementwise pass under ``dsql.join_build`` into
-        the flags (``caps._check_ordered``: a refuted hint recompiles with
-        the table, and never answers)."""
-        dense = level == _stats.ORDERED_DENSE
-        narrow = level == _stats.ORDERED_NARROW
-        k = braw.astype(jnp.int64)
-        raw = praw.astype(jnp.int64)
-        with jax.named_scope(_join_scope(jt, "build")):
-            lo, hi, ok = _ordered_check(k, dense, narrow)
-        self.ordered.append((tag, ok, dense))
-        with jax.named_scope(_join_scope(jt, "probe")):
-            if dense:
-                cand, found = _ordered_dense(lo, hi, raw)
-            else:
-                cand, found = _ordered_search(k, lo, hi, raw, narrow)
-            match = found & pvalid
-            if build.valid is not None:
-                match = match & build.valid[cand]
-        if jt in ("SEMI", "ANTI"):
-            return match, None
-        return match, [c.take(cand) for c in build.table.columns]
-
-    def _join_hash_table(self, jt, probe: _VT, build: _VT, pparts, bparts,
-                         pvalid: jax.Array, ph: jax.Array, bh: jax.Array,
-                         exist_test=None, ordered=None, span: int = 0):
-        """Open-addressing hash join: insert build row ids into a power-of-2
-        table (empty-slot claim rounds, see _hash_table_insert), probe with
-        one gather chain per round actually used; where the data lets the
-        table be direct-addressed, round 0 is one 32-bit gather and the only
-        round (``_direct_probe``).  Verification always compares raw key
-        parts, so lossy hashes only add collisions — caught by the flags and
-        rerun eager.  SEMI/ANTI residual exist-tests aggregate (count, min,
-        max) per slot with cheap scatters, which the sorted-gather strategy
-        could not express.
-        ``ordered`` (``_ordered_hint``): the build side's key column is its
-        own index, and the join inserts nothing (``_join_ordered``).
-        ``span``: the class of the build key's ingest span (a ``span*`` hint:
-        ``statistics.key_span_hints``), by which ``_hash_table_size`` may give
-        one integer key a table that holds it; ``_direct_info`` checks the fit.
-        """
-        if ordered is not None:
-            return self._join_ordered(jt, probe, build, pparts[0][1],
-                                      bparts[0][1], pvalid, *ordered)
-        nb, npr = build.n, probe.n
-        bvalid = bh != _U64_MAX          # _hash_parts marks invalid keys
-        # single integer-raw key (ints, dates, unified string codes): the
-        # _mix64 rehash is a BIJECTION, so hash equality IS key equality —
-        # no raw verification, no collision flag — and the raw values
-        # enable the direct-address round-0 fast path
-        bij = (len(bparts) == 1
-               and jnp.issubdtype(bparts[0][1].dtype, jnp.integer))
-        size = _hash_table_size(nb, span if bij else 0, npr)
-        self.span_tables += size != _hash_table_size(nb)
-        direct_b = direct_p = None
-        combo_ok = None
-        if bij:
-            braw1 = bparts[0][1].astype(jnp.int64)
-            praw1 = pparts[0][1].astype(jnp.int64)
-            bh = _mix64(braw1.astype(jnp.uint64))   # clamp-free, clean
-            ph = _mix64(praw1.astype(jnp.uint64))
-            direct_b = _direct_info(braw1, bvalid, size)
-            direct_p = direct_b._replace(raw=praw1)
-        else:
-            # multi-part keys: mixed-radix combination over the UNION of
-            # both sides' runtime ranges — injective where the radix
-            # product fits (combo_ok), giving a collision-free hash and
-            # direct addressing when it also fits the table
-            combo = _combined_int_key(
-                [[(braw, None, bvalid), (praw, None, pvalid)]
-                 for (_, braw), (_, praw) in zip(bparts, pparts)])
-            if combo is not None:
-                (bkey, pkey), combo_ok, span_prod = combo
-                bh = jnp.where(combo_ok,
-                               _mix64(bkey.astype(jnp.uint64)), bh)
-                ph = jnp.where(combo_ok,
-                               _mix64(pkey.astype(jnp.uint64)), ph)
-                direct_b = _combined_direct(bkey, combo_ok, span_prod, size)
-                direct_p = direct_b._replace(raw=pkey)
-        with jax.named_scope(_join_scope(jt, "build")):
-            slot, resident, resolved, table, rounds = _hash_table_insert(
-                bh, bvalid, size, direct_b)
-            rowtab = _row_id_table(table, nb)
-
-        raw_mismatch = jnp.zeros((), bool)
-        if not bij:
-            rc0 = jnp.clip(resident, 0, nb - 1)
-            for _, braw in bparts:
-                raw_mismatch = raw_mismatch | (resolved
-                                               & (braw[rc0] != braw)).any()
-            if combo_ok is not None:
-                # injective combined keys cannot collide; the raw check
-                # only matters where the combination overflowed
-                raw_mismatch = raw_mismatch & ~combo_ok
-        unresolved = (bvalid & ~resolved).any()
-        if jt in ("INNER", "LEFT", "RIGHT"):
-            # these require a unique build key (same policy as the sort
-            # strategies): any second row of a key resolves to a foreign
-            # resident
-            dup = (resolved
-                   & (resident != jnp.arange(nb, dtype=resident.dtype))).any()
-            self.fallback.append(raw_mismatch | dup | unresolved)
-        else:
-            self.fallback.append(raw_mismatch | unresolved)
-
-        # probe: same slot sequence; a key resident at round k implies its
-        # rounds 0..k slots are all occupied, so scanning the rounds the
-        # insert used and taking the first equal-hash resident is complete
-        nb32 = jnp.int32(nb)
-
-        def probe_body(st):
-            k, cand = st
-            s_k = _slot_at_round(ph, k, size, direct_p)
-            r = rowtab[s_k]
-            hit = (r != nb32) & (bh[jnp.clip(r, 0, nb32 - 1)] == ph)
-            cand = jnp.where((cand == nb32) & hit, r, cand)
-            return k + 1, cand
-
-        def probe_cond(st):
-            k, _ = st
-            return k < rounds
-
-        with jax.named_scope(_join_scope(jt, "probe")):
-            # a direct-addressed insert ends after round 0, which is peeled
-            # here, so the loop below runs no round at all; any other table
-            # discards the peeled candidates and loops from round 0
-            if direct_p is None:
-                direct = jnp.zeros((), bool)
-                cand0 = jnp.full(npr, nb32)
-            else:
-                direct = direct_p.fits
-                cand0 = _direct_probe(rowtab, direct_p, nb)
-            _, cand = jax.lax.while_loop(
-                probe_cond, probe_body, (direct.astype(jnp.int32), cand0))
-        self.direct_probes.append(direct)
-        found = cand < nb32
-        cc = jnp.clip(cand, 0, nb - 1)
-        match = found & pvalid
-        if not bij:
-            raw_eq = jnp.ones(npr, dtype=bool)
-            for (_, praw), (_, braw) in zip(pparts, bparts):
-                raw_eq = raw_eq & (praw == braw[cc])
-            if combo_ok is not None:
-                # hash equality is key equality where the combination held
-                match = match & (combo_ok | raw_eq)
-            else:
-                match = match & raw_eq
-
-        if exist_test is not None:
-            # per-slot build aggregates decide "exists build x OP y"
-            op_t, x_col, y_col = exist_test
-            xd, yd = _exist_operands(x_col, y_col)
-            # aggregates are indexed by the group's RESIDENT row id (dense
-            # in [0, nb)), not by table slot: nb-sized arrays instead of
-            # table-sized ones, and the probe's candidate IS the resident
-            xv = resolved & x_col.valid_mask()
-            idx = jnp.where(xv, resident, nb)
-            i64 = jnp.iinfo(jnp.int64)
-            cnt = jnp.zeros(nb, jnp.int64).at[idx].add(1, mode="drop")
-            mn = (jnp.full(nb, i64.max, jnp.int64)
-                  .at[idx].min(xd, mode="drop"))
-            mx = (jnp.full(nb, i64.min, jnp.int64)
-                  .at[idx].max(xd, mode="drop"))
-            match = (match & (cnt[cc] > 0)
-                     & _exists(op_t, mn[cc], mx[cc], yd) & y_col.valid_mask())
-
-        if jt in ("SEMI", "ANTI"):
-            return match, None
-        return match, [c.take(cc) for c in build.table.columns]
 
 
 # ---------------------------------------------------------------------------
@@ -1395,10 +712,9 @@ def _build(plan: RelNode, context, scans, caps: Dict[str, int], key,
             base = len(flat) - len(params)
             tr.param_values = {id(p): flat[base + j]
                                for j, p in enumerate(params)}
-        if _strategy_on_tpu() \
-                and os.environ.get("DSQL_COMPACT", "1") != "0":
-            # TPU only: off-TPU the hash kernels already cost O(valid rows)
-            # and gathers/scatters are ~1 ms — compaction buys nothing there
+        if _strategy_on_tpu():
+            # off the TPU strategy no operator sorts, and the hash kernels
+            # cost by the rows set, not by the rows there
             tr.compact_ok = _compact_eligible(plan)
         tr.join_tags = _stats.join_tags(plan)
         out = tr.run(plan)
@@ -1407,35 +723,13 @@ def _build(plan: RelNode, context, scans, caps: Dict[str, int], key,
             count = jnp.int64(n)
         else:
             count = jnp.sum(out.valid.astype(jnp.int64))
-        fb = jnp.zeros((), dtype=bool)
-        for f in tr.fallback:
-            fb = fb | f
-        # after the sites, one entry an ordered probe and a GROUP BY by runs:
-        # its hint refuted (``_check_ordered``).  The tail is read by count
-        # from the END (``_materialize``): ``_check_flags``' part stays put
-        flags = jnp.stack([fb.astype(jnp.int64), count]
-                          + [g.astype(jnp.int64) for g in
-                             tr.ngroups + [~ok for _, ok, _ in tr.ordered]
-                             + [~ok for ok in tr.run_groups]
-                             + tr.direct_probes])
         meta["names"] = list(out.table.names)
         meta["cols"] = [(c.stype, c.mask is not None, c.dictionary)
                         for c in out.table.columns]
         meta["has_valid"] = out.valid is not None
-        meta["ngroup_caps"] = list(tr.ngroup_caps)
-        meta["agg_sites"] = list(tr.agg_sites)
-        meta["join_rows"] = tr.join_rows
-        meta["limb_rows"] = dict(tr.limb_rows)
-        meta["hash_table_joins"] = len(tr.direct_probes)
-        meta["span_tables"] = tr.span_tables
-        meta["semi_joins"] = tr.semi_joins
-        meta["scalar_subqueries"] = tr.scalar_subqueries
-        meta["shared_subplans"] = tr.shared_subplans
-        meta["ordered"] = [tag for tag, _, _ in tr.ordered]
-        meta["ordered_dense"] = sum(dense for _, _, dense in tr.ordered)
-        meta["run_groupbys"] = len(tr.run_groups)
+        meta.update(tr.flags.meta())
         meta["n_out"] = n
-        outs: List[jax.Array] = [flags]
+        outs: List[jax.Array] = [tr.flags.pack(count)]
         for c in out.table.columns:
             outs.append(c.data)
             if c.mask is not None:
@@ -1480,8 +774,9 @@ def _compact_eligible(plan: RelNode) -> set:
             if isinstance(below, LogicalJoin):
                 out.add(id(rel))
         # global DISTINCT aggregates (except MIN/MAX, which are
-        # dedup-invariant and skip _distinct_keep) still factorize every
-        # row in-program (_first_occurrence_keep), so they count
+        # dedup-invariant and skip ``aggregates.distinct_keep``) still
+        # factorize every row in-program (``first_occurrence_keep``), so
+        # they count
         sorty = sorty_above \
             or isinstance(rel, (LogicalJoin, LogicalWindow, LogicalSort)) \
             or (isinstance(rel, LogicalAggregate)
@@ -1505,7 +800,7 @@ def _compact_attrs(meta: dict) -> dict:
     (``kernels.compact_slab_rows``: static, as everything here) and the
     largest of their caps; beside them the rows its joins take in, which is
     the work the sites between two joins remove, how many of their hash
-    tables a ``span*`` hint sized (``_join_hash_table``), and what the
+    tables a ``span*`` hint sized (``joins.hash_table``), and what the
     program holds of subqueries: SEMI / ANTI joins, inlined scalar ones."""
     sites = [(n_rows, cap) for (n_rows, _, tag), cap in
              zip(meta["agg_sites"], meta["ngroup_caps"])
@@ -1522,19 +817,20 @@ def _compact_attrs(meta: dict) -> dict:
             "run_groupbys": meta.get("run_groupbys", 0)}
 
 
-def _count_probes(meta: dict, flags) -> None:
+def _count_probes(meta: dict, direct_bits) -> None:
     """How the joins of the hash-table formulation probed, known when a
     program's flags are in.  ``hash_table_joins``: all of them, static.
     ``direct_probes``: those that addressed their build row directly,
-    through a direct-addressed table (``fits`` is the data's: the flags'
-    tail, one bit a table) or in a dense key column.  ``ordered_probes``:
+    through a direct-addressed table (``fits`` is the data's:
+    ``direct_bits``, one a table) or in a dense key column.
+    ``ordered_probes``:
     those that built no table and probed the build side's key column, dense
     or searched; a searched one is neither direct nor looped."""
     tables = meta.get("hash_table_joins", 0)
     ordered = len(meta.get("ordered", ()))
     if not tables + ordered:
         return
-    direct = int(flags[len(flags) - tables:].sum()) if tables else 0
+    direct = int(direct_bits.sum()) if tables else 0
     dense = meta.get("ordered_dense", 0)
     _tel.annotate(hash_table_joins=tables + ordered,
                   direct_probes=direct + dense)
@@ -1556,15 +852,16 @@ def _materialize(entry: _Compiled, outs) -> Table:
     # two-phase (flags, then data) costs double
     host = jax.device_get(list(outs)) if small else None
     flags = host[0] if small else np.asarray(outs[0])
+    said = _read_flags(meta, flags)
     _caps._check_ordered(entry, flags)
-    if flags[0]:
+    if said.eager:
         _tel.inc("fallbacks")
         return None
     _check_flags(entry, flags)
-    _count_probes(meta, flags)
+    _count_probes(meta, said.direct)
     if meta.get("run_groupbys"):
         _tel.inc("groupby_run_aggregates", meta["run_groupbys"])
-    count = int(flags[1])
+    count = said.count
     cut = meta["has_valid"] and count < meta["n_out"]
     sel = np.nonzero(host[-1])[0] if small and cut else None
     idx = 1

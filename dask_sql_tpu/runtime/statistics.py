@@ -768,7 +768,7 @@ def _counted_ndv(cs: Optional[ColumnStats]) -> Optional[int]:
 
 
 #: What an ordered-probe hint says of a join's build key column (the
-#: compiled tier's ``_join_hash_table``): it increases strictly in load
+#: compiled tier's ``joins.hash_table``): it increases strictly in load
 #: order, and beyond that nothing (the search gathers 64 bits), or its span
 #: is under 2^31 (the search runs in 32), or it holds every integer of its
 #: range (no search).  0 is a hint a program's own check refuted.

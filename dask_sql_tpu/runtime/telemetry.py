@@ -250,8 +250,8 @@ STABLE_COUNTERS: Tuple[str, ...] = (
     # reason); ``recompiles`` stays their sum
     "recompiles_overflow", "recompiles_tighten", "recompiles_hint",
     # grouped aggregates that took their groups from the runs of a key
-    # column in load order and hashed nothing (PR 45, physical/compiled.py
-    # ``_run_aggregate``): one a request and node, over the runs that gave
+    # column in load order and hashed nothing (PR 45, physical/aggregates.py
+    # ``run_aggregate``): one a request and node, over the runs that gave
     # an answer
     "groupby_run_aggregates",
 )

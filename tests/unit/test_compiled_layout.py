@@ -20,6 +20,8 @@ PACKAGE = os.path.dirname(os.path.abspath(dask_sql_tpu.__file__))
 #: the modules beneath ``physical/compiled.py``, and the three outside the
 #: executor that used to reach into it for a program's identity
 BENEATH = ["physical/identity.py", "ops/hashing.py", "physical/caps.py",
+           "physical/traced.py", "physical/joins.py",
+           "physical/aggregates.py",
            "physical/semijoin.py", "physical/shared.py",
            "physical/programs.py", "physical/stage_exec.py",
            "physical/tiering.py", "physical/stages.py",
@@ -88,15 +90,87 @@ def test_a_program_key_is_built_at_one_site():
 
 
 def test_the_request_path_stays_readable():
-    """ISSUE 29's bounds: the tracer and the entry in 1,800 lines, the
-    request's path in 120."""
+    """The tracer and the entry in 1,300 lines (ISSUE 46: the formulations
+    it chooses among live beneath it), the request's path in 120 (ISSUE
+    29)."""
     with open(os.path.join(PACKAGE, "physical/compiled.py")) as f:
         source = f.read()
-    assert len(source.splitlines()) <= 1800
+    assert len(source.splitlines()) <= 1300
     single, = [n for n in ast.parse(source).body
                if isinstance(n, ast.FunctionDef)
                and n.name == "_execute_single"]
     assert single.end_lineno - single.lineno + 1 <= 120
+
+
+def test_the_flags_round_trip_through_their_one_home():
+    """A hand-built trace (two sites, an ``ord*`` hint, a ``runs`` hint,
+    two tables, interleaved as a plan would): ``read`` hands each reader
+    what the offsets it used to count by hand gave it."""
+    import jax.numpy as jnp
+
+    from dask_sql_tpu.physical.traced import ProgramFlags, read
+    from dask_sql_tpu.runtime.statistics import RUN_GROUPS_TAG
+    trace = ProgramFlags()
+    trace.site("agg0", 6000, False, 2048, jnp.int64(1500))
+    trace.hint(RUN_GROUPS_TAG, jnp.array(False))        # refuted
+    trace.fallback(jnp.array(False))
+    trace.direct(jnp.array(True))
+    trace.hint("ord1r", jnp.array(True))                # kept
+    trace.ordered_dense += 1
+    trace.site("cmp0", 6000, False, 1024, jnp.int64(77))
+    trace.direct(jnp.array(False))
+    trace.fallback(jnp.array(True))
+    trace.join_rows += 6040
+    meta = trace.meta()
+    assert meta == {
+        "ngroup_caps": [2048, 1024],
+        "agg_sites": [(6000, False, "agg0"), (6000, False, "cmp0")],
+        "join_rows": 6040, "limb_rows": {}, "hash_table_joins": 2,
+        "span_tables": 0, "semi_joins": 0, "scalar_subqueries": 0,
+        "shared_subplans": 0, "ordered": ["ord1r"], "ordered_dense": 1,
+        "run_groupbys": 1}
+    flags = np.asarray(trace.pack(jnp.int64(42)))
+    # eager, count, the sites' counts, the joins' hints before the GROUP
+    # BYs' (a set bit: refuted), a bit a table
+    assert flags.dtype == np.int64
+    assert flags.tolist() == [1, 42, 1500, 77, 0, 1, 1, 0]
+    said = read(meta, flags)
+    sites = len(meta["agg_sites"])
+    tags = meta["ordered"] + [RUN_GROUPS_TAG] * meta["run_groupbys"]
+    assert (said.eager, said.count) == (flags[0], flags[1])
+    assert list(said.site_counts) == list(flags[2:][:sites])
+    assert said.refuted == list(zip(tags, flags[2 + sites:][:len(tags)]))
+    assert said.refuted == [("ord1r", 0), (RUN_GROUPS_TAG, 1)]
+    assert list(said.direct) \
+        == list(flags[len(flags) - meta["hash_table_joins"]:])
+    # a program from before a segment existed has no key for it
+    bare = read({"agg_sites": meta["agg_sites"]}, flags[:4])
+    assert list(bare.site_counts) == [1500, 77]
+    assert bare.refuted == [] and len(bare.direct) == 0
+
+
+def test_nothing_but_the_ledger_subscripts_a_flags_vector():
+    found = set()
+    for path in _package_files():
+        with open(os.path.join(PACKAGE, path)) as f:
+            tree = ast.parse(f.read())
+        if any(isinstance(n, ast.Subscript)
+               and getattr(n.value, "id", getattr(n.value, "attr", ""))
+               == "flags" for n in ast.walk(tree)):
+            found.add(path)
+    assert found == {"physical/traced.py"}
+
+
+def test_the_tracer_chooses_a_formulation_and_lowers_none():
+    """What ``_Tracer`` has is a method a plan node, the choices and the
+    subquery hook: a join's or a grouped aggregate's lowering is a
+    function of ``physical/joins.py`` / ``physical/aggregates.py``."""
+    methods = {name for name, value in vars(compiled._Tracer).items()
+               if callable(value) and not name.startswith("__")}
+    nodes = {name for name in methods if name.startswith("_Logical")}
+    assert methods - nodes == {"run", "traced_scalar_subquery",
+                               "_maybe_compact", "_ordered_hint",
+                               "_build_tag"}
 
 
 def test_the_benchmark_finds_its_names_on_the_compiled_tier():

@@ -1,6 +1,6 @@
 """A hash-table join whose build side is a base table's rows in load order,
 on a key column that strictly increases, builds nothing and probes the
-column itself (``compiled._join_ordered``; kernels in ``ops/hashing.py``).
+column itself (``joins.ordered``; kernels in ``ops/hashing.py``).
 It takes the path on an ingest statistic's word (``ColumnStats.increasing``,
 riding as the hint ``ord<j>l`` / ``ord<j>r`` among a request's capacities)
 and the program checks that word: a refuted hint recompiles with the table

@@ -1,6 +1,6 @@
 """A GROUP BY on one key column that never decreases in load order takes its
 groups from the runs of that column (``ops/groupby.py``: ``key_runs``,
-``run_aggregate``; ``compiled._run_aggregate``): no hash table, no scatter
+``run_aggregate``; ``aggregates.run_aggregate``): no hash table, no scatter
 over the rows.  It is taken on an ingest statistic's word
 (``statistics.grouped_by_runs``; the hint ``runs`` among a request's
 capacities) and the program checks that word: a refuted hint recompiles on
@@ -493,8 +493,8 @@ def test_a_table_with_a_row_mask_of_its_own_keeps_the_hashed_path():
         src = cm._VT(table.limit_to(["k", "v"]), valid, load_order=load_order)
         tracer._ran[id(agg.input)] = src
         out = tracer._LogicalAggregate(agg)
-        assert len(tracer.run_groups) == expect
-        assert out.table.num_rows == tracer.ngroup_caps[0]
+        assert len(tracer.flags.hints) == expect
+        assert out.table.num_rows == tracer.flags.site_caps[0]
 
 
 # --- no scatter of the rows under the inner aggregate ----------------------
